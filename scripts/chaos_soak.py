@@ -169,9 +169,10 @@ def main() -> int:
         # donation (train/step.py's bisected aliasing gate) — same on
         # both sides, so the bitwise comparison holds.
         env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   TF_CPP_MIN_LOG_LEVEL="3", XLA_FLAGS="",
-                   JAX_COMPILATION_CACHE_DIR=os.path.join(work,
-                                                          "jax_cache"))
+                   TF_CPP_MIN_LOG_LEVEL="3", XLA_FLAGS="")
+        # A cache directory given from outside wins.
+        env.setdefault("JAX_COMPILATION_CACHE_DIR",
+                       os.path.join(work, "jax_cache"))
 
         base_jsonl = os.path.join(work, "baseline.jsonl")
         base_ckpt = os.path.join(work, "ckpt_base")

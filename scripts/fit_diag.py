@@ -19,10 +19,8 @@ def main():
     import jax.numpy as jnp
     import numpy as np
 
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.join(_REPO, "tests", ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    from tpuic.compiled.cache import enable_compile_cache
+    enable_compile_cache()
 
     from tpuic.config import DataConfig, ModelConfig, OptimConfig
     from tpuic.data.folder import ImageFolderDataset

@@ -90,11 +90,11 @@ def main() -> int:
                                    size=24)
         base_env = dict(os.environ, JAX_PLATFORMS="cpu",
                         TF_CPP_MIN_LOG_LEVEL="3", XLA_FLAGS="",
-                        TPUIC_FLEET_RANKS=str(RANKS),
-                        # Both ranks compile the same program: share the
-                        # persistent cache so the second compile is a hit.
-                        JAX_COMPILATION_CACHE_DIR=os.path.join(
-                            work, "jax_cache"))
+                        TPUIC_FLEET_RANKS=str(RANKS))
+        # Both ranks compile the same program: share the persistent cache
+        # so the second compile is a hit. A directory given from outside wins.
+        base_env.setdefault("JAX_COMPILATION_CACHE_DIR",
+                            os.path.join(work, "jax_cache"))
         sink = None if args.verbose else subprocess.DEVNULL
         print(f"[fleet_smoke] launching {RANKS} ranks "
               f"(rank {SLOW_RANK} seeded slow_step#{args.slow_s:g})")

@@ -28,10 +28,8 @@ def capture(per_chip_batch: int, n_steps: int, trace_dir: str,
     import jax
     import jax.numpy as jnp
 
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.join(_REPO, "tests", ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    from tpuic.compiled.cache import enable_compile_cache
+    enable_compile_cache()
 
     from tpuic.config import ModelConfig, OptimConfig
     from tpuic.data.synthetic import synthetic_batch

@@ -118,7 +118,9 @@ def main() -> int:
         # Shared persistent XLA cache across both lives AND the baseline
         # (identical env => identical trajectories; the restart's prewarm
         # compiles become disk reads). XLA_FLAGS overridden, not popped —
-        # see chaos_soak.py for why.
+        # see chaos_soak.py for why. The cache directory is this run's own
+        # even when JAX_COMPILATION_CACHE_DIR is given from outside: a cold
+        # cache in the first life is what the smoke proves.
         env = dict(os.environ, JAX_PLATFORMS="cpu",
                    TF_CPP_MIN_LOG_LEVEL="3", XLA_FLAGS="",
                    JAX_COMPILATION_CACHE_DIR=os.path.join(work,

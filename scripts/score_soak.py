@@ -122,9 +122,10 @@ def main() -> int:
         n_shards = n_corpus // SHARD_SIZE
         env = dict(os.environ, JAX_PLATFORMS="cpu",
                    TF_CPP_MIN_LOG_LEVEL="3", XLA_FLAGS="",
-                   PYTHONPATH=_REPO,
-                   JAX_COMPILATION_CACHE_DIR=os.path.join(work,
-                                                          "jax_cache"))
+                   PYTHONPATH=_REPO)
+        # A cache directory given from outside wins.
+        env.setdefault("JAX_COMPILATION_CACHE_DIR",
+                       os.path.join(work, "jax_cache"))
         env.pop("TPUIC_FAULTS", None)
         sink = None if args.verbose else subprocess.DEVNULL
         ckpt = os.path.join(work, "ckpt")

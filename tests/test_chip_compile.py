@@ -1,0 +1,134 @@
+"""Compile-only rehearsal of every Pallas entry point for a described v5e.
+
+The TPU compiler is installed with jax and compiles for a chip that is
+described, not attached (``jax.experimental.topologies``), so Mosaic
+refusals interpret mode cannot see — a strided vector slice, a block that
+overflows VMEM — fail here, on the CPU, at no chip time. Nothing runs: a
+pass says the kernel compiles at this shape, not that it is right or fast
+(tests/test_kernels.py pins numerics in interpret mode; chip_smoke.py runs
+the kernels on the chip).
+
+Shapes are the main path's published widths: ViT-B/16 attention at 224 px
+(N=197) and 768 px (N=2305), the 1000-class loss at batch 128, ResNet-50
+leaves and layers. The whole-step compile (~36 s) is not tier-1; see
+scripts/chip_compile_rehearsal.py.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from tpuic.kernels import (flash_attention, fused_conv_bn_relu,
+                           fused_weighted_cross_entropy, lamb_leaf_update,
+                           lars_leaf_update)
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One described v5e chip's sharding, persistent cache off: a compile
+    for a described chip is written to the cache but cannot be read back
+    without one, and the next run would warn and compile again."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, chip, *shapes):
+    """Lower ``fn`` at ``(shape, dtype)`` args placed on the described chip
+    and compile; returns the number of Mosaic kernels in the program."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return text.count("tpu_custom_call")
+
+
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("shape", [(64, 197, 12, 64), (8, 2305, 12, 64)],
+                         ids=["vitb16_224", "vitb16_768"])
+def test_flash_attention(chip, shape, grad):
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, None, None, False)
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(F32))
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
+    n = _compile(fn, chip, *[(shape, BF16)] * 3)
+    assert n >= (2 if grad else 1)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_fused_cross_entropy(chip, grad):
+    def fwd(logits, labels):
+        return fused_weighted_cross_entropy(logits, labels, None, None,
+                                            0.0, 128, False)
+
+    fn = jax.grad(fwd) if grad else fwd
+    assert _compile(fn, chip, ((128, 1000), F32), ((128,), I32)) >= 1
+
+
+@pytest.mark.parametrize("leaf", [(3, 3, 512, 512), (64,)],
+                         ids=["conv3x3_512", "bn_64"])
+@pytest.mark.parametrize("opt", ["lars", "lamb"])
+def test_fused_optimizer_leaf_update(chip, opt, leaf):
+    if opt == "lars":
+        def fn(w, g, m):
+            return lars_leaf_update(w, g, m, lr=0.1, weight_decay=1e-4,
+                                    trust_coefficient=1e-3, momentum=0.9,
+                                    impl="pallas", interpret=False)
+        shapes = [(leaf, F32)] * 3
+    else:
+        def fn(w, g, m, v, count):
+            return lamb_leaf_update(w, g, m, v, count, lr=1e-3, b1=0.9,
+                                    b2=0.999, eps=1e-6, weight_decay=1e-2,
+                                    impl="pallas", interpret=False)
+        shapes = [(leaf, F32)] * 4 + [((), I32)]
+    assert _compile(fn, chip, *shapes) == 1
+
+
+# ResNet-50 @224 layers: layer1's 3x3, and layer2's first block where the
+# stride sits (torchvision v1.5 puts it on the 3x3; the 1x1 projection
+# shortcut is strided too).
+@pytest.mark.parametrize("x,w,stride,pad", [
+    ((8, 56, 56, 64), (3, 3, 64, 64), 1, 1),
+    ((8, 56, 56, 128), (3, 3, 128, 128), 2, 1),
+    ((8, 56, 56, 256), (1, 1, 256, 512), 2, 0),
+], ids=["layer1_3x3_s1", "layer2_3x3_s2", "layer2_proj_1x1_s2"])
+def test_fused_conv_bn_relu(chip, x, w, stride, pad):
+    def fn(xv, wv, scale, bias):
+        return fused_conv_bn_relu(xv, wv, scale, bias, strides=stride,
+                                  padding=pad, interpret=False)
+
+    cout = w[-1]
+    assert _compile(fn, chip, (x, BF16), (w, BF16), ((cout,), F32),
+                    ((cout,), F32)) == 1
+
+
+def test_fused_conv_bn_relu_refuses_the_224px_stem(chip):
+    """The 7x7/2 ImageNet stem (Cin=3 pads to 128 lanes) cannot hold one
+    image's blocks in VMEM: the wrapper says so by shape instead of
+    handing Mosaic a program it is certain to refuse (models/resnet.py
+    keeps that layer unfused)."""
+    def fn(xv, wv, scale, bias):
+        return fused_conv_bn_relu(xv, wv, scale, bias, strides=2,
+                                  padding=3, interpret=False)
+
+    with pytest.raises(ValueError, match="MiB of VMEM"):
+        _compile(fn, chip, ((8, 224, 224, 3), BF16), ((7, 7, 3, 64), BF16),
+                 ((64,), F32), ((64,), F32))

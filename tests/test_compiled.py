@@ -257,6 +257,39 @@ def test_donation_allowed_truth_table():
         jax.config.update("jax_compilation_cache_dir", cache_dir)
 
 
+# ------------------------------------------------ persistent-cache helper
+
+@pytest.mark.parametrize("env,flag,want", [
+    ("/x/from-env", "", "/x/from-env"),            # the environment wins
+    ("/x/from-env", "/y/from-flag", "/x/from-env"),  # over the serve flag too
+    ("", "", None),                                # the fixed in-checkout path
+], ids=["env_set", "env_beats_flag", "unset"])
+def test_enable_compile_cache_location(monkeypatch, env, flag, want):
+    """tpuic/compiled/cache.py is the only code that names the cache
+    directory: with JAX_COMPILATION_CACHE_DIR set it leaves the directory
+    to JAX (no ``jax_compilation_cache_dir`` update at all); unset, it is
+    one fixed path inside the checkout."""
+    from tpuic.compiled import cache
+    if env:
+        monkeypatch.setenv(cache.CACHE_ENV, env)
+    else:
+        monkeypatch.delenv(cache.CACHE_ENV, raising=False)
+    updates = {}
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.__setitem__(k, v))
+    monkeypatch.setattr(os, "makedirs", lambda *a, **k: None)
+    got = cache.enable_compile_cache(flag)
+    assert updates["jax_persistent_cache_min_compile_time_secs"] == 1.0
+    assert updates["jax_persistent_cache_min_entry_size_bytes"] == 0
+    if want is not None:
+        assert got == want
+        assert "jax_compilation_cache_dir" not in updates
+    else:
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert got == os.path.join(repo, "tests", ".jax_cache")
+        assert updates["jax_compilation_cache_dir"] == got
+
+
 # --------------------------------------------------- consumer integration
 
 def _sum_forward(variables, images):

@@ -110,8 +110,10 @@ def test_peak_flops_dtype_ladder():
     v5e = _Dev("TPU v5 lite")
     assert peak_flops(v5e) == peak_flops(v5e, "bfloat16") == 197e12
     assert peak_flops(v5e, "float32") == 98.5e12
-    # unknown device kind: nominal fallback under either roofline
-    assert peak_flops(_Dev("QPU v1"), "bf16") == 1e12
+    # unknown device kind: an error, never a made-up peak; None reads
+    # the nominal cpu row (CI)
+    with pytest.raises(ValueError, match="unknown device kind 'QPU v1'"):
+        peak_flops(_Dev("QPU v1"), "bf16")
     assert peak_flops(None, "f32") == 1e12
     with pytest.raises(ValueError, match="dtype"):
         peak_flops(v5e, "fp8")

@@ -125,6 +125,15 @@ def test_hbm_table_golden_values():
     assert hbm_bandwidth(jax.devices()[0]) == 50e9  # CPU CI
 
 
+def test_unknown_device_kind_has_no_bandwidth():
+    """A device the table does not know is an error, not 50 GB/s."""
+    class _Dev:
+        device_kind = "QPU v1"
+
+    with pytest.raises(ValueError, match="unknown device kind 'QPU v1'"):
+        hbm_bandwidth(_Dev())
+
+
 def test_roofline_classification_boundaries():
     peak, bw = 100e12, 1e12   # ridge = 100 FLOPs/byte
     assert ridge_intensity(peak, bw) == 100.0

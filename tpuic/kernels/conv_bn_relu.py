@@ -21,8 +21,7 @@ classes.  This kernel keeps the whole block in VMEM:
 
 Grid: one batch element per grid step — the weights and the affine stay
 resident in VMEM across the grid, and per-image activations for the
-ResNet stage sizes (<= 112x112x64 at 224px, <= 32x32x64 on CIFAR) fit
-comfortably.  The batch dim is embarrassingly parallel, so under a
+ResNet stage sizes (<= 56x56x256 at 224px, <= 32x32x64 on CIFAR) fit.  The batch dim is embarrassingly parallel, so under a
 sharded jit GSPMD keeps the kernel batch-parallel like every other
 per-sample Pallas call here (cross_entropy.py's discipline).
 
@@ -35,8 +34,10 @@ reference is pinned in tests/test_kernels.py (atol 1e-4 in float32 —
 the tap-matmul accumulation order differs from XLA's conv).
 
 On CPU (CI) the kernel runs in Pallas interpret mode like every other
-kernel in this package; on TPU it compiles via Mosaic.  Stride-2 taps
-read through ``jax.lax.slice`` with strides on the VMEM-resident block.
+kernel in this package; on TPU it compiles via Mosaic.  Mosaic takes only
+unit-stride vector slices, so a strided conv is split into its stride
+phases in XLA before the call and every tap reads a unit-stride window.
+A layer whose per-image blocks exceed VMEM raises a ValueError by shape.
 """
 
 from __future__ import annotations
@@ -77,25 +78,43 @@ def _kernel(x_ref, w_ref, scale_ref, bias_ref, out_ref, *, kh: int, kw: int,
             sh: int, sw: int, ho: int, wo: int, relu: bool):
     """One batch element: accumulate the K*K tap matmuls in f32, apply
     the folded-BN affine + optional ReLU, write once."""
-    xb = x_ref[0]                                    # [Hp, Wp, Cin]
-    cin = xb.shape[-1]
+    cin = x_ref.shape[-1]
     cout = out_ref.shape[-1]
+    # x_ref holds the sh*sw stride phases of the padded image (see
+    # _fused): phase (pi, pj) is rows pi, pi+sh, ... / cols pj, pj+sw, ...
+    # Only the phases some tap reads are there: min(k, s) along each axis.
+    pw = min(kw, sw)
+    phases = [x_ref[0, p] for p in range(x_ref.shape[1])]  # [Hq, Wq, Cin]
     acc = jnp.zeros((ho * wo, cout), jnp.float32)
     for ki in range(kh):
         for kj in range(kw):
-            # Tap (ki, kj)'s receptive field: rows ki, ki+sh, ... — a
-            # strided window over the VMEM-resident block (a value-level
-            # lax.slice, not a memory gather).
-            patch = jax.lax.slice(
-                xb, (ki, kj, 0),
-                (ki + (ho - 1) * sh + 1, kj + (wo - 1) * sw + 1, cin),
-                (sh, sw, 1))                         # [ho, wo, Cin]
+            # Tap (ki, kj) reads rows ki, ki+sh, ...: a UNIT-stride window
+            # at offset (ki // sh, kj // sw) of phase (ki % sh, kj % sw).
+            oi, oj = ki // sh, kj // sw
+            patch = jax.lax.slice(phases[(ki % sh) * pw + kj % sw],
+                                  (oi, oj, 0), (oi + ho, oj + wo, cin))
             acc += jnp.dot(patch.reshape(ho * wo, cin), w_ref[ki, kj],
                            preferred_element_type=jnp.float32)
     y = acc * scale_ref[0] + bias_ref[0]
     if relu:
         y = jnp.maximum(y, 0.0)
     out_ref[0] = y.reshape(ho, wo, cout).astype(out_ref.dtype)
+
+
+# Mosaic's scoped-VMEM limit on v5e.
+_VMEM_LIMIT = 16 * 2 ** 20
+
+
+def _tiled_bytes(shape, dtype) -> int:
+    """Bytes of a VMEM block: the last dim pads to 128 lanes, the one
+    before it to a sublane tile (8 rows of 32 bits)."""
+    item = jnp.dtype(dtype).itemsize
+    *lead, rows, cols = shape
+    sub = 8 * max(1, 4 // item)
+    n = -(-rows // sub) * sub * -(-cols // 128) * 128 * item
+    for d in lead:
+        n *= d
+    return n
 
 
 @functools.partial(jax.jit, static_argnames=("strides", "padding", "relu",
@@ -112,8 +131,33 @@ def _fused(x, w, scale, bias, strides, padding, relu, interpret, out_dtype):
     if ho < 1 or wo < 1:
         raise ValueError(f"empty output for input {x.shape}, kernel "
                          f"{w.shape}, strides {strides}, padding {padding}")
-    xp = jnp.pad(x, ((0, 0), (pt, pb), (pl_, pr), (0, 0)))
-    hp, wp = xp.shape[1], xp.shape[2]
+    # Mosaic takes only unit-stride vector slices, so the stride is taken
+    # out here, in XLA: split the padded image into its sh*sw phases
+    # [B, sh*sw, Hq, Wq, Cin] (for stride 1 a plain reshape) and let each
+    # tap read a unit-stride window of one phase.  Rows/cols past the last
+    # tap are cropped or zero-filled; no tap reads them.
+    # A phase holds the deepest tap offset plus the output extent.
+    hq, wq = (kh - 1) // sh + ho, (kw - 1) // sw + wo
+    xp = jnp.pad(x, ((0, 0), (pt, max(0, hq * sh - h - pt)),
+                     (pl_, max(0, wq * sw - w_in - pl_)), (0, 0)))
+    xp = xp[:, :hq * sh, :wq * sw]
+    xp = xp.reshape(b, hq, sh, wq, sw, cin).transpose(0, 2, 4, 1, 3, 5)
+    ph, pw = min(kh, sh), min(kw, sw)    # a 1x1 stride-2 tap reads 1 phase
+    xp = xp[:, :ph, :pw].reshape(b, ph * pw, hq, wq, cin)
+    # One image's blocks are the least the kernel holds in VMEM (the
+    # pipeline double-buffers what moves with the grid; the compiler's own
+    # temporaries come on top).  Past the limit the compile is certain to
+    # be refused: say so here, by shape.  The 224-px ImageNet stem is such
+    # a layer (Cin=3 pads to 128 lanes); models/resnet.py keeps it unfused.
+    need = (2 * _tiled_bytes(xp.shape[1:], x.dtype)
+            + 2 * _tiled_bytes((ho, wo, cout), out_dtype)
+            + _tiled_bytes(w.shape, w.dtype))
+    if need > _VMEM_LIMIT:
+        raise ValueError(
+            f"fused_conv_bn_relu: input {x.shape}, kernel {w.shape}, "
+            f"strides {strides} needs {need / 2 ** 20:.1f} MiB of VMEM for "
+            f"one image's blocks, over the {_VMEM_LIMIT // 2 ** 20} MiB "
+            "limit; run this layer unfused")
     # The grid walks the batch; weights + the affine rows use a constant
     # index map, so they stay VMEM-resident across all B steps.
     out = pl.pallas_call(
@@ -122,7 +166,8 @@ def _fused(x, w, scale, bias, strides, padding, relu, interpret, out_dtype):
         out_shape=jax.ShapeDtypeStruct((b, ho, wo, cout), out_dtype),
         grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, hp, wp, cin), lambda i: (i, 0, 0, 0),
+            pl.BlockSpec((1, ph * pw, hq, wq, cin),
+                         lambda i: (i, 0, 0, 0, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((kh, kw, cin, cout), lambda i: (0, 0, 0, 0),
                          memory_space=pltpu.VMEM),

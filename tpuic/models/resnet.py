@@ -162,7 +162,11 @@ class ResNet(nn.Module):
     def __call__(self, x: jnp.ndarray, train: bool = False) -> jnp.ndarray:
         kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
         x = x.astype(self.dtype)
-        fused = _fused_ready(self, train)
+        # The fused kernel holds one whole image per grid step, and the
+        # ImageNet stem's input (Cin=3, or 12 after space-to-depth, padded
+        # to 128 lanes) does not fit VMEM at 224 px: only the small stem
+        # fuses; the 7x7 stem always runs the unfused graph.
+        fused = _fused_ready(self, train) and self.small_stem
         # jax.named_scope tags ('stem'/'gap') thread the structural
         # phases flax's module path does not name into the HLO op
         # metadata — the device-time waterfall (telemetry/profile.py)
@@ -170,22 +174,7 @@ class ResNet(nn.Module):
         # already scoped by their flax module names (layerN_i).
         with jax.named_scope("stem"):
             if fused:
-                if self.small_stem:
-                    x = _fused_cbr(self, x, "conv1", "bn1", padding=1)
-                elif self.space_to_depth:
-                    b, h, w, c = x.shape
-                    if h % 2 or w % 2:
-                        raise ValueError(
-                            f"space_to_depth stem needs even H/W, "
-                            f"got {(h, w)}")
-                    x = x.reshape(b, h // 2, 2, w // 2, 2, c)
-                    x = x.transpose(0, 1, 3, 2, 4, 5).reshape(
-                        b, h // 2, w // 2, 4 * c)
-                    x = _fused_cbr(self, x, "conv1", "bn1",
-                                   padding=((2, 1), (2, 1)))
-                else:
-                    x = _fused_cbr(self, x, "conv1", "bn1", strides=2,
-                                   padding=3)
+                x = _fused_cbr(self, x, "conv1", "bn1", padding=1)
             elif self.small_stem:
                 x = nn.Conv(self.num_filters, (3, 3), padding=1,
                             use_bias=False, **kw, name="conv1")(x)

@@ -32,6 +32,10 @@ class RuntimeInfo:
     local_device_count: int
     global_device_count: int
     platform: str
+    device_kind: str
+    # Why jax.distributed.initialize() was called in this process; '' when
+    # it was not (a single host). Entry points print it.
+    distributed: str = ""
 
     @property
     def is_distributed(self) -> bool:
@@ -47,11 +51,19 @@ _CLUSTER_ENV_MARKERS = ("TPU_WORKER_HOSTNAMES", "JAX_COORDINATOR_ADDRESS",
                         "COORDINATOR_ADDRESS", "MEGASCALE_COORDINATOR_ADDRESS")
 
 
-def _looks_multi_host() -> bool:
+def _cluster_marker() -> str:
+    """The launcher marker that says this is one process of several, or
+    '' on a single host (one worker hostname, no coordinator address)."""
     hosts = os.environ.get("TPU_WORKER_HOSTNAMES", "")
     if hosts and len(hosts.split(",")) > 1:
-        return True
-    return any(os.environ.get(m) for m in _CLUSTER_ENV_MARKERS[1:])
+        return f"TPU_WORKER_HOSTNAMES lists {len(hosts.split(','))} hosts"
+    for m in _CLUSTER_ENV_MARKERS[1:]:
+        if os.environ.get(m):
+            return f"{m} is set"
+    return ""
+
+
+_init_reason = ""
 
 
 def initialize(coordinator_address: str | None = None,
@@ -79,7 +91,7 @@ def initialize(coordinator_address: str | None = None,
     auto-discovered rank 0/1 would wedge the rendezvous (or worse,
     train as the wrong fleet) with nothing in the logs.
     """
-    global _initialized
+    global _initialized, _init_reason
     env_addr = os.environ.get("TPUIC_COORDINATOR_ADDRESS") or None
     env_num = os.environ.get("TPUIC_NUM_PROCESSES") or None
     env_pid = os.environ.get("TPUIC_PROCESS_ID") or None
@@ -101,10 +113,11 @@ def initialize(coordinator_address: str | None = None,
             f"TPUIC_PROCESS_ID={env_pid!r} — a launcher must set all "
             "three (or none; TPUIC_NUM_PROCESSES alone keeps the "
             "auto-discovery path)")
-    multi = (coordinator_address is not None
-             or num_processes not in (None, 1)
-             or _looks_multi_host())
-    if multi and not _initialized:
+    reason = ("coordinator address given" if coordinator_address is not None
+              else f"num_processes={num_processes}"
+              if num_processes not in (None, 1) else _cluster_marker())
+    if reason and not _initialized:
+        _init_reason = reason
         jax.distributed.initialize(
             coordinator_address=coordinator_address,
             num_processes=num_processes,
@@ -121,6 +134,8 @@ def runtime_info() -> RuntimeInfo:
         local_device_count=jax.local_device_count(),
         global_device_count=jax.device_count(),
         platform=jax.devices()[0].platform,
+        device_kind=jax.devices()[0].device_kind,
+        distributed=_init_reason,
     )
 
 

@@ -14,7 +14,6 @@ import dataclasses
 from typing import Optional, Sequence
 
 import jax
-import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tpuic.config import MeshConfig
@@ -40,21 +39,12 @@ def make_mesh(cfg: Optional[MeshConfig] = None,
     if data * seq * model != n:
         raise ValueError(f"mesh {shape} != device count {n}")
     # Auto axis types: shardings constrain data layout and GSPMD propagates /
-    # inserts collectives (jax>=0.9 defaults make_mesh to Explicit
-    # sharding-in-types, which instead demands out_sharding annotations on
-    # every contraction touching a sharded dim — not the model we want).
-    # jax < 0.6 has no AxisType (no sharding-in-types): the plain Mesh
-    # fallback IS Auto semantics there.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, tuple(cfg.axis_names),
-                                 axis_types=(axis_type.Auto,) * len(shape),
-                                 devices=devices)
-        except TypeError:
-            pass  # older make_mesh signature without axis_types/devices
-    arr = np.asarray(devices).reshape(shape)
-    return Mesh(arr, tuple(cfg.axis_names))
+    # inserts collectives (make_mesh defaults to Explicit sharding-in-types,
+    # which instead demands out_sharding annotations on every contraction
+    # touching a sharded dim — not the model we want).
+    return jax.make_mesh(shape, tuple(cfg.axis_names),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def replica_mesh(replicas: int, cfg: Optional[MeshConfig] = None,

@@ -668,17 +668,17 @@ def _sidecar_ema(ckpt_dir: str, model_name: str) -> float:
 
 def build_engine(args):
     """Checkpoint -> warmed InferenceEngine (shared predict loading rules)."""
-    if args.compile_cache_dir:
-        # Persistent XLA compilation cache: warmup's per-bucket AOT
-        # compiles land on disk, so a server RESTART warms up from cache
-        # instead of recompiling (same mechanism the test suite and
-        # bench.py use).
-        import jax
-        cache = os.path.expanduser(args.compile_cache_dir)
-        os.makedirs(cache, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    # Persistent XLA compilation cache: warmup's per-bucket AOT compiles
+    # land on disk, so a server RESTART warms up from cache instead of
+    # recompiling. JAX_COMPILATION_CACHE_DIR wins over the flag.
+    import jax
+
+    from tpuic.compiled.cache import enable_compile_cache
+    cache = enable_compile_cache(args.compile_cache_dir)
+    dev = jax.devices()[0]
+    print(f"[serve] {jax.device_count()} {dev.platform} device(s), "
+          f"device_kind={dev.device_kind!r}; compile cache: {cache}",
+          file=sys.stderr)
 
     from tpuic.checkpoint.loading import load_inference_variables
     from tpuic.config import (Config, DataConfig, ModelConfig, OptimConfig,
@@ -818,9 +818,10 @@ def main(argv=None) -> int:
                         "fp32")
     p.add_argument("--max-wait-ms", type=float, default=5.0)
     p.add_argument("--queue-size", type=int, default=256)
-    p.add_argument("--compile-cache-dir", default="~/.cache/tpuic/xla",
+    p.add_argument("--compile-cache-dir", default="",
                    help="persistent XLA compile cache (restarts warm up "
-                        "from disk); empty string disables")
+                        "from disk) when JAX_COMPILATION_CACHE_DIR is "
+                        "unset; default: the checkout's tests/.jax_cache")
     p.add_argument("--top-k", type=int, default=1)
     p.add_argument("--classes", default="",
                    help="optional file of class names, one per line")

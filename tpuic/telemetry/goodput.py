@@ -67,18 +67,27 @@ _DTYPE_PEAKS = {"bf16": PEAK_FLOPS, "bfloat16": PEAK_FLOPS,
                 "f32": PEAK_FLOPS_F32, "float32": PEAK_FLOPS_F32}
 
 
-def peak_flops(device, dtype: str = "bf16") -> float:
-    """Peak FLOP/s for a jax device at ``dtype`` ('bf16' default, 'f32'
-    for the half-rate f32 roofline; 1e12 nominal fallback)."""
-    table = _DTYPE_PEAKS.get(str(dtype).lower())
-    if table is None:
-        raise ValueError(f"peak_flops: unknown dtype {dtype!r} "
-                         "(want 'bf16' or 'f32')")
+def _by_kind(table: dict, device, what: str) -> float:
+    """``table``'s row for a jax device's ``device_kind`` (``None`` reads
+    the nominal ``cpu`` row).  A device the table does not know is an
+    error, not a default: a utilization against a made-up peak is no
+    number at all."""
     kind = getattr(device, "device_kind", "cpu") if device is not None else "cpu"
     for k, v in table.items():
         if str(kind).lower().startswith(k.lower()):
             return v
-    return 1e12
+    raise ValueError(f"{what}: unknown device kind {kind!r}; add its "
+                     f"spec-sheet row (known: {sorted(table)})")
+
+
+def peak_flops(device, dtype: str = "bf16") -> float:
+    """Peak FLOP/s for a jax device at ``dtype`` ('bf16' default, 'f32'
+    for the half-rate f32 roofline); raises on an unknown device kind."""
+    table = _DTYPE_PEAKS.get(str(dtype).lower())
+    if table is None:
+        raise ValueError(f"peak_flops: unknown dtype {dtype!r} "
+                         "(want 'bf16' or 'f32')")
+    return _by_kind(table, device, "peak_flops")
 
 
 # HBM bandwidth in GB/s per chip by device kind (public spec sheets) —
@@ -97,12 +106,8 @@ HBM_GBPS = {
 
 
 def hbm_bandwidth(device) -> float:
-    """HBM bytes/s for a jax device (50 GB/s nominal fallback)."""
-    kind = getattr(device, "device_kind", "cpu") if device is not None else "cpu"
-    for k, v in HBM_GBPS.items():
-        if str(kind).lower().startswith(k.lower()):
-            return v * 1e9
-    return 50e9
+    """HBM bytes/s for a jax device; raises on an unknown device kind."""
+    return _by_kind(HBM_GBPS, device, "hbm_bandwidth") * 1e9
 
 
 def roofline_intensity(flops: float, bytes_accessed: float) -> Optional[float]:

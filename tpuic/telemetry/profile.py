@@ -2,7 +2,7 @@
 
 The telemetry layer attributes every *host*-side millisecond (goodput
 buckets, step breakdown, fleet skew) — but ``device_ms``, the dominant
-bucket at MFU 0.31 (BENCH_r05), stayed an opaque residual.  The
+bucket at MFU 0.31 (builder, 2026-08-01), stayed an opaque residual.  The
 "MFU 0.31 → 0.5+" roadmap item cannot be earned without knowing which
 ops are compute-bound vs HBM-bound; the 15-minute-ImageNet line
 (arXiv 1711.04325) and every TPU scaling paper start from exactly this

@@ -173,8 +173,7 @@ def make_train_step(optim_cfg: OptimConfig, model_cfg: ModelConfig,
     # buffer corruption (NaN loss on finite data after a restore) and
     # nondeterministic SIGSEGV/SIGABRT in dispatch.  Cache+donate+guard
     # is the exact trigger; any two of the three are fine, so TPU runs
-    # (and any run without a persistent cache — train.py configures
-    # none) keep donation.
+    # (and any run without a persistent cache) keep donation.
     from tpuic.compiled import donation_allowed
     if donate and not donation_allowed(
             guard_active=bool(optim_cfg.skip_nonfinite)):
