@@ -602,6 +602,8 @@ def _loop_stub(*, handle_preemption: bool, steps: int):
 
     class _Steptime:
         last_step = 0
+        # the epoch's first loader wait and dispatch (epoch.* spans)
+        first_batch = first_dispatch = (0.0, 0.0)
 
         def epoch_start(self):
             pass
@@ -641,6 +643,9 @@ def _loop_stub(*, handle_preemption: bool, steps: int):
         logger=types.SimpleNamespace(write=lambda *a, **k: None),
         membership=None,   # no elastic watcher (runtime/membership.py)
         _rollback_pending=False, _last_skip_streak=0, _quarantine_seen=0)
+    # train_epoch opens its span and hands over to the loop proper
+    from tpuic.train.loop import Trainer
+    stub._train_epoch = types.MethodType(Trainer._train_epoch, stub)
     return stub
 
 

@@ -66,6 +66,45 @@ def test_event_bus_idle_is_free_and_sink_errors_contained():
     assert [e.data["step"] for e in good.events] == [1]
 
 
+def test_event_bus_reports_a_subscribers_exception(capsys):
+    """A failing subscriber is named: once on stderr, and the newest
+    failure in ``last_sink_error``; delivery to the others is unchanged."""
+    bus = EventBus()
+    assert bus.last_sink_error is None
+    good = MemorySink()
+
+    class Broken:
+        def __call__(self, ev):
+            raise KeyError("no such field")
+
+    def other_broken(ev):
+        raise RuntimeError("boom")
+    bus.subscribe(Broken(), kinds=("step",))
+    bus.subscribe(good)
+    bus.subscribe(other_broken, kinds=("epoch",))
+    for i in range(3):
+        bus.publish("step", step=i)     # must not raise
+    assert bus.sink_errors == 3
+    assert [e.data["step"] for e in good.events] == [0, 1, 2]
+    err = bus.last_sink_error
+    assert err["kind"] == "step" and err["error"] == \
+        "KeyError: 'no such field'"
+    assert err["subscriber"].endswith("Broken")
+    assert __name__ in err["subscriber"]        # the qualified name
+    bus.publish("epoch", epoch=0)
+    assert bus.sink_errors == 4
+    assert bus.last_sink_error["subscriber"].endswith("other_broken")
+    assert bus.last_sink_error["error"] == "RuntimeError: boom"
+    # one stderr line per failing subscriber, not one per failure
+    lines = [ln for ln in capsys.readouterr().err.splitlines()
+             if ln.startswith("[telemetry] subscriber")]
+    assert len(lines) == 2
+    assert "Broken" in lines[0] and "KeyError" in lines[0]
+    assert "other_broken" in lines[1] and "'epoch'" in lines[1]
+    bus.reset()
+    assert bus.last_sink_error is None and bus.sink_errors == 0
+
+
 def test_jsonl_sink_roundtrip(tmp_path):
     path = str(tmp_path / "events.jsonl")
     bus = EventBus()

@@ -38,6 +38,10 @@ before/after evidence (docs/observability.md):
 - ``fleet``    — **per-rank fleet view**: rank-tagged events, per-rank
   JSONL streams, and the offline straggler-attribution aggregator
   (``python -m tpuic.telemetry.fleet <dir>``).
+- ``spans``    — **span ledger** for host work that is not a step: the
+  import graph, the stages of ``Trainer.__init__``, the head and tail of
+  every ``train_epoch``; on ``perf_counter`` and, through
+  ``TraceAnnotation``, on the profiler's clock (stdlib-only).
 - ``wiring``   — ``TrainTelemetry``, one training run's subscriber set.
 
 Everything is host-side: no module here ever calls ``jax.device_get``

@@ -32,6 +32,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -75,6 +76,11 @@ EVENT_KINDS = ("step", "epoch", "eval", "drain", "checkpoint_commit",
                # steady-compile counter).
                "score_plan", "score_shard", "score_commit",
                "score_duplicate", "score_done",
+               # Span ledger (telemetry/spans.py, docs/observability.md
+               # "Spans"): one 'span' event per closed span of host work
+               # that is not a step — the import graph, the stages of
+               # Trainer.__init__, the head and tail of every train_epoch.
+               "span",
                # Compiled-program registry (tpuic/compiled/,
                # docs/performance.md "Compiled-program registry"): one
                # 'compile_cache' event per registry action — a miss that
@@ -103,6 +109,10 @@ class EventBus:
         self._subs: Tuple[Tuple[Optional[frozenset], Callable], ...] = ()
         self.published = 0
         self.sink_errors = 0
+        # The newest subscriber failure: {subscriber, error, kind}. Each
+        # failing subscriber is also named once on stderr.
+        self.last_sink_error: Optional[Dict[str, str]] = None
+        self._failed_subs: set = set()
         # Fleet rank tag (telemetry/fleet.py): when set (a plain dict,
         # e.g. {"rank": 2, "ranks": 8}), every published event's data is
         # merged over it, so per-rank JSONL streams are attributable
@@ -146,13 +156,27 @@ class EventBus:
             delivered = True
             try:
                 fn(ev)
-            except Exception:
+            except Exception as e:
                 # A broken sink must never kill the train loop or the
-                # serve batcher; the counter makes the breakage visible.
-                self.sink_errors += 1
+                # serve batcher; the counter and the one stderr line per
+                # subscriber make the breakage visible.
+                self._sink_failed(fn, e, kind)
         if delivered:
             self.published += 1
         return ev
+
+    def _sink_failed(self, fn: Callable, e: Exception, kind: str) -> None:
+        self.sink_errors += 1
+        named = fn if hasattr(fn, "__qualname__") else type(fn)
+        who = f"{named.__module__}.{named.__qualname__}"
+        error = f"{type(e).__name__}: {e}"
+        self.last_sink_error = {"subscriber": who, "kind": kind,
+                                "error": error}
+        if who not in self._failed_subs:
+            self._failed_subs.add(who)
+            print(f"[telemetry] subscriber {who} raised on a {kind!r} "
+                  f"event: {error} (its later failures are only counted, "
+                  "in bus.sink_errors)", file=sys.stderr)
 
     def reset(self) -> None:
         """Drop every subscriber (test isolation — the process-global
@@ -161,6 +185,8 @@ class EventBus:
             self._subs = ()
             self.published = 0
             self.sink_errors = 0
+            self.last_sink_error = None
+            self._failed_subs = set()
             self.rank_tag = None
 
 

@@ -68,6 +68,11 @@ class StepTimer:
         self._data_s = 0.0
         self._dispatch_s = 0.0
         self._t_dispatch: Optional[float] = None
+        # (t0, t1) of the epoch's first loader wait and first dispatch,
+        # from the timestamps taken anyway: the loop records them as the
+        # epoch.first_batch / epoch.first_dispatch spans (telemetry/spans.py).
+        self.first_batch: Optional[tuple] = None
+        self.first_dispatch: Optional[tuple] = None
 
     # -- loop hooks ----------------------------------------------------
     def epoch_start(self) -> None:
@@ -77,6 +82,7 @@ class StepTimer:
         self._t_mark = time.perf_counter()
         self._data_s = 0.0
         self._dispatch_s = 0.0
+        self.first_batch = self.first_dispatch = None
 
     def wrap_epoch(self, it: Iterable) -> Iterator:
         """Pass-through iterator that accumulates time spent waiting on
@@ -88,7 +94,10 @@ class StepTimer:
                 item = next(it)
             except StopIteration:
                 return
-            self._data_s += time.perf_counter() - t0
+            t1 = time.perf_counter()
+            self._data_s += t1 - t0
+            if self.first_batch is None:
+                self.first_batch = (t0, t1)
             yield item
 
     def dispatch_start(self) -> None:
@@ -96,7 +105,10 @@ class StepTimer:
 
     def dispatch_end(self) -> None:
         if self._t_dispatch is not None:
-            self._dispatch_s += time.perf_counter() - self._t_dispatch
+            now = time.perf_counter()
+            self._dispatch_s += now - self._t_dispatch
+            if self.first_dispatch is None:
+                self.first_dispatch = (self._t_dispatch, now)
             self._t_dispatch = None
 
     def step_end(self, step: int) -> dict:

@@ -19,6 +19,7 @@ from tpuic.telemetry.goodput import (GoodputTracker, analytic_flops_per_step,
                                      hbm_bandwidth, peak_flops)
 from tpuic.telemetry.memory import MemorySampler
 from tpuic.telemetry.slo import SLOTracker, parse_objectives
+from tpuic.telemetry.spans import replay as replay_spans
 from tpuic.telemetry.steptime import StepTimer
 from tpuic.telemetry.tracing import TraceTrigger
 
@@ -67,6 +68,11 @@ class TrainTelemetry:
             sink = JsonlSink(rank_stream_path(jsonl, self.rank))
             self._sinks.append(sink)
             self._unsubs.append(bus.subscribe(sink))
+            # The stream starts with what ran before this sink existed:
+            # the import and trainer.* spans (telemetry/spans.py). Only
+            # this sink gets them; subscribers that were already there
+            # (the flight recorder) saw each span as it closed.
+            replay_spans(sink)
         # Supervised-liveness heartbeat (runtime/supervisor.py,
         # docs/robustness.md): when a supervisor parent set
         # TPUIC_HEARTBEAT_FILE for this process, mirror bus activity into

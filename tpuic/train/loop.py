@@ -28,12 +28,15 @@ import signal
 import time
 from typing import Optional
 
+_T_IMPORT = time.perf_counter()     # the 'import' span of this module
+
 import jax
 import numpy as np
 from tqdm import tqdm
 
 from tpuic.runtime import faults as _faults
 from tpuic.telemetry.events import publish as _tm_publish
+from tpuic.telemetry.spans import record as _record_span, span as _span
 
 from tpuic.checkpoint.manager import CheckpointManager
 from tpuic.config import Config
@@ -47,6 +50,10 @@ from tpuic.train.optimizer import make_optimizer, make_schedule
 from tpuic.train.state import create_train_state
 from tpuic.train.step import make_eval_step, make_train_step
 
+# What this module's import graph cost beyond what the caller had already
+# imported (docs/observability.md, "Spans").
+_record_span("import", _T_IMPORT, time.perf_counter(), module=__name__)
+
 
 def _async_copy(tree) -> None:
     """Start device->host transfers for every array in a metrics dict so the
@@ -59,6 +66,11 @@ def _async_copy(tree) -> None:
 
 class Trainer:
     def __init__(self, cfg: Config, mesh=None, log_dir: Optional[str] = None):
+        with _span("trainer.init", model=cfg.model.name) as sp:
+            self._init(cfg, mesh, log_dir)
+            sp.attrs["chips"] = self.mesh.size
+
+    def _init(self, cfg: Config, mesh, log_dir: Optional[str]) -> None:
         self.cfg = cfg
         self.mesh = mesh if mesh is not None else make_mesh(cfg.mesh)
         # On a single device the step is jitted without the mesh and its
@@ -66,148 +78,153 @@ class Trainer:
         # to shard over.
         step_mesh = self.mesh if self.mesh.size > 1 else None
         d = cfg.data
-        self.train_ds = ImageFolderDataset(d.data_dir, "train", d.resize_size, d)
-        self.val_ds = ImageFolderDataset(d.data_dir, "val", d.resize_size, d,
-                                         class_to_idx=self.train_ds.class_to_idx)
-        if d.pack:
-            # Decode-once packed cache + device-side augmentation: the only
-            # way a 1-core host feeds the chip (tpuic/data/pack.py docstring).
-            from tpuic.data.pack import pack_dataset
-            cache = d.cache_dir or os.path.join(d.data_dir, ".tpuic_pack")
-            self.train_ds = pack_dataset(self.train_ds, cache,
-                                         verbose=is_host0())
-            self.val_ds = pack_dataset(self.val_ds, cache, verbose=is_host0())
-        global_batch = self._build_loaders()
-        num_classes = cfg.model.num_classes or self.train_ds.num_classes
-        mcfg = cfg.model
-        if num_classes != mcfg.num_classes:
-            mcfg = dataclasses.replace(mcfg, num_classes=num_classes)
-        # Mixed-precision policy (docs/performance.md "Mixed-precision
-        # training"): compute_dtype is the one knob — it forces the flax
-        # forward dtype, the train step's batch cast and f32-loss guard
-        # (train/step.py), and the dtype-aware MFU roofline below. Master
-        # weights, optimizer moments, and checkpoints stay f32 regardless
-        # (param_dtype is untouched), so lifecycle/swap/elastic machinery
-        # never sees a bf16 artifact.
-        from tpuic.config import resolve_compute_dtype
-        compute_dtype = resolve_compute_dtype(mcfg)
-        if compute_dtype:
-            mcfg = dataclasses.replace(
-                mcfg, dtype=("bfloat16" if compute_dtype == "bf16"
-                             else "float32"))
-        if cfg.optim.auto_class_weights:
-            # Inverse-frequency CE weights from the train fold (what the
-            # reference's hand-tuned [3,3,10,1,4,4,5] approximated for its
-            # own dataset): w_c = N / (K_present * n_c), mean ~1 over the
-            # classes that actually occur. Sized by the RESOLVED head width
-            # so an explicit --num-classes larger than the fold's class
-            # count pads with weight 1.0 instead of tracing a shape error.
-            counts = self.train_ds.class_counts()
-            if len(counts) > num_classes:
-                raise ValueError(
-                    f"auto class weights: train fold has {len(counts)} "
-                    f"classes but the model head is {num_classes} wide")
-            counts = np.concatenate(
-                [counts, np.zeros(num_classes - len(counts), np.int64)])
-            w = np.ones(num_classes, np.float64)
-            present = counts > 0
-            w[present] = counts.sum() / (present.sum() * counts[present])
-            cfg = dataclasses.replace(cfg, optim=dataclasses.replace(
-                cfg.optim,
-                class_weights=tuple(round(float(x), 6) for x in w)))
-            self.cfg = cfg
-            host0_print("[weights] auto class weights: "
-                        + ", ".join(f"{c}={x:.3f}" for c, x in
-                                    zip(self.train_ds.classes,
-                                        cfg.optim.class_weights)))
-        self.mcfg = mcfg  # resolved model config (inferred num_classes)
-        self.model = create_model_from_config(mcfg, mesh=self.mesh)
-        steps = max(1, self.train_loader.steps_per_epoch())
-        self.schedule = make_schedule(cfg.optim, steps, cfg.run.epochs,
-                                      global_batch=global_batch)
-        tx = make_optimizer(cfg.optim, steps, cfg.run.epochs,
-                            global_batch=global_batch)
-        shape = (global_batch, d.resize_size, d.resize_size, 3)
-        with self.mesh:
-            self.state = create_train_state(
-                self.model, tx, jax.random.key(cfg.run.seed), shape,
-                ema=cfg.optim.ema_decay > 0)
-        from tpuic.utils import tree_bytes, tree_size
-        host0_print(f"[model] {mcfg.name}: "
-                    f"{tree_size(self.state.params) / 1e6:.1f}M params "
-                    f"({tree_bytes(self.state.params) / (1 << 20):.1f} MB), "
-                    f"{num_classes} classes, global batch {global_batch}")
-        # TP/FSDP state sharding (replicated when neither is requested —
-        # reference DDP semantics).
-        self.state_sharding = None
-        if step_mesh is not None and (cfg.mesh.fsdp or cfg.mesh.zero1 or (
-                cfg.mesh.tensor_parallel and self.mesh.shape["model"] > 1)):
-            from tpuic.parallel.sharding import shard_state, state_shardings
-            self.state_sharding = state_shardings(
-                self.state, self.mesh, tp=cfg.mesh.tensor_parallel,
-                fsdp=cfg.mesh.fsdp, zero1=cfg.mesh.zero1)
-            self.state = shard_state(self.state, self.state_sharding)
-        elif step_mesh is not None:
-            # Pure data parallelism: the state starts where every step
-            # leaves it, committed and replicated over the mesh. Left
-            # uncommitted on the default device, step 1 and step 2 would
-            # carry different input shardings and compile the step twice.
-            self.state = jax.device_put(self.state,
-                                        replicated_sharding(self.mesh))
-        self._build_steps()
-        self.last_misclassified: list = []
-        self.ckpt = CheckpointManager(cfg.run.ckpt_dir, mcfg.name,
-                                      cfg.run.save_period,
-                                      async_commit=cfg.run.async_checkpoint)
-        if is_host0():
-            # Reproducibility sidecar: the resolved config (incl. inferred
-            # num_classes / derived class weights) next to the checkpoint
-            # tracks. tpuic.predict reads it to auto-resolve the model.
-            resolved = dataclasses.replace(cfg, model=mcfg)
-            with open(os.path.join(self.ckpt.root, "config.json"), "w") as f:
-                json.dump(dataclasses.asdict(resolved), f, indent=2,
-                          default=str)
-            # Class-name sidecar: online serving (tpuic.serve) has no fold
-            # tree to derive display names from at request time.
-            with open(os.path.join(self.ckpt.root,
-                                   "class_to_idx.json"), "w") as f:
-                json.dump(self.train_ds.class_to_idx, f, indent=2)
-        # SIGTERM (pod preemption / scheduler eviction) -> finish the
-        # current step, flush a 'latest' checkpoint, return cleanly
-        # (runtime/preemption.py). The handler is installed for the span of
-        # fit() only; polling is a flag read per step, with a cross-host
-        # agreement at fixed boundaries on multi-host pods.
-        from tpuic.runtime.preemption import PreemptionGuard
-        self.preemption = PreemptionGuard()
-        # Elastic fleet membership (runtime/membership.py, docs/
-        # parallelism.md "Elastic data parallelism"): when the elastic
-        # gang supervisor injected TPUIC_MEMBERSHIP_FILE, the loop polls
-        # it at step boundaries (one os.stat when unchanged) and a
-        # 'degrade' transition re-forms THIS process in place — restore
-        # from the fleet-agreed step through the capped integrity
-        # ladder, recompile if the local mesh shrank — with no process
-        # restart. None (the common case) costs nothing.
-        from tpuic.runtime.membership import MembershipWatcher
-        self.membership = MembershipWatcher.from_env()
-        self._reform_pending = None
-        self.reforms = 0
-        self.logger = MetricLogger(log_dir)
-        self.start_epoch = 0
-        # Step offset into start_epoch (step-exact resume from a mid-epoch
-        # preemption flush); 0 for normal end-of-epoch checkpoints.
-        self.start_step = 0
-        self.best_score = 0.0
-        if cfg.run.init_from:
-            self._init_from_torch(cfg.run.init_from)
-        if cfg.run.resume:
-            # Newest of latest/best — a crash after the last val improvement
-            # resumes at the last periodic save instead of replaying epochs.
-            self.state, self.start_epoch, self.best_score = \
-                self.ckpt.restore_into(self.state)
-            self.start_step = self._validated_start_step()
-            if self.state_sharding is not None:
-                from tpuic.parallel.sharding import shard_state
+        with _span("trainer.data") as sp:
+            self.train_ds = ImageFolderDataset(d.data_dir, "train", d.resize_size, d)
+            self.val_ds = ImageFolderDataset(d.data_dir, "val", d.resize_size, d,
+                                             class_to_idx=self.train_ds.class_to_idx)
+            if d.pack:
+                # Decode-once packed cache + device-side augmentation: the only
+                # way a 1-core host feeds the chip (tpuic/data/pack.py docstring).
+                from tpuic.data.pack import pack_dataset
+                cache = d.cache_dir or os.path.join(d.data_dir, ".tpuic_pack")
+                self.train_ds = pack_dataset(self.train_ds, cache,
+                                             verbose=is_host0())
+                self.val_ds = pack_dataset(self.val_ds, cache, verbose=is_host0())
+            global_batch = self._build_loaders()
+            sp.attrs["images"] = len(self.train_ds)
+        with _span("trainer.state_init"):
+            num_classes = cfg.model.num_classes or self.train_ds.num_classes
+            mcfg = cfg.model
+            if num_classes != mcfg.num_classes:
+                mcfg = dataclasses.replace(mcfg, num_classes=num_classes)
+            # Mixed-precision policy (docs/performance.md "Mixed-precision
+            # training"): compute_dtype is the one knob — it forces the flax
+            # forward dtype, the train step's batch cast and f32-loss guard
+            # (train/step.py), and the dtype-aware MFU roofline below. Master
+            # weights, optimizer moments, and checkpoints stay f32 regardless
+            # (param_dtype is untouched), so lifecycle/swap/elastic machinery
+            # never sees a bf16 artifact.
+            from tpuic.config import resolve_compute_dtype
+            compute_dtype = resolve_compute_dtype(mcfg)
+            if compute_dtype:
+                mcfg = dataclasses.replace(
+                    mcfg, dtype=("bfloat16" if compute_dtype == "bf16"
+                                 else "float32"))
+            if cfg.optim.auto_class_weights:
+                # Inverse-frequency CE weights from the train fold (what the
+                # reference's hand-tuned [3,3,10,1,4,4,5] approximated for its
+                # own dataset): w_c = N / (K_present * n_c), mean ~1 over the
+                # classes that actually occur. Sized by the RESOLVED head width
+                # so an explicit --num-classes larger than the fold's class
+                # count pads with weight 1.0 instead of tracing a shape error.
+                counts = self.train_ds.class_counts()
+                if len(counts) > num_classes:
+                    raise ValueError(
+                        f"auto class weights: train fold has {len(counts)} "
+                        f"classes but the model head is {num_classes} wide")
+                counts = np.concatenate(
+                    [counts, np.zeros(num_classes - len(counts), np.int64)])
+                w = np.ones(num_classes, np.float64)
+                present = counts > 0
+                w[present] = counts.sum() / (present.sum() * counts[present])
+                cfg = dataclasses.replace(cfg, optim=dataclasses.replace(
+                    cfg.optim,
+                    class_weights=tuple(round(float(x), 6) for x in w)))
+                self.cfg = cfg
+                host0_print("[weights] auto class weights: "
+                            + ", ".join(f"{c}={x:.3f}" for c, x in
+                                        zip(self.train_ds.classes,
+                                            cfg.optim.class_weights)))
+            self.mcfg = mcfg  # resolved model config (inferred num_classes)
+            self.model = create_model_from_config(mcfg, mesh=self.mesh)
+            steps = max(1, self.train_loader.steps_per_epoch())
+            self.schedule = make_schedule(cfg.optim, steps, cfg.run.epochs,
+                                          global_batch=global_batch)
+            tx = make_optimizer(cfg.optim, steps, cfg.run.epochs,
+                                global_batch=global_batch)
+            shape = (global_batch, d.resize_size, d.resize_size, 3)
+            with self.mesh:
+                self.state = create_train_state(
+                    self.model, tx, jax.random.key(cfg.run.seed), shape,
+                    ema=cfg.optim.ema_decay > 0)
+            from tpuic.utils import tree_bytes, tree_size
+            host0_print(f"[model] {mcfg.name}: "
+                        f"{tree_size(self.state.params) / 1e6:.1f}M params "
+                        f"({tree_bytes(self.state.params) / (1 << 20):.1f} MB), "
+                        f"{num_classes} classes, global batch {global_batch}")
+            # TP/FSDP state sharding (replicated when neither is requested —
+            # reference DDP semantics).
+            self.state_sharding = None
+            if step_mesh is not None and (cfg.mesh.fsdp or cfg.mesh.zero1 or (
+                    cfg.mesh.tensor_parallel and self.mesh.shape["model"] > 1)):
+                from tpuic.parallel.sharding import shard_state, state_shardings
+                self.state_sharding = state_shardings(
+                    self.state, self.mesh, tp=cfg.mesh.tensor_parallel,
+                    fsdp=cfg.mesh.fsdp, zero1=cfg.mesh.zero1)
                 self.state = shard_state(self.state, self.state_sharding)
+            elif step_mesh is not None:
+                # Pure data parallelism: the state starts where every step
+                # leaves it, committed and replicated over the mesh. Left
+                # uncommitted on the default device, step 1 and step 2 would
+                # carry different input shardings and compile the step twice.
+                self.state = jax.device_put(self.state,
+                                            replicated_sharding(self.mesh))
+        with _span("trainer.build_steps"):
+            self._build_steps()
+        self.last_misclassified: list = []
+        with _span("trainer.checkpoint"):
+            self.ckpt = CheckpointManager(cfg.run.ckpt_dir, mcfg.name,
+                                          cfg.run.save_period,
+                                          async_commit=cfg.run.async_checkpoint)
+            if is_host0():
+                # Reproducibility sidecar: the resolved config (incl. inferred
+                # num_classes / derived class weights) next to the checkpoint
+                # tracks. tpuic.predict reads it to auto-resolve the model.
+                resolved = dataclasses.replace(cfg, model=mcfg)
+                with open(os.path.join(self.ckpt.root, "config.json"), "w") as f:
+                    json.dump(dataclasses.asdict(resolved), f, indent=2,
+                              default=str)
+                # Class-name sidecar: online serving (tpuic.serve) has no fold
+                # tree to derive display names from at request time.
+                with open(os.path.join(self.ckpt.root,
+                                       "class_to_idx.json"), "w") as f:
+                    json.dump(self.train_ds.class_to_idx, f, indent=2)
+            # SIGTERM (pod preemption / scheduler eviction) -> finish the
+            # current step, flush a 'latest' checkpoint, return cleanly
+            # (runtime/preemption.py). The handler is installed for the span of
+            # fit() only; polling is a flag read per step, with a cross-host
+            # agreement at fixed boundaries on multi-host pods.
+            from tpuic.runtime.preemption import PreemptionGuard
+            self.preemption = PreemptionGuard()
+            # Elastic fleet membership (runtime/membership.py, docs/
+            # parallelism.md "Elastic data parallelism"): when the elastic
+            # gang supervisor injected TPUIC_MEMBERSHIP_FILE, the loop polls
+            # it at step boundaries (one os.stat when unchanged) and a
+            # 'degrade' transition re-forms THIS process in place — restore
+            # from the fleet-agreed step through the capped integrity
+            # ladder, recompile if the local mesh shrank — with no process
+            # restart. None (the common case) costs nothing.
+            from tpuic.runtime.membership import MembershipWatcher
+            self.membership = MembershipWatcher.from_env()
+            self._reform_pending = None
+            self.reforms = 0
+            self.logger = MetricLogger(log_dir)
+            self.start_epoch = 0
+            # Step offset into start_epoch (step-exact resume from a mid-epoch
+            # preemption flush); 0 for normal end-of-epoch checkpoints.
+            self.start_step = 0
+            self.best_score = 0.0
+            if cfg.run.init_from:
+                self._init_from_torch(cfg.run.init_from)
+            if cfg.run.resume:
+                # Newest of latest/best — a crash after the last val improvement
+                # resumes at the last periodic save instead of replaying epochs.
+                self.state, self.start_epoch, self.best_score = \
+                    self.ckpt.restore_into(self.state)
+                self.start_step = self._validated_start_step()
+                if self.state_sharding is not None:
+                    from tpuic.parallel.sharding import shard_state
+                    self.state = shard_state(self.state, self.state_sharding)
         # Telemetry (docs/observability.md): step-time breakdown, goodput
         # accounting, optional JSONL event sink / trace trigger /
         # TensorBoard bridge — all host-side subscribers on the global
@@ -429,6 +446,10 @@ class Trainer:
         does not list this run's keys is reported but does not block
         (the geometry is local knowledge; the manifest is the fleet's
         memory of it)."""
+        with _span("prewarm"):
+            return self._prewarm(manifest_path)
+
+    def _prewarm(self, manifest_path: Optional[str]) -> dict:
         from tpuic.compiled import ProgramKey, load_manifest
         from tpuic.compiled import registry as _registry
         t0 = time.perf_counter()
@@ -566,42 +587,49 @@ class Trainer:
         ``self.last_epoch_steps`` records how many steps of this epoch are
         complete when the method returns — = steps_per_epoch normally,
         less if preemption broke the loop — for the mid-epoch flush."""
-        losses = AverageMeter()
-        remaining = len(self.train_loader) - start_step
-        self.last_epoch_steps = start_step
-        # Step-time breakdown (telemetry/steptime.py): the wrapped
-        # iterator times loader waits (data-wait), dispatch is timed
-        # around the step call below, and the residual is device time —
-        # pure perf_counter arithmetic, no host syncs added.
-        steptime = self.telemetry.steptime
-        steptime.epoch_start()
-        it = steptime.wrap_epoch(
-            self.train_loader.epoch(epoch, start_step=start_step))
-        bar = tqdm(it, total=remaining, disable=not is_host0())
-        metrics = None
-        log_every = max(1, self.cfg.run.log_every_steps)
-        global_batch = self.train_loader.global_batch
-        # One readback per EPOCH for the optimizer step counter: the in-loop
-        # step number is step0 + host steps, so logging never touches
-        # state.step on the hot path (each device_get is a blocking sync).
-        step0 = int(jax.device_get(self.state.step))  # tpuic-ok: TPU101 one read per EPOCH, off the steady-state path
-        # Deferred logging: at log point N we SCHEDULE an async device->host
-        # copy of the interval's metrics and DRAIN log point N-1, whose
-        # values the device finished an interval ago — so the drain returns
-        # from the transfer cache instead of stalling dispatch. The loop
-        # still cannot run away from the device: draining point N-1 throttles
-        # the host to at most one interval of run-ahead, which keeps the
-        # measured images/sec honest.
-        pending = None  # (host step number, images/sec, metric handles)
-        t_log = time.perf_counter()
-        from tpuic.runtime.preemption import agree
-        preempt_on = self.cfg.run.handle_preemption
-        multi = jax.process_count() > 1
-        # Multi-host: a locally-latched SIGTERM may only be acted on at a
-        # boundary every host reaches together (agree() is a collective);
-        # 16 steps of latency is well inside any grace window. With
-        # handle_preemption off, no polling (and no allgather) happens.
-        preempt_sync = 16
+        with _span("train_epoch", epoch=epoch) as sp:
+            loss = self._train_epoch(epoch, start_step)
+            sp.attrs["steps"] = self.last_epoch_steps - start_step
+        return loss
+
+    def _train_epoch(self, epoch: int, start_step: int) -> float:
+        with _span("epoch.head", epoch=epoch):
+            losses = AverageMeter()
+            remaining = len(self.train_loader) - start_step
+            self.last_epoch_steps = start_step
+            # Step-time breakdown (telemetry/steptime.py): the wrapped
+            # iterator times loader waits (data-wait), dispatch is timed
+            # around the step call below, and the residual is device time —
+            # pure perf_counter arithmetic, no host syncs added.
+            steptime = self.telemetry.steptime
+            steptime.epoch_start()
+            it = steptime.wrap_epoch(
+                self.train_loader.epoch(epoch, start_step=start_step))
+            bar = tqdm(it, total=remaining, disable=not is_host0())
+            metrics = None
+            log_every = max(1, self.cfg.run.log_every_steps)
+            global_batch = self.train_loader.global_batch
+            # One readback per EPOCH for the optimizer step counter: the in-loop
+            # step number is step0 + host steps, so logging never touches
+            # state.step on the hot path (each device_get is a blocking sync).
+            step0 = int(jax.device_get(self.state.step))  # tpuic-ok: TPU101 one read per EPOCH, off the steady-state path
+            # Deferred logging: at log point N we SCHEDULE an async device->host
+            # copy of the interval's metrics and DRAIN log point N-1, whose
+            # values the device finished an interval ago — so the drain returns
+            # from the transfer cache instead of stalling dispatch. The loop
+            # still cannot run away from the device: draining point N-1 throttles
+            # the host to at most one interval of run-ahead, which keeps the
+            # measured images/sec honest.
+            pending = None  # (host step number, images/sec, metric handles)
+            t_log = time.perf_counter()
+            from tpuic.runtime.preemption import agree
+            preempt_on = self.cfg.run.handle_preemption
+            multi = jax.process_count() > 1
+            # Multi-host: a locally-latched SIGTERM may only be acted on at a
+            # boundary every host reaches together (agree() is a collective);
+            # 16 steps of latency is well inside any grace window. With
+            # handle_preemption off, no polling (and no allgather) happens.
+            preempt_sync = 16
         for step, batch in enumerate(bar):
             # Fault-injection sites (runtime/faults.py; inert when unarmed):
             # 'sigterm' drives the REAL preemption path — the latch, the
@@ -696,6 +724,11 @@ class Trainer:
             steptime.dispatch_start()
             self.state, metrics = self.train_step(self.state, fbatch)
             steptime.dispatch_end()
+            if step == 0:
+                _record_span("epoch.first_batch", *steptime.first_batch,
+                             epoch=epoch)
+                _record_span("epoch.first_dispatch",
+                             *steptime.first_dispatch, epoch=epoch)
             self.last_epoch_steps = start_step + step + 1
             if (step + 1) % log_every == 0:
                 handles = {"loss": metrics["loss"],
@@ -741,38 +774,39 @@ class Trainer:
                 self._steps_exhausted = True
                 bar.close()
                 break
-        if pending is not None:
-            # Post-loop drain (break paths: budget/rollback/preemption —
-            # the in-loop last-step branch covers normal epoch ends): the
-            # blocking readback here is the final dispatched step still
-            # executing, i.e. device time AFTER its step event closed.
-            # Published as a 'drain' span so the goodput ledger books it
-            # as productive instead of losing it to 'other'.
-            t_drain = time.perf_counter()
-            self._drain_train_log(pending, losses, bar, epoch)
-            _tm_publish("drain",
-                        duration_s=round(time.perf_counter() - t_drain, 3))
-        # Epoch-mean loss over all steps, one sync, off the hot path: the
-        # running meter only sees logged points (display semantics identical
-        # to the reference bar, train.py:67-68).
-        if metrics is not None and losses.count == 0:
-            losses.update(
-                float(metrics["loss"]), 1)  # tpuic-ok: TPU101 post-loop epoch boundary, one sync
-        # Quarantine surfacing (docs/robustness.md): decode failures the
-        # data layer absorbed this epoch, one console line + JSONL record
-        # per epoch with events — a corrupt file is visible without being
-        # fatal.
-        q = self.train_loader.quarantine_count
-        if q > self._quarantine_seen:
-            delta = q - self._quarantine_seen
-            self._quarantine_seen = q
-            host0_print(f"[quarantine] epoch {epoch}: {delta} sample "
-                        f"load(s) served a replacement (total {q})")
-            self.logger.write(step0 + self.last_epoch_steps - start_step,
-                              quarantined=delta, quarantined_total=q)
-        _tm_publish("epoch", epoch=epoch,
-                    steps=self.last_epoch_steps - start_step,
-                    loss=round(losses.avg, 6))
+        with _span("epoch.tail", epoch=epoch):
+            if pending is not None:
+                # Post-loop drain (break paths: budget/rollback/preemption —
+                # the in-loop last-step branch covers normal epoch ends): the
+                # blocking readback here is the final dispatched step still
+                # executing, i.e. device time AFTER its step event closed.
+                # Published as a 'drain' span so the goodput ledger books it
+                # as productive instead of losing it to 'other'.
+                t_drain = time.perf_counter()
+                self._drain_train_log(pending, losses, bar, epoch)
+                _tm_publish("drain",
+                            duration_s=round(time.perf_counter() - t_drain, 3))
+            # Epoch-mean loss over all steps, one sync, off the hot path: the
+            # running meter only sees logged points (display semantics identical
+            # to the reference bar, train.py:67-68).
+            if metrics is not None and losses.count == 0:
+                losses.update(
+                    float(metrics["loss"]), 1)  # tpuic-ok: TPU101 post-loop epoch boundary, one sync
+            # Quarantine surfacing (docs/robustness.md): decode failures the
+            # data layer absorbed this epoch, one console line + JSONL record
+            # per epoch with events — a corrupt file is visible without being
+            # fatal.
+            q = self.train_loader.quarantine_count
+            if q > self._quarantine_seen:
+                delta = q - self._quarantine_seen
+                self._quarantine_seen = q
+                host0_print(f"[quarantine] epoch {epoch}: {delta} sample "
+                            f"load(s) served a replacement (total {q})")
+                self.logger.write(step0 + self.last_epoch_steps - start_step,
+                                  quarantined=delta, quarantined_total=q)
+            _tm_publish("epoch", epoch=epoch,
+                        steps=self.last_epoch_steps - start_step,
+                        loss=round(losses.avg, 6))
         return losses.avg
 
     def _drain_train_log(self, pending, losses: AverageMeter, bar,  # tpuic-ok: TPU101 THE deferred drain site
@@ -821,6 +855,10 @@ class Trainer:
         """Reference val_epoch (train.py:78-97): exact global accuracy ×100,
         plus the exact global weighted val CE (num/den accumulated
         separately)."""
+        with _span("val_epoch", epoch=epoch):
+            return self._val_epoch(epoch)
+
+    def _val_epoch(self, epoch: int) -> float:
         t_eval0 = time.perf_counter()
         correct = correct5 = count = loss_num = loss_den = 0.0
         have_top5 = False

@@ -357,10 +357,12 @@ def main(argv=None) -> int:
     from tpuic.telemetry.flight import install_flight_recorder
     flight = install_flight_recorder()
     install_stack_dump_handler(chain=flight is not None)
-    from tpuic.compiled.cache import enable_compile_cache
-    from tpuic.metrics.logging import host0_print
-    from tpuic.runtime.distributed import initialize
-    from tpuic.train.loop import Trainer
+    from tpuic.telemetry.spans import span
+    with span("import", module="train"):    # jax, flax, the whole program
+        from tpuic.compiled.cache import enable_compile_cache
+        from tpuic.metrics.logging import host0_print
+        from tpuic.runtime.distributed import initialize
+        from tpuic.train.loop import Trainer
 
     # The trainer runs on the platform JAX gives it and says which.
     cache_dir = enable_compile_cache()
