@@ -3,6 +3,7 @@
 
     python chip_smoke.py            # one TPU chip: train, resume, serve, kernels
     python chip_smoke.py --chips 4  # four chips: data-parallel train vs one chip
+                                    # (both: the resident loader's compiled gather)
 
 Drives the program through the entry points a user calls (``train.py``,
 ``python -m tpuic.serve``) at published widths — ResNet-50 and ViT-B/16 at
@@ -94,6 +95,24 @@ print("[leg] " + json.dumps({
     "params_replicated": all(x.is_fully_replicated for x in leaves)}),
     flush=True)
 trainer.fit()
+"""
+
+
+# The resident loader's per-batch program, compiled (nothing allocated) for
+# a corpus of 4,096 images a chip at 224 px against a batch of 8 a chip:
+# raises if its temporaries reach a quarter of the corpus. On four chips,
+# under the mesh.
+_RESIDENT_PREP = """
+import json
+import jax
+from tpuic.config import MeshConfig
+from tpuic.data.device_prep import check_resident_prep
+from tpuic.runtime.mesh import make_mesh
+
+n = len(jax.devices())
+mesh = make_mesh(MeshConfig(), jax.devices()) if n > 1 else None
+facts = check_resident_prep(224, rows=4096 * n, batch=8 * n, mesh=mesh)
+print("[resident_prep] " + json.dumps(dict(facts, devices=n)), flush=True)
 """
 
 
@@ -349,6 +368,18 @@ def kernels(s: Smoke) -> None:
     s.train_phase("kernels_ref", plain)
 
 
+def resident_prep(s: Smoke) -> None:
+    """A batch of the resident loader reads its rows of the corpus in
+    place: the compiled program holds no corpus-sized temporary."""
+    rc, out, err, secs = s.child("resident_prep", ["-c", _RESIDENT_PREP], 180)
+    m = re.search(r"^\[resident_prep\] (\{.*\})$", out, re.M)
+    problems = [f"exit code {rc}"] if rc else []
+    if not m:
+        problems.append("no [resident_prep] line on stdout")
+    s.phase("resident_prep", secs, problems, err,
+            **(json.loads(m.group(1)) if m else {}))
+
+
 def four_chips(s: Smoke) -> None:
     """Data parallelism over a data=4 mesh against the same global batch
     on one of the four chips: same seed, same data, five steps."""
@@ -411,6 +442,7 @@ def main() -> int:
                                  240)
     if s.phase("data", secs, [f"exit code {rc}"] if rc else [], err,
                train_images=640, val_images=64, px=224):
+        resident_prep(s)
         if args.chips == 4:
             four_chips(s)
         else:

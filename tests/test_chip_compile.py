@@ -1,4 +1,5 @@
-"""Compile-only rehearsal of every Pallas entry point for a described v5e.
+"""Compile-only rehearsal of every Pallas entry point, and of the resident
+loader's per-batch program, for a described v5e.
 
 The TPU compiler is installed with jax and compiles for a chip that is
 described, not attached (``jax.experimental.topologies``), so Mosaic
@@ -29,10 +30,10 @@ from tpuic.kernels import (flash_attention, fused_conv_bn_relu,
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """One described v5e chip's sharding, persistent cache off: a compile
-    for a described chip is written to the cache but cannot be read back
-    without one, and the next run would warn and compile again."""
+def chips():
+    """The four described chips of one v5e host, persistent cache off: a
+    compile for a described chip is written to the cache but cannot be read
+    back without one, and the next run would warn and compile again."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     try:
@@ -42,9 +43,15 @@ def chip():
         pytest.skip(f"cannot describe a v5e topology here: {e}")
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", True)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(chips):
+    """One described v5e chip's sharding."""
+    return SingleDeviceSharding(chips[0])
 
 
 def _compile(fn, chip, *shapes):
@@ -132,3 +139,25 @@ def test_fused_conv_bn_relu_refuses_the_224px_stem(chip):
     with pytest.raises(ValueError, match="MiB of VMEM"):
         _compile(fn, chip, ((8, 224, 224, 3), BF16), ((7, 7, 3, 64), BF16),
                  ((64,), F32), ((64,), F32))
+
+
+@pytest.mark.parametrize("size,rows,batch,meshed", [
+    (224, 5120, 128, False), (224, 5120, 64, False),
+    (224, 20480, 512, True), (299, 8192, 128, False)],
+    ids=["resnet50_cell", "vit_b16_cell", "dp4_cell", "px299_two_pieces"])
+def test_resident_prep_reads_rows_in_place(chips, size, rows, batch, meshed):
+    """The resident loader's per-batch program at the benchmark cells'
+    shapes (and at 299 px, where a row is gathered as two pieces): no
+    temporary of the compiled program is corpus-sized. Held as [N,S,S,3]
+    this read 881 MB against a corpus of 771 MB: the copy of the whole
+    corpus that ran in every step until PR 26."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from tpuic.data.device_prep import check_resident_prep
+    mesh = Mesh(np.array(chips), ("data",)) if meshed else None
+    facts = check_resident_prep(size, rows=rows, batch=batch, mesh=mesh,
+                                device=chips[0])
+    # A few float copies of one chip's batch, whatever the corpus.
+    per_chip = batch // (len(chips) if meshed else 1)
+    assert facts["temp_bytes"] <= 4 * per_chip * size * size * 3 * 4

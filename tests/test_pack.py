@@ -314,7 +314,8 @@ def test_packed_loader_serves_clean_images(tree, tmp_path, fold, loader_kw):
 def test_resident_upload_chunked(tree, tmp_path, monkeypatch):
     """Chunked resident upload (slow-link robustness): with a chunk budget
     smaller than the dataset, the device copy is assembled from several
-    slices and must equal the memmap bit-for-bit."""
+    slices and must equal the memmap bit-for-bit — in the held form, each
+    image one dense run of bytes."""
     from tpuic.data import pipeline as pl
 
     cfg = DataConfig(data_dir=tree, resize_size=32)
@@ -326,11 +327,119 @@ def test_resident_upload_chunked(tree, tmp_path, monkeypatch):
     monkeypatch.setattr(pl, "_UPLOAD_CHUNK_BYTES", 5 * row_bytes)
     loader = Loader(packed, global_batch=4, seed=7)
     assert loader.resident
-    np.testing.assert_array_equal(np.asarray(loader._data_dev),
-                                  np.asarray(packed.array()))
+    held = np.asarray(loader._data_dev)
+    assert held.shape == (len(packed), row_bytes // 128, 128)
+    np.testing.assert_array_equal(
+        held.reshape(len(packed), -1),
+        np.asarray(packed.array()).reshape(len(packed), -1))
     # The loader still serves correct batches through the chunked copy.
     batches = list(loader.epoch(0))
     assert len(batches) == len(loader)
+
+
+def _cpu_mesh():
+    import jax
+    from tpuic.config import MeshConfig
+    from tpuic.runtime.mesh import make_mesh
+    return make_mesh(MeshConfig(), jax.devices())
+
+
+@pytest.mark.parametrize("meshed", [False, True], ids=["nomesh", "mesh8"])
+@pytest.mark.parametrize("size,row_bytes", [(32, 3072), (30, 3072),
+                                            (299, 268288)],
+                         ids=["row3072B", "row2700B", "row268203B"])
+def test_resident_matches_streaming_bitwise(tree, tmp_path, size, row_bytes,
+                                            meshed):
+    """The resident path moves bytes and nothing else: for the same seed,
+    epoch and indices its batches are the streaming path's bit for bit,
+    augmentation on — for a row that is whole (8,128) tiles (32 px), one
+    that is padded (30 px: 2,700 B) and one gathered as two pieces (299 px,
+    the reference's default model), with and without a mesh."""
+    mesh = _cpu_mesh() if meshed else None
+    cfg = DataConfig(data_dir=tree, resize_size=size)
+    ds = ImageFolderDataset(tree, "train", size, cfg)
+    packed = pack_dataset(ds, str(tmp_path / "c6"), verbose=False)
+    resident = Loader(packed, global_batch=8, mesh=mesh, seed=11)
+    streaming = Loader(packed, global_batch=8, mesh=mesh, seed=11,
+                       device_cache_bytes=0)
+    assert resident.resident and not streaming.resident
+    assert resident.augment
+    # Padding is counted, and what is held is what is counted.
+    assert resident.resident_bytes == len(packed) * row_bytes
+    assert resident._data_dev.nbytes == resident.resident_bytes
+    n = 0
+    for a, b in zip(resident.epoch(3), streaming.epoch(3)):
+        assert a["image"].shape == (8, size, size, 3)
+        np.testing.assert_array_equal(np.asarray(a["image"]),
+                                      np.asarray(b["image"]))
+        np.testing.assert_array_equal(a.indices, b.indices)
+        n += 1
+    assert n == len(streaming) == 2
+
+
+def test_resident_rows_view_or_padded_copy():
+    """The held form costs the host nothing when a row needs no padding (a
+    view of the memmap, for the chunked upload and the mesh callback
+    alike) and one zero-padded copy when it does."""
+    from tpuic.data.device_prep import resident_rows
+    rng = np.random.default_rng(6)
+    whole = rng.integers(0, 256, (5, 32, 32, 3), np.uint8)
+    held = resident_rows(whole)
+    assert held.shape == (5, 24, 128) and np.shares_memory(held, whole)
+    assert np.shares_memory(resident_rows(whole[1:3]), whole)
+    ragged = rng.integers(0, 256, (5, 30, 30, 3), np.uint8)
+    held = resident_rows(ragged).reshape(5, -1)
+    assert held.shape == (5, 3072)
+    np.testing.assert_array_equal(held[:, :2700], ragged.reshape(5, -1))
+    assert not held[:, 2700:].any()
+
+
+@pytest.mark.parametrize("size,tiles,pieces", [
+    (32, 3, 1), (30, 3, 1), (224, 147, 1), (295, 255, 1), (296, 129, 2),
+    (299, 131, 2), (768, 247, 7)])
+def test_resident_geometry(size, tiles, pieces):
+    """A row is whole 1 KiB tiles, gathered in equal pieces of at most 256
+    (the largest slice the TPU's compiler gathers in place); the padding
+    that costs is counted in resident_row_bytes (296 px: 257 tiles held as
+    2 x 129)."""
+    from tpuic.data import device_prep as dp
+    assert dp._resident_geometry(size) == (tiles, pieces)
+    assert tiles <= dp._MAX_GATHER_TILES
+    row = dp.resident_row_bytes(size)
+    assert row == tiles * pieces * 1024 >= size * size * 3
+    assert row - size * size * 3 < 1024 * pieces
+    assert dp.resident_shape(7, size) == (7, row // 128, 128)
+
+
+@pytest.mark.parametrize("meshed", [False, True], ids=["nomesh", "mesh8"])
+@pytest.mark.parametrize("size", [32, 30])
+def test_resident_prep_holds_no_corpus_sized_temporary(size, meshed):
+    """A batch reads B rows of the corpus in place: the compiled program's
+    temporaries are of the order of the batch, not of the corpus. On a CPU
+    this catches a whole-corpus astype or transpose; chip_smoke.py runs the
+    same check on the TPU, where it catches a relayout of the operand."""
+    from tpuic.data.device_prep import check_resident_prep
+    facts = check_resident_prep(size, rows=4096, batch=8,
+                                mesh=_cpu_mesh() if meshed else None)
+    assert facts["corpus_bytes"] == 4096 * 3072
+    assert facts["temp_bytes"] < facts["corpus_bytes"] // 4
+
+
+def test_resident_prep_guard_trips_on_a_corpus_sized_temporary(monkeypatch):
+    """The guard itself: a gather written as a one-hot matmul (the form
+    the step uses for tables indexed by batch-sharded labels) converts the
+    whole corpus first, and is refused."""
+    import jax
+    import jax.numpy as jnp
+    from tpuic.data import device_prep as dp
+
+    def bad_prep(size, **_):
+        return jax.jit(lambda data, idx, packed: jnp.einsum(
+            "bn,nrl->brl", jax.nn.one_hot(idx, len(data), dtype=jnp.float32),
+            data.astype(jnp.float32)))
+    monkeypatch.setattr(dp, "make_resident_prep", bad_prep)
+    with pytest.raises(AssertionError, match="corpus-sized temporary"):
+        dp.check_resident_prep(32, rows=4096, batch=8)
 
 
 def test_packed_loader_start_step_serves_identical_remainder(tree, tmp_path):

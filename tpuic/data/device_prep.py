@@ -26,7 +26,7 @@ only *applies* pre-drawn decisions, vectorized per sample:
 
 from __future__ import annotations
 
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -117,22 +117,122 @@ def make_device_prep(mean=None, std=None, out_dtype=jnp.float32,
                    out_shardings=sharding, donate_argnums=(0,))
 
 
-def make_resident_prep(mean=None, std=None, out_dtype=jnp.float32,
+# The device-resident corpus is held as [N, R, 128] uint8: each image one
+# dense run of R*128 bytes, the image axis the only one the device does not
+# tile. The TPU tiles a uint8 array's two minor dimensions (8, 128), so a
+# row is whole tiles of 1 KiB, and its compiler gathers a slice in place
+# only up to 256 KiB (past that it slices the whole operand first), so a
+# longer row is gathered as equal pieces of at most that. Rows are padded
+# up to fit both; at 32 px (3 tiles), 224 px (147) and 299 px (2 x 131)
+# there is nothing to pad, at 768 px 1 KiB a row.
+_LANES = 128
+_TILE_BYTES = 8 * _LANES
+_MAX_GATHER_TILES = 256
+
+
+def _resident_geometry(size: int) -> Tuple[int, int]:
+    """(tiles a gathered piece, pieces a row) of a ``size``-px image."""
+    tiles = -(-size * size * 3 // _TILE_BYTES)
+    pieces = -(-tiles // _MAX_GATHER_TILES)
+    return -(-tiles // pieces), pieces
+
+
+def resident_row_bytes(size: int) -> int:
+    """Bytes one ``size``-px image takes in the held form (padding counted)."""
+    tiles, pieces = _resident_geometry(size)
+    return tiles * pieces * _TILE_BYTES
+
+
+def resident_shape(n: int, size: int) -> Tuple[int, int, int]:
+    """Shape of the held form of ``n`` images of ``size`` px."""
+    return (n, resident_row_bytes(size) // _LANES, _LANES)
+
+
+def resident_rows(images: np.ndarray) -> np.ndarray:
+    """Host [n,S,S,3] uint8 -> the held form [n,R,128].
+
+    A view of a contiguous array (the packed memmap, a slice of it) when a
+    row needs no padding; a zero-padded copy otherwise."""
+    n, size = images.shape[:2]
+    flat = images.reshape(n, -1)
+    pad = resident_row_bytes(size) - flat.shape[1]
+    if pad:
+        flat = np.pad(flat, ((0, 0), (0, pad)))
+    return flat.reshape(n, -1, _LANES)
+
+
+def make_resident_prep(size: int, mean=None, std=None,
+                       out_dtype=jnp.float32,
                        sharding: Optional[jax.sharding.NamedSharding] = None,
                        replicated=None):
-    """Jitted (dataset_u8 [N,S,S,3], indices [B] i32, packed_params) ->
-    normalized batch, for the DEVICE-RESIDENT dataset cache.
+    """Jitted (corpus_u8 [N,R,128], indices [B] i32, packed_params) ->
+    normalized [B,size,size,3] batch, for the DEVICE-RESIDENT dataset cache.
 
     The whole packed uint8 dataset lives in HBM (uploaded once, replicated
-    under a mesh); a batch costs one [B]-row gather + augment + normalize
-    ON DEVICE. Per-step host->device traffic is the index/param vectors —
-    a few KB — instead of the image bytes. This is what makes the training
-    loop immune to host-link bandwidth."""
+    under a mesh) in the row-contiguous form of ``resident_rows``; a batch
+    is one gather of B rows read in place, reshaped to [B,S,S,3] only then,
+    + augment + normalize ON DEVICE. No op of the program has an operand
+    or a result that grows with N, so a step costs the device B rows of
+    traffic and the host the index/param vectors (a few KB).
+
+    The form is the point. Held as [N,S,S,3] the corpus arrives in the
+    TPU's default layout for that shape (N minor-most) and the gather wants
+    N major-most, so the compiler put a copy of all N rows in front of
+    every batch: 3.68 ms a step at 0.77 GB, 14.7 ms at 3.1 GB (ledger, PR
+    25). A flat [N,S*S*3] does no better: N is then tiled, rows interleave
+    inside tiles, and the compiler slices the whole corpus (PERF.md PR 26).
+    check_resident_prep is the guard."""
+    row = size * size * 3
+    tiles, pieces = _resident_geometry(size)
+
     def fn(data, idx, packed):
-        imgs = jnp.take(data, idx, axis=0)
+        batch = idx.shape[0]
+        # [N,R,128] -> [N*pieces, 8*tiles, 128] splits whole tiles off the
+        # untiled axis: no bytes move. One piece a row at 224 px.
+        held = data.reshape(-1, 8 * tiles, _LANES)
+        at = (idx[:, None] * pieces
+              + jnp.arange(pieces, dtype=idx.dtype)).reshape(-1)
+        rows = jnp.take(held, at, axis=0).reshape(batch, -1)
+        imgs = rows[:, :row].reshape(batch, size, size, 3)
         return apply_batch_augment(imgs, _unpack_params(packed), mean=mean,
                                    std=std, out_dtype=out_dtype)
     if sharding is None:
         return jax.jit(fn)
     return jax.jit(fn, in_shardings=(replicated, sharding, sharding),
                    out_shardings=sharding)
+
+
+def check_resident_prep(size: int, rows: int = 4096, batch: int = 8,
+                        mesh: Optional[jax.sharding.Mesh] = None,
+                        device=None) -> Dict:
+    """Compile the resident prep for a corpus >> batch and assert it holds
+    no corpus-sized temporary.
+
+    Only shapes are handed to the compiler; nothing is allocated. On the
+    TPU (chip_smoke.py, and a described one in tests/test_chip_compile.py)
+    this is the check that would have caught the per-step copy of the whole
+    corpus; on a CPU it catches a gather that converts the corpus first.
+    Compiles for the default backend's first device, for ``device``, or,
+    with ``batch`` the global batch, for ``mesh``."""
+    from jax.sharding import (NamedSharding, PartitionSpec as P,
+                              SingleDeviceSharding)
+
+    if mesh is not None:
+        repl, shard = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+        prep = make_resident_prep(size, sharding=shard, replicated=repl)
+    else:
+        repl = shard = (None if device is None
+                        else SingleDeviceSharding(device))
+        prep = make_resident_prep(size)
+    shape = resident_shape(rows, size)
+    compiled = prep.lower(
+        jax.ShapeDtypeStruct(shape, jnp.uint8, sharding=repl),
+        jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=shard),
+        jax.ShapeDtypeStruct((batch, len(PARAM_KEYS)), jnp.float32,
+                             sharding=shard)).compile()
+    facts = {"corpus_bytes": int(np.prod(shape)),
+             "temp_bytes": int(compiled.memory_analysis().temp_size_in_bytes)}
+    if 4 * facts["temp_bytes"] >= facts["corpus_bytes"]:
+        raise AssertionError(
+            f"the resident prep holds a corpus-sized temporary: {facts}")
+    return facts
