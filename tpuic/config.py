@@ -124,14 +124,17 @@ class ModelConfig:
     #                 are never residuals; recompute is one einsum + softmax
     #                 per layer. No-op for models/impls with no dense
     #                 attention core (ResNet; flash never materializes it).
-    #   'blocks'    — ViT ``remat_blocks``: each encoder block under
-    #                 nn.remat with the save-nothing policy, so the only
-    #                 N-sized residuals are the block inputs and the
-    #                 backward recomputes one block at a time. The
-    #                 long-context memory mode: at N=4097/b16 'dots' needs
-    #                 19.5 GB (flash) / 41.1 GB (dense) vs 15.75 HBM
-    #                 (PERF_ANALYSIS.md §10f). Composes with any attention
-    #                 impl; ViT-only (warns and no-ops elsewhere).
+    #   'blocks'    — ViT and looped-stack (ouro-*) ``remat_blocks``: each
+    #                 block under nn.remat with the save-nothing policy, so
+    #                 the only N-sized residuals are the block inputs and
+    #                 the backward recomputes one block at a time. The
+    #                 ViT's long-context memory mode: at N=4097/b16 'dots'
+    #                 needs 19.5 GB (flash) / 41.1 GB (dense) vs 15.75 HBM
+    #                 (PERF_ANALYSIS.md §10f). For the looped stack it is
+    #                 the memory mode: activations grow with layers x
+    #                 passes, weights do not. Composes with any attention
+    #                 impl; these two families only (warns and no-ops
+    #                 elsewhere).
     #   'gelu'      — ViT ``remat_mlp``: each block's Dense(mlp_up)+GELU
     #                 runs under nn.remat (models/vit.py MlpUpGelu), so
     #                 the [B,N,4D] pre-activation is never a residual —
@@ -145,6 +148,10 @@ class ModelConfig:
     remat_policy: str = "dots"
     # Inception aux-logits loss weight (reference train.py:52).
     aux_loss_weight: float = 0.4
+    # Looped models (models/ouro.py): weight beta of the entropy term in
+    # ``train/loss.py::exit_expected_loss``, which keeps the learned exit
+    # distribution from collapsing onto one pass.
+    exit_entropy_weight: float = 0.05
     # MoE load-balancing loss weight (Switch Transformer's alpha; only
     # active for *-moe models, which sow 'moe_router' stats that the train
     # step turns into a padding-masked switch_aux_loss).

@@ -20,6 +20,7 @@ from tpuic.models import resnet as _resnet
 from tpuic.models import efficientnet as _effnet
 from tpuic.models import inception as _inception
 from tpuic.models import vit as _vit
+from tpuic.models import ouro as _ouro
 
 # name -> (factory(num_classes, dtype, param_dtype, bn_momentum, bn_eps),
 #          has_aux)
@@ -178,6 +179,24 @@ def _register_builtins():
     # 'model' axis; beyond-parity (reference is dense-only, SURVEY.md §2c).
     register("vit-s16-moe", _vit_factory(_vit.vit_s16_moe))
     register("vit-tiny-moe", _vit_factory(_vit.vit_tiny_moe))
+
+    def _looped(ctor, **extra):
+        def make(*, num_classes, dtype, param_dtype, bn_momentum, bn_eps,
+                 attention, mesh, bn_f32_stats, drop_path, remat_core,
+                 remat_blocks, remat_mlp, fused_conv_bn):
+            # RMSNorm only; the causal rotary core is dense (196 tokens)
+            del (num_classes, bn_momentum, bn_eps, bn_f32_stats, attention,
+                 mesh, drop_path, remat_core, remat_mlp, fused_conv_bn)
+            return ctor(dtype=dtype, param_dtype=param_dtype,
+                        remat_blocks=remat_blocks, **extra)
+        return make
+
+    # Looped decoder stack as a backbone (models/ouro.py): Ouro-2.6B at its
+    # published depth, and the first pipeline stage of eight (6 of the 48
+    # layers; every width and the four passes as published).
+    register("ouro-2.6b", _looped(_ouro.ouro_2_6b))
+    register("ouro-2.6b-l6", _looped(_ouro.ouro_2_6b, depth=6))
+    register("ouro-tiny", _looped(_ouro.ouro_tiny))
 
     def _inc(*, num_classes, dtype, param_dtype, bn_momentum, bn_eps,
              attention, mesh, bn_f32_stats, drop_path, remat_core,

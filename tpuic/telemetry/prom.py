@@ -22,6 +22,7 @@ Transport is the caller's choice: ``write_exposition`` dumps to a file
 from __future__ import annotations
 
 import os
+import re
 import threading
 from typing import Callable, Iterable, List, Optional, Tuple
 
@@ -549,7 +550,8 @@ def train_exposition(report: dict, steptime: Optional[dict] = None,
                      heartbeat_age_s: Optional[float] = None,
                      slo: Optional[dict] = None,
                      memory: Optional[dict] = None,
-                     profile: Optional[dict] = None) -> str:
+                     profile: Optional[dict] = None,
+                     exits: Optional[dict] = None) -> str:
     """GoodputTracker.report() (+ StepTimer.summary()) -> Prometheus text.
 
     ``heartbeat_age_s`` as in :func:`serve_exposition`; ``restart_count``
@@ -558,7 +560,9 @@ def train_exposition(report: dict, steptime: Optional[dict] = None,
     ``slo``: an SLOTracker.report() for the step-time objectives.
     ``memory``: a MemorySampler.snapshot() (telemetry/memory.py).
     ``profile``: the last device-time waterfall (telemetry/profile.py,
-    ``CaptureAnalyzer.last``) for ``device_time_ms{op_class}`` rows."""
+    ``CaptureAnalyzer.last``) for ``device_time_ms{op_class}`` rows.
+    ``exits``: a looped model's last drained exit counters
+    (``Trainer.last_exit_stats``)."""
     rows: List[Tuple] = [
         _process_rss_row(),
         ("restart_count", report.get("restarts"), "counter",
@@ -603,6 +607,23 @@ def train_exposition(report: dict, steptime: Optional[dict] = None,
             rows.append((name, v, "gauge",
                          "step-time percentiles over the sliding window",
                          {"quantile": q}))
+    exits = exits or {}
+    rows.append(("exit_expected_pass", exits.get("exit_expected_pass"),
+                 "gauge", "looped model: batch mean of the expected exit "
+                 "pass under the learned exit distribution", None))
+    rows.append(("exit_entropy", exits.get("exit_entropy"), "gauge",
+                 "looped model: batch mean entropy of the learned exit "
+                 "distribution", None))
+    for k, v in sorted(exits.items()):
+        by_pass = re.fullmatch(r"(exit_p|loss_pass)(\d+)", k)
+        if by_pass and by_pass[1] == "exit_p":
+            rows.append(("exit_probability", v, "gauge", "looped model: "
+                         "batch mean exit probability of each pass",
+                         {"pass": by_pass[2]}))
+        elif by_pass:
+            rows.append(("pass_loss", v, "gauge", "looped model: batch "
+                         "mean cross-entropy of each pass's logits",
+                         {"pass": by_pass[2]}))
     rows.extend(profile_rows(profile))
     rows.extend(memory_rows(memory))
     rows.extend(slo_rows(slo))

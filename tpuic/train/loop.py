@@ -55,6 +55,11 @@ from tpuic.train.step import make_eval_step, make_train_step
 _record_span("import", _T_IMPORT, time.perf_counter(), module=__name__)
 
 
+# Step-metric prefixes of a looped model's exit counters: loss_pass<t>,
+# exit_p<t>, exit_expected_pass, exit_entropy.
+_EXIT_COUNTERS = ("loss_pass", "exit_")
+
+
 def _async_copy(tree) -> None:
     """Start device->host transfers for every array in a metrics dict so the
     later (deferred) device_get returns from the transfer cache instead of
@@ -92,7 +97,7 @@ class Trainer:
                 self.val_ds = pack_dataset(self.val_ds, cache, verbose=is_host0())
             global_batch = self._build_loaders()
             sp.attrs["images"] = len(self.train_ds)
-        with _span("trainer.state_init"):
+        with _span("trainer.state_init") as sp:
             num_classes = cfg.model.num_classes or self.train_ds.num_classes
             mcfg = cfg.model
             if num_classes != mcfg.num_classes:
@@ -148,6 +153,11 @@ class Trainer:
                     self.model, tx, jax.random.key(cfg.run.seed), shape,
                     ema=cfg.optim.ema_decay > 0)
             from tpuic.utils import tree_bytes, tree_size
+            # What persists between steps, from shapes alone (no device
+            # work): the benchmark's step_transient_gib subtracts them
+            # from the peak.
+            sp.attrs["param_bytes"] = tree_bytes(self.state.params)
+            sp.attrs["opt_state_bytes"] = tree_bytes(self.state.opt_state)
             host0_print(f"[model] {mcfg.name}: "
                         f"{tree_size(self.state.params) / 1e6:.1f}M params "
                         f"({tree_bytes(self.state.params) / (1 << 20):.1f} MB), "
@@ -172,6 +182,7 @@ class Trainer:
         with _span("trainer.build_steps"):
             self._build_steps()
         self.last_misclassified: list = []
+        self.last_exit_stats: dict = {}     # looped models; see the drain
         with _span("trainer.checkpoint"):
             self.ckpt = CheckpointManager(cfg.run.ckpt_dir, mcfg.name,
                                           cfg.run.save_period,
@@ -590,6 +601,10 @@ class Trainer:
         with _span("train_epoch", epoch=epoch) as sp:
             loss = self._train_epoch(epoch, start_step)
             sp.attrs["steps"] = self.last_epoch_steps - start_step
+            exits = getattr(self, "last_exit_stats", {})
+            if "exit_expected_pass" in exits:
+                # looped models: the epoch's last drained value
+                sp.attrs["exit_expected_pass"] = exits["exit_expected_pass"]
         return loss
 
     def _train_epoch(self, epoch: int, start_step: int) -> float:
@@ -740,6 +755,10 @@ class Trainer:
                     # deferred drain as the other metrics — rollback
                     # detection costs zero extra host syncs.
                     handles["skip_count"] = metrics["skip_count"]
+                # A looped model's exit counters (train/loss.py
+                # exit_expected_loss) ride the same drain.
+                handles.update({k: v for k, v in metrics.items()
+                                if k.startswith(_EXIT_COUNTERS)})
                 _async_copy(handles)
                 now = time.perf_counter()
                 imgs_per_sec = log_every * global_batch / max(now - t_log,
@@ -837,6 +856,12 @@ class Trainer:
             delta = streak - last if streak > last else streak
             _tm_publish("skip", step=step_num, streak=streak, delta=delta)
         self._last_skip_streak = streak
+        exits = {k: float(v) for k, v in vals.items()
+                 if k.startswith(_EXIT_COUNTERS)}
+        if exits:
+            # kept for the train_epoch span and the Prometheus rows
+            self.last_exit_stats = exits
+            extra.update(exits)
         self.logger.write(step_num, loss=loss,
                           accuracy=float(vals["accuracy"]),
                           lr=float(vals.get("lr", 0.0)),

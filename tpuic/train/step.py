@@ -34,7 +34,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tpuic.config import ModelConfig, OptimConfig, resolve_compute_dtype
 from tpuic.metrics.meters import accuracy, topk_accuracy
-from tpuic.train.loss import classification_loss
+from tpuic.models.classifier import ExitOutputs
+from tpuic.train.loss import classification_loss, exit_expected_loss
 from tpuic.train.state import TrainState
 
 
@@ -119,16 +120,18 @@ def resolve_remat_policy(model_cfg: ModelConfig):
                 stacklevel=2)
         return None
     if model_cfg.remat_policy == "blocks":
-        # Per-encoder-block nn.remat lives in the model (ViT
-        # ``remat_blocks``): residuals are the block inputs only, the
-        # backward recomputes one block at a time. The long-context
-        # memory mode — see ModelConfig.remat_policy.
-        if "vit" not in model_cfg.name:
+        # Per-block nn.remat lives in the model (ViT and the looped
+        # stack, ``remat_blocks``): residuals are the block inputs only,
+        # the backward recomputes one block at a time. The long-context
+        # memory mode of the ViT and the looped stack's only one (its
+        # activations grow with layers x passes, its weights do not) —
+        # see ModelConfig.remat_policy.
+        if not any(f in model_cfg.name for f in ("vit", "ouro")):
             warnings.warn(
                 f"remat_policy='blocks' has no effect for model="
-                f"'{model_cfg.name}': only the ViT encoder has "
-                "per-block remat; NO remat is applied. Use "
-                "remat_policy='dots' for whole-forward remat.",
+                f"'{model_cfg.name}': only the ViT encoder and the looped "
+                "stack (ouro-*) have per-block remat; NO remat is applied. "
+                "Use remat_policy='dots' for whole-forward remat.",
                 stacklevel=2)
         return None
     raise ValueError(f"unknown remat_policy '{model_cfg.remat_policy}'; "
@@ -153,6 +156,7 @@ def make_train_step(optim_cfg: OptimConfig, model_cfg: ModelConfig,
     class_weights = (jnp.asarray(optim_cfg.class_weights, jnp.float32)
                      if optim_cfg.class_weights else None)
     aux_w = model_cfg.aux_loss_weight
+    exit_beta = model_cfg.exit_entropy_weight
     smoothing = optim_cfg.label_smoothing
     remat_policy = resolve_remat_policy(model_cfg)
     # Mixed-precision policy (ModelConfig.compute_dtype): under 'bf16' the
@@ -347,20 +351,25 @@ def make_train_step(optim_cfg: OptimConfig, model_cfg: ModelConfig,
                 out = jax.tree.map(lambda t: t.astype(jnp.float32), out)
             # 'loss' scope: CE (+aux) ops separate from the backbone's
             # layers in the device-time waterfall (telemetry/profile.py).
-            with jax.named_scope("loss"):
-                loss = classification_loss(
-                    out, labels, class_weights=class_weights, mask=mask,
+            def loss_of(lbls):
+                """``(loss, counters)`` against ``lbls``: the looped
+                model's expectation over exits, else CE (+aux)."""
+                if isinstance(out, ExitOutputs):
+                    with jax.named_scope("exit_loss"):
+                        return exit_expected_loss(
+                            out, lbls, class_weights=class_weights,
+                            mask=mask, label_smoothing=smoothing,
+                            entropy_weight=exit_beta)
+                return classification_loss(
+                    out, lbls, class_weights=class_weights, mask=mask,
                     aux_weight=aux_w, label_smoothing=smoothing,
                     impl="fused" if optim_cfg.fused_loss
-                    else "reference", mesh=mesh)
+                    else "reference", mesh=mesh), {}
+
+            with jax.named_scope("loss"):
+                loss, counters = loss_of(labels)
                 if labels_mix is not None:
-                    loss_b = classification_loss(
-                        out, labels_mix, class_weights=class_weights,
-                        mask=mask, aux_weight=aux_w,
-                        label_smoothing=smoothing,
-                        impl="fused" if optim_cfg.fused_loss
-                        else "reference", mesh=mesh)
-                    loss = lam * loss + (1.0 - lam) * loss_b
+                    loss = lam * loss + (1.0 - lam) * loss_of(labels_mix)[0]
                 routers = _moe_router_stats(mutated.get("intermediates",
                                                         {}))
                 if routers and model_cfg.moe_aux_weight:
@@ -369,8 +378,12 @@ def make_train_step(optim_cfg: OptimConfig, model_cfg: ModelConfig,
                               for p, o in routers)
                     loss = loss + (model_cfg.moe_aux_weight * aux
                                    / len(routers))
-            logits = out[0] if isinstance(out, tuple) else out
-            return loss, (mutated.get("batch_stats", state.batch_stats), logits)
+            if isinstance(out, ExitOutputs):
+                logits = out.logits[-1]     # accuracy reads the last pass
+            else:
+                logits = out[0] if isinstance(out, tuple) else out
+            return loss, (mutated.get("batch_stats", state.batch_stats),
+                          logits, counters)
 
         if loss_scale != 1.0:
             # Static loss scaling (OptimConfig.loss_scale): backward runs
@@ -381,14 +394,14 @@ def make_train_step(optim_cfg: OptimConfig, model_cfg: ModelConfig,
             def scaled_loss_fn(params):
                 loss, aux = loss_fn(params)
                 return loss * loss_scale, aux
-            (loss, (new_stats, logits)), grads = jax.value_and_grad(
-                scaled_loss_fn, has_aux=True)(state.params)
+            (loss, (new_stats, logits, counters)), grads = \
+                jax.value_and_grad(scaled_loss_fn, has_aux=True)(state.params)
             inv = 1.0 / loss_scale
             loss = loss * inv
             grads = jax.tree.map(lambda g: g * inv, grads)
         else:
-            (loss, (new_stats, logits)), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(state.params)
+            (loss, (new_stats, logits, counters)), grads = \
+                jax.value_and_grad(loss_fn, has_aux=True)(state.params)
         grad_norm = optax.global_norm(grads)
 
         @jax.named_scope("optimizer_update")
@@ -464,8 +477,10 @@ def make_train_step(optim_cfg: OptimConfig, model_cfg: ModelConfig,
                 acc_mean = jnp.sum(acc * m) / jnp.maximum(jnp.sum(m), 1.0)
             else:
                 acc_mean = jnp.mean(acc)
+        # ``counters``: a looped model's exit statistics (loss_pass<t>,
+        # exit_p<t>, exit_expected_pass, exit_entropy); empty otherwise.
         metrics = {"loss": loss, "accuracy": acc_mean,
-                   "grad_norm": grad_norm}
+                   "grad_norm": grad_norm, **counters}
         if optim_cfg.skip_nonfinite:
             metrics["skipped"] = 1.0 - finite.astype(jnp.float32)
             if new_state.skip_count is not None:

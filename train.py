@@ -187,8 +187,9 @@ def build_parser() -> argparse.ArgumentParser:
                    help="what --remat saves: 'dots' recomputes all "
                         "activation-sized tensors; 'attention' recomputes "
                         "ONLY the [B,H,N,N] attention logits/probs (ViT); "
-                        "'blocks' saves only encoder-block inputs (ViT "
-                        "long-context memory mode); 'gelu' drops only the "
+                        "'blocks' saves only the block inputs (ViT "
+                        "long-context memory mode; the looped ouro-* "
+                        "stack's memory mode); 'gelu' drops only the "
                         "ViT MLP pre-activations (lightest — one fewer "
                         "[B,N,4D] HBM write/read per block)")
     p.add_argument("--drop-path", type=float, default=0.0,
@@ -411,7 +412,8 @@ def main(argv=None) -> int:
                     heartbeat_age_s=hb.age_s() if hb is not None else None,
                     slo=slo.report() if slo is not None else None,
                     memory=trainer.telemetry.memory.snapshot(),
-                    profile=prof.last if prof is not None else None))
+                    profile=prof.last if prof is not None else None,
+                    exits=trainer.last_exit_stats))
             subscribe(_prom_dump, kinds=("goodput",))
     try:
         best = trainer.fit()
