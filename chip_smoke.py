@@ -100,8 +100,9 @@ trainer.fit()
 
 # The resident loader's per-batch program, compiled (nothing allocated) for
 # a corpus of 4,096 images a chip at 224 px against a batch of 8 a chip:
-# raises if its temporaries reach a quarter of the corpus. On four chips,
-# under the mesh.
+# raises if its temporaries reach a quarter of the corpus, or if the
+# augmentation reverses a float32 copy of the batch (the geometry belongs
+# on the uint8 rows). On four chips, under the mesh.
 _RESIDENT_PREP = """
 import json
 import jax

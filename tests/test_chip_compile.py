@@ -141,6 +141,18 @@ def test_fused_conv_bn_relu_refuses_the_224px_stem(chip):
                  ((64,), F32), ((64,), F32))
 
 
+def _resident_prep_facts(chips, size, rows, batch, meshed):
+    """check_resident_prep for one described chip, or for the four under a
+    ``data`` mesh with ``batch`` the global batch."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from tpuic.data.device_prep import check_resident_prep
+    mesh = Mesh(np.array(chips), ("data",)) if meshed else None
+    return check_resident_prep(size, rows=rows, batch=batch, mesh=mesh,
+                               device=chips[0])
+
+
 @pytest.mark.parametrize("size,rows,batch,meshed", [
     (224, 5120, 128, False), (224, 5120, 64, False),
     (224, 20480, 512, True), (299, 8192, 128, False)],
@@ -151,13 +163,26 @@ def test_resident_prep_reads_rows_in_place(chips, size, rows, batch, meshed):
     temporary of the compiled program is corpus-sized. Held as [N,S,S,3]
     this read 881 MB against a corpus of 771 MB: the copy of the whole
     corpus that ran in every step until PR 26."""
-    import numpy as np
-    from jax.sharding import Mesh
-
-    from tpuic.data.device_prep import check_resident_prep
-    mesh = Mesh(np.array(chips), ("data",)) if meshed else None
-    facts = check_resident_prep(size, rows=rows, batch=batch, mesh=mesh,
-                                device=chips[0])
+    facts = _resident_prep_facts(chips, size, rows, batch, meshed)
     # A few float copies of one chip's batch, whatever the corpus.
     per_chip = batch // (len(chips) if meshed else 1)
     assert facts["temp_bytes"] <= 4 * per_chip * size * size * 3 * 4
+
+
+# What the compiler counted for the parent of PR 30 (four rot90 variants
+# of the batch in float32, selected among): the record the composed
+# geometry is held against, in bytes.
+@pytest.mark.parametrize("rows,batch,meshed,was,share", [
+    (5120, 128, False, 2228.7e6, 0.40), (5120, 64, False, 1546.5e6, 0.40),
+    (20480, 512, True, 2228.7e6, 0.40), (256, 32, False, 1104.5e6, 0.60)],
+    ids=["resnet50_cell", "vit_b16_cell", "dp4_cell", "ouro_cell"])
+def test_resident_prep_moves_the_batch_as_bytes(chips, rows, batch, meshed,
+                                                was, share):
+    """The augmentation's geometry at the four cells' shapes: whatever is
+    reversed is the uint8 batch (check_resident_prep refuses a float32
+    reversal of the batch's size), and the compiler's count of the
+    program's traffic stays at most this share of what it was when every
+    image was rotated four ways in float32."""
+    facts = _resident_prep_facts(chips, 224, rows, batch, meshed)
+    assert facts["f32_reversals"] == []
+    assert facts["bytes_accessed"] <= share * was, facts

@@ -238,6 +238,97 @@ def test_device_prep_identity_params_is_normalize():
     assert np.abs(out - ref).max() < 1e-5
 
 
+_DECISIONS = [(k, vf, hf) for k in range(4) for vf in (0, 1) for hf in (0, 1)]
+
+
+def _reference_geometry(img, k, vf, hf):
+    """The reference's own order (dp/loader.py:63-71): rot90^k, then the
+    vertical flip, then the horizontal one."""
+    g = np.rot90(img, k, axes=(0, 1))
+    g = np.flipud(g) if vf else g
+    return np.ascontiguousarray(np.fliplr(g) if hf else g)
+
+
+def _decision_params(decisions, colors, factors):
+    k, vf, hf = (np.asarray(c, np.int32) for c in zip(*decisions))
+    return {"rot": k, "vflip": vf, "hflip": hf,
+            "color": np.asarray(colors, np.int32),
+            "factor": np.asarray(factors, np.float32)}
+
+
+@pytest.mark.parametrize("size", [7, 32])
+@pytest.mark.parametrize("k,vf,hf", _DECISIONS,
+                         ids=[f"rot{k}v{vf}h{hf}" for k, vf, hf in _DECISIONS])
+def test_device_geometry_is_the_references_permutation(k, vf, hf, size):
+    """Every (rot, vflip, hflip) decision under every colour branch, at an
+    odd and an even size: the one transpose and two reversals the device
+    composes on the uint8 batch move each pixel where np.rot90 / flipud /
+    fliplr put it. Bitwise against the device's own arithmetic on the
+    NumPy-permuted image (the geometry is a permutation, nothing else), and
+    against the NumPy chain at the tolerance
+    test_device_prep_matches_numpy_all_paths pins."""
+    rng = np.random.default_rng(1000 * size + 100 * k + 10 * vf + hf)
+    colors, factors = [0, 1, 2, 3], [1.0, 0.7, 1.2, 0.85]
+    imgs = rng.integers(0, 256, (4, size, size, 3), np.uint8)
+    out = np.asarray(apply_batch_augment(
+        imgs, _decision_params([(k, vf, hf)] * 4, colors, factors)))
+    moved = np.stack([_reference_geometry(im, k, vf, hf) for im in imgs])
+    np.testing.assert_array_equal(out, np.asarray(apply_batch_augment(
+        moved, _decision_params([(0, 0, 0)] * 4, colors, factors))))
+    ref = np.stack([T.normalize(T.apply_augment(im, k, bool(vf), bool(hf),
+                                                c, f))
+                    for im, c, f in zip(imgs, colors, factors)])
+    assert np.abs(out - ref).max() < 1e-5
+
+
+@pytest.mark.parametrize("path", ["resident", "streaming"])
+def test_device_geometry_through_the_preps_on_two_devices(path):
+    """The same parity through the two jitted callers, sharded over a
+    two-device mesh: the resident prep (rows gathered from the held form)
+    and the streaming prep (sharded, donating). All 16 decisions x 4
+    colour branches in one global batch of 64."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from tpuic.data.device_prep import (make_resident_prep, pack_params,
+                                        resident_rows)
+    mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
+    shard, repl = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    size, rng = 30, np.random.default_rng(30)
+    corpus = rng.integers(0, 256, (80, size, size, 3), np.uint8)
+    idx = rng.permutation(80)[:64].astype(np.int32)
+    decisions = [d for d in _DECISIONS for _ in range(4)]
+    colors = [0, 1, 2, 3] * 16
+    factors = rng.uniform(0.6, 1.4, 64)
+    params = _decision_params(decisions, colors, factors)
+    still = dict(params, rot=0 * params["rot"], vflip=0 * params["vflip"],
+                 hflip=0 * params["hflip"])
+    if path == "resident":
+        prep = make_resident_prep(size, sharding=shard, replicated=repl)
+
+        def run(images, rows, p):
+            return prep(jax.device_put(resident_rows(images), repl),
+                        jax.device_put(rows, shard),
+                        jax.device_put(pack_params(p), shard))
+    else:
+        prep = make_device_prep(sharding=shard)
+
+        def run(images, rows, p):
+            return prep(jax.device_put(images[rows], shard),
+                        jax.device_put(pack_params(p), shard))
+    out = run(corpus, idx, params)
+    assert out.sharding.spec == P("data") and out.shape == (64, size, size, 3)
+    moved = np.stack([_reference_geometry(corpus[i], *d)
+                      for i, d in zip(idx, decisions)])
+    np.testing.assert_array_equal(
+        np.asarray(out),
+        np.asarray(run(moved, np.arange(64, dtype=np.int32), still)))
+    ref = np.stack([T.normalize(T.apply_augment(corpus[i], k, bool(vf),
+                                                bool(hf), c, f))
+                    for i, (k, vf, hf), c, f in zip(idx, decisions, colors,
+                                                    factors)])
+    assert np.abs(np.asarray(out) - ref).max() < 1e-5
+
+
 # -- packed Loader end-to-end ----------------------------------------------
 
 @pytest.mark.parametrize("cache_mb", [4096, 0])
@@ -439,6 +530,22 @@ def test_resident_prep_guard_trips_on_a_corpus_sized_temporary(monkeypatch):
             data.astype(jnp.float32)))
     monkeypatch.setattr(dp, "make_resident_prep", bad_prep)
     with pytest.raises(AssertionError, match="corpus-sized temporary"):
+        dp.check_resident_prep(32, rows=4096, batch=8)
+
+
+def test_resident_prep_guard_trips_on_a_float32_reversal(monkeypatch):
+    """The guard's other half: geometry done after the conversion to
+    float32 (as it was until PR 30, four times the bytes on the chip) is
+    refused by the compiled program's own text."""
+    import jax.numpy as jnp
+    from tpuic.data import device_prep as dp
+
+    def late_flip(images_u8, params, **_):
+        x = images_u8.astype(jnp.float32)
+        return jnp.where(params["vflip"].astype(bool)[:, None, None, None],
+                         jnp.flip(x, axis=1), x)
+    monkeypatch.setattr(dp, "apply_batch_augment", late_flip)
+    with pytest.raises(AssertionError, match="reverses the batch in float32"):
         dp.check_resident_prep(32, rows=4096, batch=8)
 
 

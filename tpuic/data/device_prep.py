@@ -14,18 +14,27 @@ source of truth shared with the NumPy and native paths), so a sample's
 augmentation is identical no matter which path executed it. This module
 only *applies* pre-drawn decisions, vectorized per sample:
 
-- geometry: the four rot90 variants are computed batch-wise (transpose +
-  reverse are free layout ops for XLA) and selected per sample, then
-  conditional v/h flips — a permutation, bitwise-equal to the NumPy path.
+- geometry: rot90^k, vflip and hflip compose to one of the eight
+  symmetries of the square, so each sample takes at most one transpose,
+  one reversal of the rows and one of the columns, the three conditions
+  computed from the decisions by integer logic, all on the uint8 batch
+  before the conversion to float32 — a permutation, bitwise-equal to the
+  NumPy path. On the TPU a transpose or a reversal is no free layout op:
+  each is a pass over the batch, paid by the byte (as four rot90 variants
+  in float32 the program took 2.7 ms of a 49 ms ResNet-50 step, composed
+  on uint8 0.7; PERF.md PR 30).
 - color: same f32 arithmetic as transforms.adjust_* (clip to [0,255]);
-  reduction order in the contrast mean may differ from NumPy's pairwise
-  sums at the last-ulp level (tests/test_pack.py::
+  reduction order in the contrast mean is the compiler's: it may differ
+  from NumPy's pairwise sums, and from one compiled shape to another, at
+  the last-ulp level (tests/test_pack.py::
   test_device_prep_matches_numpy_all_paths pins the tolerance).
 - normalize: x/255 (true division), then (x-mean)/std, f32.
 """
 
 from __future__ import annotations
 
+import math
+import re
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -43,19 +52,22 @@ def apply_batch_augment(images_u8: jnp.ndarray, params: Dict[str, jnp.ndarray],
     params: {'rot': [B] i32 (k in 0..3), 'vflip': [B] i32, 'hflip': [B] i32,
     'color': [B] i32 (0 none / 1 sat / 2 bright / 3 contrast),
     'factor': [B] f32}. Traced; call under jit (make_device_prep)."""
-    x = images_u8.astype(jnp.float32)
+    # The eight outcomes of (rot, vflip, hflip) are the symmetries of the
+    # square: each is "transpose or not, flip rows or not, flip columns or
+    # not". np.rot90(m, k, axes=(0,1)) is k=1: flip(m.T, 0); k=2:
+    # flip(flip(m, 0), 1); k=3: flip(m.T, 1); flips of different axes
+    # commute and each undoes itself, so the reference's vflip and hflip
+    # fold into the same two reversals. All of it on the uint8 batch: a
+    # permutation commutes with the exact conversion to float32, and the
+    # chip pays a reversal by the byte.
     rot = params["rot"].astype(jnp.int32)[:, None, None, None]
-    # np.rot90(m, k, axes=(0,1)) parity: out_k[i,j] selected per sample.
-    xt = jnp.swapaxes(x, 1, 2)
-    r1 = jnp.flip(xt, axis=1)                 # out[i,j] = m[j, S-1-i]
-    r2 = jnp.flip(jnp.flip(x, axis=1), axis=2)
-    r3 = jnp.flip(xt, axis=2)                 # out[i,j] = m[S-1-j, i]
-    g = jnp.where(rot == 1, r1, jnp.where(rot == 2, r2,
-                                          jnp.where(rot == 3, r3, x)))
     vf = params["vflip"].astype(bool)[:, None, None, None]
     hf = params["hflip"].astype(bool)[:, None, None, None]
-    g = jnp.where(vf, jnp.flip(g, axis=1), g)
-    g = jnp.where(hf, jnp.flip(g, axis=2), g)
+    g = images_u8
+    g = jnp.where(rot % 2 == 1, jnp.swapaxes(g, 1, 2), g)
+    g = jnp.where(((rot == 1) | (rot == 2)) ^ vf, jnp.flip(g, axis=1), g)
+    g = jnp.where(((rot == 2) | (rot == 3)) ^ hf, jnp.flip(g, axis=2), g)
+    g = g.astype(jnp.float32)
 
     color = params["color"].astype(jnp.int32)[:, None, None, None]
     factor = params["factor"].astype(jnp.float32)[:, None, None, None]
@@ -202,18 +214,25 @@ def make_resident_prep(size: int, mean=None, std=None,
                    out_shardings=sharding)
 
 
+# A float32 reversal in a compiled program's text, and its dimensions.
+_F32_REVERSE = re.compile(r"= f32\[([\d,]+)\]\S* reverse\(")
+
+
 def check_resident_prep(size: int, rows: int = 4096, batch: int = 8,
                         mesh: Optional[jax.sharding.Mesh] = None,
                         device=None) -> Dict:
     """Compile the resident prep for a corpus >> batch and assert it holds
-    no corpus-sized temporary.
+    no corpus-sized temporary and reverses no float32 copy of the batch.
 
     Only shapes are handed to the compiler; nothing is allocated. On the
     TPU (chip_smoke.py, and a described one in tests/test_chip_compile.py)
     this is the check that would have caught the per-step copy of the whole
-    corpus; on a CPU it catches a gather that converts the corpus first.
-    Compiles for the default backend's first device, for ``device``, or,
-    with ``batch`` the global batch, for ``mesh``."""
+    corpus, and the augmentation's geometry done after the conversion to
+    float32 (four times the bytes, in every step); on a CPU it catches a
+    gather that converts the corpus first. ``bytes_accessed`` is the
+    compiler's own count of the program's traffic, for the caller to hold
+    against a record. Compiles for the default backend's first device, for
+    ``device``, or, with ``batch`` the global batch, for ``mesh``."""
     from jax.sharding import (NamedSharding, PartitionSpec as P,
                               SingleDeviceSharding)
 
@@ -230,9 +249,20 @@ def check_resident_prep(size: int, rows: int = 4096, batch: int = 8,
         jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=shard),
         jax.ShapeDtypeStruct((batch, len(PARAM_KEYS)), jnp.float32,
                              sharding=shard)).compile()
+    one_chip = batch // (1 if mesh is None else mesh.shape["data"])
     facts = {"corpus_bytes": int(np.prod(shape)),
-             "temp_bytes": int(compiled.memory_analysis().temp_size_in_bytes)}
+             "temp_bytes": int(compiled.memory_analysis().temp_size_in_bytes),
+             "bytes_accessed": float(
+                 compiled.cost_analysis()["bytes accessed"]),
+             "f32_reversals": sorted(
+                 f"f32[{m.group(1)}]"
+                 for m in _F32_REVERSE.finditer(compiled.as_text())
+                 if math.prod(map(int, m.group(1).split(",")))
+                 >= one_chip * size * size * 3)}
     if 4 * facts["temp_bytes"] >= facts["corpus_bytes"]:
         raise AssertionError(
             f"the resident prep holds a corpus-sized temporary: {facts}")
+    if facts["f32_reversals"]:
+        raise AssertionError(
+            f"the resident prep reverses the batch in float32: {facts}")
     return facts
