@@ -64,9 +64,9 @@ def _measure() -> dict:
     # On the CPU (asked for by name): small batch / few steps — a smoke
     # of the code path, not a measurement.
     size = 224
-    # Per-chip batch 128: the round-3 sweep's peak (perf/sweep.json —
-    # 2674 img/s vs 2291@64, 2551@256, 2327@160; 128 aligns the batch dim
-    # with MXU tiling). PERF_ANALYSIS.md has the full grid.
+    # Per-chip batch 128: the peak of a batch sweep (builder, <= 2026-08-01,
+    # other code: 2674 img/s vs 2291@64, 2551@256, 2327@160; 128 aligns the
+    # batch dim with MXU tiling). PERF_ANALYSIS.md has the full grid.
     per_chip_batch, n_steps = (8, 3) if on_cpu else (128, 20)
     global_batch = per_chip_batch * n_chips
 

@@ -95,25 +95,3 @@ def test_no_augment_flag():
     assert cli.config_from_args(args).data.augment is True
     args = cli.build_parser().parse_args(["--datadir", "/d", "--no-augment"])
     assert cli.config_from_args(args).data.augment is False
-
-
-def test_fit_proof_steady_rate_math():
-    """The chip-proof artifact's steady-state computation (scripts/
-    fit_proof.py): each epoch's first logged interval is dropped (compile/
-    ramp), degenerate cadences fall back instead of zeroing the number."""
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "fit_proof", os.path.join(os.path.dirname(__file__), "..",
-                                  "scripts", "fit_proof.py"))
-    fp = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(fp)
-
-    # 2 epochs x 3 logs: indices 0 and 3 dropped -> median of [5,6,8,9]=7
-    assert fp.steady_rate([1, 5, 6, 2, 8, 9], 3) == 7
-    # cadence longer than the epoch (logs_per_epoch 0): keep everything
-    assert fp.steady_rate([4, 7], 0) == 5.5
-    # every sample dropped (1 log/epoch): fall back to the raw median
-    assert fp.steady_rate([3, 4], 1) == 3.5
-    assert fp.steady_rate([], 3) == 0.0

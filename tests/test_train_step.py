@@ -181,7 +181,7 @@ def test_remat_step_matches_plain_step():
 
 def _vit_state(mcfg, batch=4, size=32):
     """Build via create_model_from_config so remat_core flows from the
-    config (the production path — Trainer and perf_sweep do the same)."""
+    config (the production path — the Trainer does the same)."""
     from tpuic.models import create_model_from_config
     model = create_model_from_config(mcfg)
     return create_train_state(model, make_optimizer(OCFG), jax.random.key(0),

@@ -102,7 +102,7 @@ class ModelConfig:
     # BN batch-statistics accumulation dtype. True (default) reduces in
     # float32 — torch/SyncBN semantics. False reduces in the compute dtype
     # (bf16): the stat fusions re-read large activation tensors and are the
-    # top HBM consumers in the ResNet-50 profile (perf/profile.json), so
+    # top HBM consumers in the ResNet-50 profile (builder, <= 2026-08-01), so
     # halving their read width is a bandwidth experiment (VERDICT r3 item
     # 7); numerics tolerance is pinned in tests/test_models.py. ResNet
     # family only; inception/effnet keep f32 stats.
@@ -113,7 +113,10 @@ class ModelConfig:
     # memory-limited. The reference has no equivalent (torch would need
     # torch.utils.checkpoint rewiring).
     remat: bool = False
-    # What remat recomputes (effective only when remat=True):
+    # What remat recomputes (effective only when remat=True). 'dots' is the
+    # step's and applies to every model; the others are flags of the
+    # backbone, and which a family has is what it declares when registered
+    # (models.Family.remat_policies) — elsewhere they warn and no-op:
     #   'dots'      — whole-forward jax.checkpoint saving only matmul/conv
     #                 outputs without batch dims; recomputes all
     #                 activation-sized tensors (the original behavior;
@@ -133,18 +136,16 @@ class ModelConfig:
     #                 (PERF_ANALYSIS.md §10f). For the looped stack it is
     #                 the memory mode: activations grow with layers x
     #                 passes, weights do not. Composes with any attention
-    #                 impl; these two families only (warns and no-ops
-    #                 elsewhere).
+    #                 impl.
     #   'gelu'      — ViT ``remat_mlp``: each block's Dense(mlp_up)+GELU
     #                 runs under nn.remat (models/vit.py MlpUpGelu), so
     #                 the [B,N,4D] pre-activation is never a residual —
     #                 the mlp_up fusion writes ONE output instead of two
     #                 and the backward recomputes W1·x per block. The
     #                 lightest policy, aimed at the dual-output mlp_up
-    #                 writes the ViT-B b64 profile fingered (§10f).
-    #                 ViT-only (warns and no-ops elsewhere); in MoE ViTs
-    #                 the dense-MLP blocks still benefit (the routed
-    #                 SwitchMoEMlp blocks are untouched).
+    #                 writes the ViT-B b64 profile fingered (§10f). In
+    #                 MoE ViTs the dense-MLP blocks still benefit (the
+    #                 routed SwitchMoEMlp blocks are untouched).
     remat_policy: str = "dots"
     # Inception aux-logits loss weight (reference train.py:52).
     aux_loss_weight: float = 0.4

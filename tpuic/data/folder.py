@@ -180,8 +180,7 @@ class ImageFolderDataset:
         This is the prefetch-worker decode (Loader workers call ``load``
         off-thread): with the native core the per-sample cost drops to
         one C decode+gather, so the telemetry ``input`` bucket on the
-        decode (--no-pack) path shrinks toward zero
-        (perf/native_prefetch.json).  JPEG decodes DCT-scaled — the
+        decode (--no-pack) path shrinks toward zero.  JPEG decodes DCT-scaled — the
         same pixels the packed cache (pack.py) already serves.  A
         corrupt/truncated file makes the native decoder return None and
         the PIL fallback raise, so the quarantine ladder engages

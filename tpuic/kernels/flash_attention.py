@@ -231,9 +231,9 @@ def _resolve_blocks(n: int, block_q, block_k):
     LARGEST whose padded length stays within 10% of the best achievable —
     padding is pure waste (masked FLOPs + HBM on every padded key), but
     per-program grid overhead is why the old fixed 128x128 default was
-    ~2x slower than dense at N=2048 (16x16 inner programs per batch*head,
-    perf/pallas_smoke.json) — so small padding buys big blocks, large
-    padding never does. Examples: 197 -> 256 (one k pass), 577 -> 128
+    ~2x slower than dense at N=2048 (16x16 inner programs per batch*head;
+    builder, <= 2026-08-01, other code) — so small padding buys big blocks,
+    large padding never does. Examples: 197 -> 256 (one k pass), 577 -> 128
     (padded 640; larger blocks pad >= 768), 1025 -> 128 (1152),
     2048 -> 512, 2305 -> 512 (2560, 5% over the 128-block 2432 but 16x
     fewer programs). VMEM at 512x512 blocks: ~1 MB f32 score tile, 128 KB
@@ -242,8 +242,8 @@ def _resolve_blocks(n: int, block_q, block_k):
 
     Powers of two ONLY: 384 was in the palette until the one chip hang
     ever observed hit exactly the one config that auto-picked 384x384
-    blocks (N=1025; perf/long_seq.json rows — 128/256/512 configs all
-    ran, the 384 child hung 900s).
+    blocks (N=1025; builder, <= 2026-08-01, other code — 128/256/512
+    configs all ran, the 384 child hung 900s).
     Non-power-of-two Mosaic tilings are the suspect; the palette sticks
     to {128, 256, 512} — worst case vs 384 is bounded by the same 10%
     padding rule.

@@ -65,8 +65,8 @@ def opt_state_device_bytes(state: TrainState,
     """Bytes of optimizer state RESIDENT on ``device`` — per-shard, not
     logical: a leaf sharded over the ``data`` axis (ZeRO-1,
     tpuic/parallel/sharding.py) charges ``nbytes / R`` here while a
-    replicated leaf charges its full size. The measured quantity behind
-    perf/elastic_zero.json (optimizer memory per replica ~ 1/R)."""
+    replicated leaf charges its full size (optimizer memory per replica
+    ~ 1/R)."""
     total = 0
     for leaf in jax.tree_util.tree_leaves(state.opt_state):
         if not isinstance(leaf, jax.Array):

@@ -34,6 +34,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tpuic.config import ModelConfig, OptimConfig, resolve_compute_dtype
 from tpuic.metrics.meters import accuracy, topk_accuracy
+from tpuic.models import MODEL_REMAT_POLICIES, family
 from tpuic.models.classifier import ExitOutputs
 from tpuic.train.loss import classification_loss, exit_expected_loss
 from tpuic.train.state import TrainState
@@ -73,69 +74,39 @@ def resolve_remat_policy(model_cfg: ModelConfig):
     without batch dims (i.e. nothing activation-sized); the backward
     recomputes activations instead of round-tripping them through HBM.
 
-    'attention' returns None on purpose: the selective form lives in the
-    MODEL (ViT ``remat_core`` — create_model_from_config sets it from the
-    config), wrapping just the logits->softmax->probs@v core so only
-    q/k/v survive as residuals. It is not expressible as a step-level
-    names policy: softmax's backward wants its own internal output, so a
-    save-anything-except-names policy still saves quadratic copies of it
-    (verified with jax.ad_checkpoint.print_saved_residuals).
+    'attention', 'blocks' and 'gelu' return None on purpose: they live in
+    the MODEL (its family's builder sets the backbone's flag from the
+    config; ModelConfig.remat_policy says what each keeps as residuals).
+    'attention' and 'gelu' are not expressible as a step-level names
+    policy: softmax's backward wants its own internal output, and the
+    mlp_up pre-activation's dtype-cast copies and erf-vjp internals are
+    as large as it is, so a save-anything-except-names policy still saves
+    them (verified with jax.ad_checkpoint.print_saved_residuals).
     """
     if not model_cfg.remat:
         return None
-    if model_cfg.remat_policy == "dots":
+    policy = model_cfg.remat_policy
+    if policy == "dots":
         return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-    if model_cfg.remat_policy == "attention":
-        # remat_core only exists in ViT's dense path; anywhere else this
-        # combination applies NO remat at all — loud beats a silent OOM at
-        # a batch size --remat (dots) would have fit.
-        if "vit" not in model_cfg.name or model_cfg.attention != "dense":
-            warnings.warn(
-                f"remat_policy='attention' has no effect for model="
-                f"'{model_cfg.name}' with attention="
-                f"'{model_cfg.attention}': only the dense ViT attention "
-                "core is rematerializable; NO remat is applied. Use "
-                "remat_policy='dots' for whole-forward remat.",
-                stacklevel=2)
-        return None
-    if model_cfg.remat_policy == "gelu":
-        # Model-level, like 'attention': ViT ``remat_mlp`` runs each
-        # block's Dense(mlp_up)+GELU under nn.remat (models/vit.py
-        # MlpUpGelu), so the [B,N,4D] pre-activation is never a residual —
-        # the mlp_up fusion writes ONE output instead of two (the
-        # dual-output writes PERF_ANALYSIS §10f fingered) and the backward
-        # recomputes W1·x per block. NOT expressible as a step-level names
-        # policy: save-anything-except a checkpoint_name'd pre-activation
-        # still saves its dtype-cast copies and the erf-vjp internals at
-        # the same [B,N,4D] size (verified with print_saved_residuals).
-        # In MoE ViTs the dense-MLP blocks still benefit; the routed
-        # SwitchMoEMlp blocks are untouched.
-        if "vit" not in model_cfg.name:
-            warnings.warn(
-                f"remat_policy='gelu' has no effect for model="
-                f"'{model_cfg.name}': only the ViT encoder has the "
-                "rematerializable mlp_up+GELU region; NO remat is "
-                "applied. Use remat_policy='dots' for whole-forward "
-                "remat.",
-                stacklevel=2)
-        return None
-    if model_cfg.remat_policy == "blocks":
-        # Per-block nn.remat lives in the model (ViT and the looped
-        # stack, ``remat_blocks``): residuals are the block inputs only,
-        # the backward recomputes one block at a time. The long-context
-        # memory mode of the ViT and the looped stack's only one (its
-        # activations grow with layers x passes, its weights do not) —
-        # see ModelConfig.remat_policy.
-        if not any(f in model_cfg.name for f in ("vit", "ouro")):
-            warnings.warn(
-                f"remat_policy='blocks' has no effect for model="
-                f"'{model_cfg.name}': only the ViT encoder and the looped "
-                "stack (ouro-*) have per-block remat; NO remat is applied. "
-                "Use remat_policy='dots' for whole-forward remat.",
-                stacklevel=2)
-        return None
-    raise ValueError(f"unknown remat_policy '{model_cfg.remat_policy}'; "
-                     f"available: ['dots', 'attention', 'blocks', 'gelu']")
+    if policy not in MODEL_REMAT_POLICIES:
+        raise ValueError(f"unknown remat_policy '{policy}'; available: "
+                         f"{['dots', *MODEL_REMAT_POLICIES]}")
+    implemented = family(model_cfg.name).remat_policies
+    # 'attention' wraps the dense logits->softmax->probs@v core only (flash
+    # never materializes it).
+    if policy not in implemented or (policy == "attention"
+                                     and model_cfg.attention != "dense"):
+        # This combination applies NO remat at all — loud beats a silent
+        # OOM at a batch size --remat (dots) would have fit.
+        warnings.warn(
+            f"remat_policy='{policy}' has no effect for model="
+            f"'{model_cfg.name}' with attention='{model_cfg.attention}': "
+            f"its backbone implements {sorted(implemented) or 'none'} of "
+            f"{list(MODEL_REMAT_POLICIES)} ('attention' over a dense core "
+            "only); NO remat is applied. Use remat_policy='dots' for "
+            "whole-forward remat.",
+            stacklevel=2)
+    return None
 
 
 def make_train_step(optim_cfg: OptimConfig, model_cfg: ModelConfig,
