@@ -623,6 +623,7 @@ def _loop_stub(*, handle_preemption: bool, steps: int):
     class _Loader:
         global_batch = 2
         quarantine_count = 0
+        last_epoch_ahead = 0
 
         def __len__(self):
             return steps

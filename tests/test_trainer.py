@@ -248,3 +248,46 @@ def test_trainer_rejects_fold_smaller_than_global_batch(imagefolder):
     )
     with pytest.raises(ValueError, match="ZERO steps"):
         Trainer(cfg)
+
+
+def test_epoch_boundary_runs_ahead_and_fit_closes_the_loaders(imagefolder,
+                                                              tmp_path):
+    """ISSUE 32: over a packed corpus the second of two consecutive
+    train_epoch calls finds its first batches made (the epoch.first_batch
+    span says how many: 0 cold, then ``prefetch``), and fit() leaves no
+    loader thread behind."""
+    import threading
+    import time
+
+    import jax
+    from tpuic.runtime.mesh import make_mesh
+    from tpuic.telemetry import spans
+
+    before = set(threading.enumerate())
+    cfg = _config(imagefolder, tmp_path, epochs=1)
+    cfg = dataclasses.replace(
+        cfg, data=dataclasses.replace(cfg.data, batch_size=4),
+        run=dataclasses.replace(cfg.run, resume=False))
+    trainer = Trainer(cfg, mesh=make_mesh(MeshConfig(data=1),
+                                          devices=jax.devices()[:1]))
+    loader = trainer.train_loader
+    assert loader.packed and loader.augment
+    mark = len(spans.ledger.snapshot())
+    trainer.train_epoch(0)
+    deadline = time.monotonic() + 20.0
+    while not (loader._parked is not None and loader._parked.q.full()):
+        assert time.monotonic() < deadline, "the producer did not park"
+        time.sleep(0.005)
+    trainer.train_epoch(1)
+    first = [r["attrs"] for r in spans.ledger.snapshot()[mark:]
+             if r["name"] == "epoch.first_batch"]
+    assert first == [{"epoch": 0, "ahead": 0},
+                     {"epoch": 1, "ahead": loader.prefetch}]
+    assert loader._parked is not None
+    trainer.fit()                       # epoch 0 again: a miss, then val
+    assert loader._parked is None and trainer.val_loader._parked is None
+    mine = [t for t in threading.enumerate()
+            if t not in before and t.name == "tpuic-loader"]
+    for t in mine:
+        t.join(timeout=10.0)
+    assert [t for t in mine if t.is_alive()] == []

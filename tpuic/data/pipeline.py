@@ -29,6 +29,7 @@ The TPU-native replacement for the reference's
 
 from __future__ import annotations
 
+import contextlib
 import queue
 import threading
 from concurrent.futures import ThreadPoolExecutor
@@ -123,6 +124,58 @@ def _epoch_indices(n: int, epoch: int, seed: int, shuffle: bool,
     return order, n  # (padded order, number of valid entries)
 
 
+class _Producer:
+    """A daemon thread that puts ``items`` into a queue of ``bound`` slots,
+    and an exception in place of the item that raised it. It blocks on the
+    full queue without polling; ``stop`` is what wakes it."""
+
+    def __init__(self, items: Iterator, bound: int) -> None:
+        self.q: "queue.Queue" = queue.Queue(maxsize=bound)
+        self.epoch: Optional[int] = None  # set by the loader that parks it
+        self._stopped = threading.Event()
+        self._free = threading.Event()  # cleared while ``hold`` is on
+        self._free.set()
+        self._thread = threading.Thread(target=self._fill, args=(items,),
+                                        name="tpuic-loader", daemon=True)
+        self._thread.start()
+
+    def _fill(self, items: Iterator) -> None:
+        # closing(): an item source left half-way (the decode executor's
+        # thread pool) is shut down here, on the thread that ran it.
+        with contextlib.closing(items):
+            try:
+                for item in items:
+                    self.q.put(item)
+                    self._free.wait()
+                    if self._stopped.is_set():
+                        return
+            except BaseException as e:  # surface worker errors to the consumer
+                self.q.put(e)
+
+    def hold(self) -> None:
+        """Keep the thread from making its next item (it still puts the one
+        in hand) until ``release``: it makes items in Python, and a consumer
+        that dispatches meanwhile waits for the interpreter lock at every
+        return from a device call."""
+        self._free.clear()
+
+    def release(self) -> None:
+        self._free.set()
+
+    def stop(self) -> None:
+        """End the thread and drop what it made. A blocked ``put`` returns
+        once a slot is free, and the thread reads the flag after every put:
+        so emptying the queue lets it make at most the item in hand."""
+        self._stopped.set()
+        self._free.set()
+        try:
+            while True:
+                self.q.get_nowait()
+        except queue.Empty:
+            pass
+        self._thread.join(timeout=5.0)
+
+
 class Loader:
     """Iterates globally-sharded device batches for one process.
 
@@ -139,6 +192,27 @@ class Loader:
     ``[B,5]`` augment parameters, and the device B rows of traffic, whatever
     N is. ``resident_bytes`` is what that form holds on each chip, row
     padding (none at 224 px) counted; the budget check counts the same.
+
+    A packed loader that augments **runs ahead** over the epoch boundary:
+    there a batch costs the host a Python loop over its samples (the
+    per-sample draws), and a producer started at ``epoch()`` would make the
+    epoch's first two batches while the device stands still. So its one
+    producer thread, having put epoch e's last batch, goes on to epoch
+    e + 1 from step 0 (same order, same draws, so the same bits) and parks,
+    blocked on the queue of ``prefetch`` slots: host payloads only, nothing
+    on the device. The plan lives from the moment epoch e has been consumed
+    to its end until the next ``epoch()`` call, which takes it over if it is
+    ``(e + 1, 0)`` and discards it otherwise, or until ``close()``.
+    ``last_epoch_ahead`` is how many batches the newest ``epoch()`` found
+    made: 0 on a cold start, ``prefetch`` once the producer has parked.
+    Where it finds the two its first yield needs, the producer is held back
+    until the caller returns for the second batch, so that the epoch's
+    first dispatches do not wait for the interpreter lock. A
+    packed loader that does not augment (two fancy-indexes a batch) and the
+    decode executor (a thread pool and the dataset's quarantine counters
+    belong to the epoch) start every epoch cold and never park. The parked
+    thread is a daemon and does not poll; it keeps the loader alive, so
+    whoever drops a loader that ran ahead calls ``close()``.
     """
 
     def __init__(self, dataset: ImageFolderDataset, global_batch: int,
@@ -201,6 +275,11 @@ class Loader:
         self._device_prep = None
         self._resident_prep = None
         self._data_dev = None
+        # Running ahead engages where a batch costs the host a loop over
+        # its samples (the augment draws); see the class docstring.
+        self._runs_ahead = self.packed and self.augment
+        self._parked: Optional[_Producer] = None
+        self.last_epoch_ahead = 0
         if self.packed:
             from tpuic.data.device_prep import (make_device_prep,
                                                 make_resident_prep,
@@ -261,6 +340,106 @@ class Loader:
         img, label, image_id = self.dataset.load(int(index), rng)
         return position, img, label, image_id, valid
 
+    def _packed_batches(self, epoch: int, start_step: int):
+        """Packed fast path: augment decisions drawn host-side from the
+        SAME (seed, epoch, index) stream as the decode path, applied on
+        device. Resident mode skips even the memmap row copy — the
+        batch payload is the [local_batch] index vector."""
+        from tpuic.data import transforms as T
+        from tpuic.data.device_prep import pack_params
+        ds, c = self.dataset, self.dataset.cfg
+        order, n_valid = _epoch_indices(len(ds), epoch, self.seed,
+                                        self.shuffle, self.global_batch)
+        for b in range(start_step, len(self)):
+            lo = b * self.global_batch + self.process_index * self.local_batch
+            # Batch assembly is vectorized (one C-level gather per
+            # array) — on the 1-core host the per-row Python loop was
+            # 2x slower; only the per-sample augment RNG draws remain a
+            # loop, because the (seed, epoch, index) stream is the
+            # parity contract with the decode/native paths.
+            idx = np.asarray(order[lo:lo + self.local_batch], np.int32)
+            imgs = None if self.resident else ds.raw_batch(idx)
+            labels = ds.label_batch(idx).astype(np.int32)
+            gpos = np.arange(lo, lo + self.local_batch)
+            mask = (gpos < n_valid).astype(np.float32)
+            ids = [ds.image_id(int(j)) for j in idx]
+            params = {"rot": np.zeros((self.local_batch,), np.int32),
+                      "vflip": np.zeros((self.local_batch,), np.int32),
+                      "hflip": np.zeros((self.local_batch,), np.int32),
+                      "color": np.zeros((self.local_batch,), np.int32),
+                      "factor": np.ones((self.local_batch,), np.float32)}
+            if self.augment:
+                for i, index in enumerate(idx):
+                    rng = np.random.default_rng(np.random.SeedSequence(
+                        [self.seed, epoch, int(index)]))
+                    k, vf, hf, color, factor = T.draw_augment(
+                        rng, p_vflip=c.p_vflip, p_hflip=c.p_hflip,
+                        p_saturation=c.p_saturation,
+                        p_brightness=c.p_brightness,
+                        p_contrast=c.p_contrast, jitter_lo=c.jitter_lo,
+                        jitter_hi=c.jitter_hi)
+                    params["rot"][i] = k
+                    params["vflip"][i] = int(vf)
+                    params["hflip"][i] = int(hf)
+                    params["color"][i] = color
+                    params["factor"][i] = factor
+            payload = idx if self.resident else imgs
+            gidx = order[b * self.global_batch:(b + 1) * self.global_batch]
+            yield payload, labels, mask, ids, pack_params(params), gidx
+
+    def _decoded_batches(self, epoch: int, start_step: int):
+        """Decode path: a thread pool, alive for this epoch, decodes and
+        augments the samples of each batch into host float32."""
+        order, n_valid = _epoch_indices(len(self.dataset), epoch, self.seed,
+                                        self.shuffle, self.global_batch)
+        with ThreadPoolExecutor(self.num_workers) as pool:
+            for b in range(start_step, len(self)):
+                lo = b * self.global_batch + self.process_index * self.local_batch
+                futs = []
+                for i in range(self.local_batch):
+                    gpos = lo + i
+                    futs.append(pool.submit(
+                        self._load_one, i, order[gpos],
+                        gpos < n_valid, epoch))
+                imgs = np.empty((self.local_batch,
+                                 self.dataset.resize_size,
+                                 self.dataset.resize_size, 3), np.float32)
+                labels = np.zeros((self.local_batch,), np.int32)
+                mask = np.zeros((self.local_batch,), np.float32)
+                ids = [""] * self.local_batch
+                for f in futs:
+                    pos, img, label, image_id, valid = f.result()
+                    imgs[pos] = img
+                    labels[pos] = label
+                    mask[pos] = 1.0 if valid else 0.0
+                    ids[pos] = image_id
+                gidx = order[b * self.global_batch:
+                             (b + 1) * self.global_batch]
+                yield imgs, labels, mask, ids, None, gidx
+
+    def _produced(self, epoch: int, start_step: int):
+        """What the producer thread puts, in order: an epoch's host batches
+        from ``start_step`` on, then ``None``; where the loader runs ahead,
+        the next epoch's from step 0 after that, and so on (the bounded
+        queue is what stops it)."""
+        batches = (self._packed_batches if self.packed
+                   else self._decoded_batches)
+        while True:
+            yield from batches(epoch, start_step)
+            yield None
+            if not self._runs_ahead:
+                return
+            epoch, start_step = epoch + 1, 0
+
+    def close(self) -> None:
+        """End the parked producer, if there is one, and drop its batches.
+        The loader stays usable: its next epoch starts cold. (The producer
+        of an epoch in progress belongs to that iterator, which ends it
+        when it is exhausted, closed or collected.)"""
+        parked, self._parked = self._parked, None
+        if parked is not None:
+            parked.stop()
+
     def epoch(self, epoch: int, start_step: int = 0) -> Iterator[Batch]:
         """Yield batches for this epoch (the set_epoch(e) equivalent).
 
@@ -269,121 +448,34 @@ class Loader:
         augment stream is (seed, epoch, index)-keyed, so the skipped
         prefix is exactly the batches a preempted run already trained and
         the remainder is served bit-identically to the uninterrupted
-        epoch."""
-        n = len(self.dataset)
-        order, n_valid = _epoch_indices(n, epoch, self.seed, self.shuffle,
-                                        self.global_batch)
-        n_batches = len(order) // self.global_batch
-        if self.drop_last and n % self.global_batch:
-            n_batches -= 1
-        if not 0 <= start_step <= n_batches:
+        epoch.
+
+        A loader that runs ahead (see the class) takes over the parked
+        producer when this call is ``(e + 1, 0)`` after an epoch ``e``
+        that was consumed to its end, and finds up to ``prefetch`` batches
+        made (``last_epoch_ahead`` says how many). Any other call ends the
+        parked producer and starts cold. An iterator that is closed or
+        dropped before its epoch's last batch, or that raises, ends its
+        producer and leaves nothing parked."""
+        if not 0 <= start_step <= len(self):
             raise ValueError(f"start_step {start_step} outside this epoch's "
-                             f"0..{n_batches} steps")
-        out_q: "queue.Queue" = queue.Queue(maxsize=self.prefetch)
-        stop = threading.Event()
-
-        def _put(item) -> bool:
-            """Bounded put that aborts when the consumer abandons the epoch
-            (otherwise the producer would park forever in a full queue)."""
-            while not stop.is_set():
-                try:
-                    out_q.put(item, timeout=0.1)
-                    return True
-                except queue.Full:
-                    continue
-            return False
-
-        def produce():
-            try:
-                _produce_loop()
-                _put(None)
-            except BaseException as e:  # surface worker errors to the consumer
-                _put(e)
-
-        def _produce_packed_loop():
-            """Packed fast path: augment decisions drawn host-side from the
-            SAME (seed, epoch, index) stream as the decode path, applied on
-            device. Resident mode skips even the memmap row copy — the
-            batch payload is the [local_batch] index vector."""
-            from tpuic.data import transforms as T
-            from tpuic.data.device_prep import pack_params
-            ds, c = self.dataset, self.dataset.cfg
-            s = ds.resize_size
-            augment = self.augment
-            for b in range(start_step, n_batches):
-                if stop.is_set():
-                    break
-                lo = b * self.global_batch + self.process_index * self.local_batch
-                # Batch assembly is vectorized (one C-level gather per
-                # array) — on the 1-core host the per-row Python loop was
-                # 2x slower; only the per-sample augment RNG draws remain a
-                # loop, because the (seed, epoch, index) stream is the
-                # parity contract with the decode/native paths.
-                idx = np.asarray(order[lo:lo + self.local_batch], np.int32)
-                imgs = None if self.resident else ds.raw_batch(idx)
-                labels = ds.label_batch(idx).astype(np.int32)
-                gpos = np.arange(lo, lo + self.local_batch)
-                mask = (gpos < n_valid).astype(np.float32)
-                ids = [ds.image_id(int(j)) for j in idx]
-                params = {"rot": np.zeros((self.local_batch,), np.int32),
-                          "vflip": np.zeros((self.local_batch,), np.int32),
-                          "hflip": np.zeros((self.local_batch,), np.int32),
-                          "color": np.zeros((self.local_batch,), np.int32),
-                          "factor": np.ones((self.local_batch,), np.float32)}
-                if augment:
-                    for i, index in enumerate(idx):
-                        rng = np.random.default_rng(np.random.SeedSequence(
-                            [self.seed, epoch, int(index)]))
-                        k, vf, hf, color, factor = T.draw_augment(
-                            rng, p_vflip=c.p_vflip, p_hflip=c.p_hflip,
-                            p_saturation=c.p_saturation,
-                            p_brightness=c.p_brightness,
-                            p_contrast=c.p_contrast, jitter_lo=c.jitter_lo,
-                            jitter_hi=c.jitter_hi)
-                        params["rot"][i] = k
-                        params["vflip"][i] = int(vf)
-                        params["hflip"][i] = int(hf)
-                        params["color"][i] = color
-                        params["factor"][i] = factor
-                payload = idx if self.resident else imgs
-                gidx = order[b * self.global_batch:(b + 1) * self.global_batch]
-                if not _put((payload, labels, mask, ids,
-                             pack_params(params), gidx)):
-                    return
-
-        def _produce_loop():
-            if self.packed:
-                return _produce_packed_loop()
-            with ThreadPoolExecutor(self.num_workers) as pool:
-                for b in range(start_step, n_batches):
-                    if stop.is_set():
-                        break
-                    lo = b * self.global_batch + self.process_index * self.local_batch
-                    futs = []
-                    for i in range(self.local_batch):
-                        gpos = lo + i
-                        futs.append(pool.submit(
-                            self._load_one, i, order[gpos],
-                            gpos < n_valid, epoch))
-                    imgs = np.empty((self.local_batch,
-                                     self.dataset.resize_size,
-                                     self.dataset.resize_size, 3), np.float32)
-                    labels = np.zeros((self.local_batch,), np.int32)
-                    mask = np.zeros((self.local_batch,), np.float32)
-                    ids = [""] * self.local_batch
-                    for f in futs:
-                        pos, img, label, image_id, valid = f.result()
-                        imgs[pos] = img
-                        labels[pos] = label
-                        mask[pos] = 1.0 if valid else 0.0
-                        ids[pos] = image_id
-                    gidx = order[b * self.global_batch:
-                                 (b + 1) * self.global_batch]
-                    if not _put((imgs, labels, mask, ids, None, gidx)):
-                        return
-
-        producer = threading.Thread(target=produce, daemon=True)
-        producer.start()
+                             f"0..{len(self)} steps")
+        run, self._parked = self._parked, None
+        if run is not None and (run.epoch, 0) != (epoch, start_step):
+            run.stop()
+            run = None
+        if run is None:
+            self.last_epoch_ahead = 0
+            run = _Producer(self._produced(epoch, start_step), self.prefetch)
+        else:
+            self.last_epoch_ahead = run.q.qsize()
+            if self.last_epoch_ahead >= 2:
+                # The device stands still until this epoch's first step is
+                # dispatched, and the two batches the first yield needs
+                # (the double buffering below) are made: the producer waits
+                # until the caller is back for the second batch.
+                run.hold()
+        consumed = False
         try:
             # Device-side double buffering: batch N+1's host->device transfer
             # is dispatched (jax transfers are async) before batch N is
@@ -391,8 +483,9 @@ class Loader:
             # sitting on its critical path.
             pending: Optional[Batch] = None
             while True:
-                item = out_q.get()
+                item = run.q.get()
                 if item is None:
+                    consumed = True
                     break
                 if isinstance(item, BaseException):
                     raise item
@@ -413,12 +506,21 @@ class Loader:
                 batch.indices = np.asarray(gidx)
                 if pending is not None:
                     yield pending
+                    run.release()
                 pending = batch
             if pending is not None:
                 yield pending
         finally:
-            stop.set()
-            producer.join(timeout=5.0)
+            run.release()
+            if consumed and self._runs_ahead:
+                # The queue now holds nothing but the next epoch's first
+                # batches: park the producer for epoch(epoch + 1), in place
+                # of one that another iterator of this loader has parked.
+                run.epoch = epoch + 1
+                self.close()
+                self._parked = run
+            else:
+                run.stop()
 
     def _to_global(self, local: np.ndarray):
         if self._sharding is None:

@@ -347,6 +347,12 @@ class Trainer:
                                      - self.train_loader.resident_bytes))
         return global_batch
 
+    def _close_loaders(self) -> None:
+        """End the loaders' parked producers (Loader.close): called where
+        the Trainer drops its loaders or is done with them."""
+        self.train_loader.close()
+        self.val_loader.close()
+
     def _step_program_keys(self):
         """Registry keys of THE train/eval step programs for the current
         geometry (tpuic.compiled, docs/performance.md "Compiled-program
@@ -741,7 +747,8 @@ class Trainer:
             steptime.dispatch_end()
             if step == 0:
                 _record_span("epoch.first_batch", *steptime.first_batch,
-                             epoch=epoch)
+                             epoch=epoch,
+                             ahead=self.train_loader.last_epoch_ahead)
                 _record_span("epoch.first_dispatch",
                              *steptime.first_dispatch, epoch=epoch)
             self.last_epoch_steps = start_step + step + 1
@@ -1077,6 +1084,7 @@ class Trainer:
         cfg = self.cfg
         self.mesh = replica_mesh(replicas, cfg.mesh)
         step_mesh = self.mesh if self.mesh.size > 1 else None
+        self._close_loaders()
         global_batch = self._build_loaders()
         steps = max(1, self.train_loader.steps_per_epoch())
         self.schedule = make_schedule(cfg.optim, steps, cfg.run.epochs,
@@ -1278,6 +1286,7 @@ class Trainer:
                 epoch += 1
         finally:
             self.preemption.uninstall()
+            self._close_loaders()
             # Commit any staged save on EVERY exit path: an exception
             # during epoch N+1 must not strand epoch N's fully-written
             # checkpoint in '{track}.new' (the restore ladder only reads
