@@ -381,9 +381,9 @@ def test_trainer_trains_evaluates_checkpoints_and_resumes(tmp_path):
         and 1.0 <= r["exit_expected_pass"] <= 4.0 and "loss_pass4" in r
         for r in logged)
     assert any("val_accuracy" in r for r in rows)
-    assert trainer.last_exit_stats["exit_expected_pass"] == logged[-1][
+    assert trainer.last_counters["exit_expected_pass"] == logged[-1][
         "exit_expected_pass"]
-    text = train_exposition({}, exits=trainer.last_exit_stats)
+    text = train_exposition({}, counters=trainer.last_counters)
     assert 'tpuic_train_exit_probability{pass="4"}' in text
     assert "tpuic_train_exit_expected_pass" in text
     assert 'tpuic_train_pass_loss{pass="1"}' in text

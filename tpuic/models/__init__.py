@@ -23,6 +23,7 @@ from tpuic.models import efficientnet as _effnet
 from tpuic.models import inception as _inception
 from tpuic.models import vit as _vit
 from tpuic.models import ouro as _ouro
+from tpuic.models import kanana as _kanana
 
 
 # The ``ModelConfig.remat_policy`` values that are flags of a backbone;
@@ -161,6 +162,22 @@ def _register_builtins():
     _looped("ouro-2.6b", _ouro.ouro_2_6b)
     _looped("ouro-2.6b-l6", _ouro.ouro_2_6b, depth=6)
     _looped("ouro-tiny", _ouro.ouro_tiny)
+
+    def _latent_moe(name, ctor, **extra):
+        def build(cfg, mesh):
+            # as the looped stack: RMSNorm only, a dense causal core
+            return ctor(**_dtypes(cfg),
+                        remat_blocks=_remat(cfg, "blocks"), **extra)
+        register(name, build, remat_policies=("blocks",))
+
+    # Latent attention and routed experts (models/kanana.py):
+    # Kanana-2-30B-A3B at its published counts, and expert-parallel rank 0
+    # of 16 in the first pipeline stage of eight (6 of the 48 layers, 8 of
+    # the 128 experts of each; every width and the router as published).
+    _latent_moe("kanana-2-30b-a3b", _kanana.kanana_2_30b_a3b)
+    _latent_moe("kanana-2-30b-a3b-l6e8", _kanana.kanana_2_30b_a3b, depth=6,
+                held=(0, 8))
+    _latent_moe("kanana-tiny", _kanana.kanana_tiny)
 
     def _inc(cfg, mesh):
         # torch inception: eps 1e-3 (module default, not cfg.bn_eps); f32

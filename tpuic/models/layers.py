@@ -1,5 +1,9 @@
 """Shared model building blocks.
 
+The decoder stacks that serve as backbones (models/ouro.py,
+models/kanana.py) share the bias-free projection, ``RMSNorm``, the gated
+SiLU MLP and the patch tokeniser below.
+
 The MLP classifier head reproduces the reference's
 ``in_features -> 128 -> ReLU -> 64 -> ReLU -> 32 -> ReLU -> num_classes`` head
 (nn/classifier.py:26-34). BatchNorm notes:
@@ -18,6 +22,7 @@ from __future__ import annotations
 
 from typing import Any, Sequence
 
+import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
@@ -76,3 +81,59 @@ def conv1x1(features: int, strides: int = 1, *, dtype=jnp.float32,
     return nn.Conv(features, (1, 1), strides=(strides, strides),
                    use_bias=False, dtype=dtype, param_dtype=param_dtype,
                    name=name)
+
+
+def proj(features: int, name: str, dtype, param_dtype, logical):
+    """A projection without bias, as every one in a decoder block."""
+    return nn.Dense(
+        features, use_bias=False, dtype=dtype, param_dtype=param_dtype,
+        name=name, kernel_init=nn.with_logical_partitioning(
+            nn.initializers.xavier_uniform(), logical))
+
+
+class RMSNorm(nn.Module):
+    """``x / sqrt(mean(x^2) + eps) * g``, statistics in float32."""
+
+    eps: float = 1e-6
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           self.param_dtype)
+        x32 = x.astype(jnp.float32)
+        y = x32 * jax.lax.rsqrt(
+            jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.eps)
+        return (y * scale.astype(jnp.float32)).astype(self.dtype)
+
+
+class GatedMlp(nn.Module):
+    """``W_down(silu(x W_gate) * x W_up)``, no bias."""
+
+    width: int
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+        d = x.shape[-1]
+        gate = proj(self.width, "gate", self.dtype, self.param_dtype,
+                    ("embed", "model"))(x)
+        up = proj(self.width, "up", self.dtype, self.param_dtype,
+                  ("embed", "model"))(x)
+        return proj(d, "down", self.dtype, self.param_dtype,
+                    ("model", "embed"))(nn.silu(gate) * up)
+
+
+def patch_tokens(images: jnp.ndarray, hidden: int, patch: int, dtype,
+                 param_dtype) -> jnp.ndarray:
+    """Tokens [B, (size / patch)**2, hidden] in raster order from a
+    ``patch`` x ``patch`` / ``patch`` convolution with bias, ``patch_embed``
+    of the module whose ``__call__`` this is called from: what stands
+    where a decoder's token table stood."""
+    with jax.named_scope("tokenize"):
+        x = nn.Conv(hidden, (patch, patch), strides=(patch, patch),
+                    dtype=dtype, param_dtype=param_dtype,
+                    name="patch_embed")(images.astype(dtype))
+        return x.reshape(x.shape[0], -1, hidden)

@@ -38,6 +38,9 @@ import numpy as np
 from flax import linen as nn
 from flax import struct
 
+from tpuic.models.layers import GatedMlp, RMSNorm, patch_tokens
+from tpuic.models.layers import proj as _proj
+
 
 @struct.dataclass
 class LoopedFeatures:
@@ -46,30 +49,6 @@ class LoopedFeatures:
 
     features: jnp.ndarray       # [passes, B, hidden] float32
     gate_logits: jnp.ndarray    # [passes, B] float32
-
-
-def _proj(features: int, name: str, dtype, param_dtype, logical):
-    return nn.Dense(
-        features, use_bias=False, dtype=dtype, param_dtype=param_dtype,
-        name=name, kernel_init=nn.with_logical_partitioning(
-            nn.initializers.xavier_uniform(), logical))
-
-
-class RMSNorm(nn.Module):
-    """``x / sqrt(mean(x^2) + eps) * g``, statistics in float32."""
-
-    eps: float = 1e-6
-    dtype: Any = jnp.float32
-    param_dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
-                           self.param_dtype)
-        x32 = x.astype(jnp.float32)
-        y = x32 * jax.lax.rsqrt(
-            jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.eps)
-        return (y * scale.astype(jnp.float32)).astype(self.dtype)
 
 
 def rotary_tables(positions: int, head_dim: int, theta: float):
@@ -123,22 +102,6 @@ class CausalRotaryAttention(nn.Module):
         out = core(q, k, v).reshape(b, n, width)
         return _proj(d, "o", self.dtype, self.param_dtype,
                      ("model", "embed"))(out)
-
-
-class GatedMlp(nn.Module):
-    width: int
-    dtype: Any = jnp.float32
-    param_dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
-        d = x.shape[-1]
-        gate = _proj(self.width, "gate", self.dtype, self.param_dtype,
-                     ("embed", "model"))(x)
-        up = _proj(self.width, "up", self.dtype, self.param_dtype,
-                   ("embed", "model"))(x)
-        return _proj(d, "down", self.dtype, self.param_dtype,
-                     ("model", "embed"))(nn.silu(gate) * up)
 
 
 class LoopedBlock(nn.Module):
@@ -216,13 +179,8 @@ class LoopedStack(nn.Module):
     @nn.compact
     def __call__(self, x: jnp.ndarray, train: bool = False) -> LoopedFeatures:
         del train       # no dropout, no statistics: one forward for both
-        b = x.shape[0]
-        with jax.named_scope("tokenize"):
-            x = nn.Conv(self.hidden, (self.patch, self.patch),
-                        strides=(self.patch, self.patch), dtype=self.dtype,
-                        param_dtype=self.param_dtype,
-                        name="patch_embed")(x.astype(self.dtype))
-            h = x.reshape(b, -1, self.hidden)
+        h = patch_tokens(x, self.hidden, self.patch, self.dtype,
+                         self.param_dtype)
         loop = nn.scan(LoopPass, variable_broadcast="params",
                        split_rngs={"params": False}, length=self.passes)
         _, features = loop(self.depth, self.num_heads, self.head_dim,

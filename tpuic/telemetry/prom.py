@@ -551,7 +551,7 @@ def train_exposition(report: dict, steptime: Optional[dict] = None,
                      slo: Optional[dict] = None,
                      memory: Optional[dict] = None,
                      profile: Optional[dict] = None,
-                     exits: Optional[dict] = None) -> str:
+                     counters: Optional[dict] = None) -> str:
     """GoodputTracker.report() (+ StepTimer.summary()) -> Prometheus text.
 
     ``heartbeat_age_s`` as in :func:`serve_exposition`; ``restart_count``
@@ -561,8 +561,9 @@ def train_exposition(report: dict, steptime: Optional[dict] = None,
     ``memory``: a MemorySampler.snapshot() (telemetry/memory.py).
     ``profile``: the last device-time waterfall (telemetry/profile.py,
     ``CaptureAnalyzer.last``) for ``device_time_ms{op_class}`` rows.
-    ``exits``: a looped model's last drained exit counters
-    (``Trainer.last_exit_stats``)."""
+    ``counters``: the last drained counters of the model's family
+    (``Trainer.last_counters``): a looped model's exits, a routed layer's
+    pairs and load; one gauge each, by its own name (the rows below)."""
     rows: List[Tuple] = [
         _process_rss_row(),
         ("restart_count", report.get("restarts"), "counter",
@@ -607,14 +608,30 @@ def train_exposition(report: dict, steptime: Optional[dict] = None,
             rows.append((name, v, "gauge",
                          "step-time percentiles over the sliding window",
                          {"quantile": q}))
-    exits = exits or {}
-    rows.append(("exit_expected_pass", exits.get("exit_expected_pass"),
-                 "gauge", "looped model: batch mean of the expected exit "
-                 "pass under the learned exit distribution", None))
-    rows.append(("exit_entropy", exits.get("exit_entropy"), "gauge",
-                 "looped model: batch mean entropy of the learned exit "
-                 "distribution", None))
-    for k, v in sorted(exits.items()):
+    counters = counters or {}
+    # One gauge a counter, by its own name (a literal list: the docs
+    # contract of tpuic.analysis checks every row name against
+    # docs/observability.md).
+    for name, doc in (
+            ("exit_expected_pass", "looped model: batch mean of the "
+             "expected exit pass under the learned exit distribution"),
+            ("exit_entropy", "looped model: batch mean entropy of the "
+             "learned exit distribution"),
+            ("routed_pairs", "routed layer: token-expert pairs a step "
+             "routes (tokens x experts per token), mean over the layers"),
+            ("routed_pairs_held", "routed layer: pairs whose expert this "
+             "chip holds, which are the ones it computes"),
+            ("routed_pairs_dropped", "routed layer: pairs on held experts "
+             "that were not computed (0: the layer is dropless)"),
+            ("routed_layers_over_buffer", "routed layer: share of the "
+             "expert layers whose held pairs exceeded the routed sum's "
+             "buffer, so that the step took the worst-case one"),
+            ("expert_load_max_over_mean", "routed layer: the busiest held "
+             "expert's rows over the mean held expert's"),
+            ("router_entropy", "routed layer: mean over tokens of the "
+             "entropy of the normalised router scores, nats")):
+        rows.append((name, counters.get(name), "gauge", doc, None))
+    for k, v in sorted(counters.items()):
         by_pass = re.fullmatch(r"(exit_p|loss_pass)(\d+)", k)
         if by_pass and by_pass[1] == "exit_p":
             rows.append(("exit_probability", v, "gauge", "looped model: "

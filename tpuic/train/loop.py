@@ -48,16 +48,11 @@ from tpuic.models import create_model_from_config
 from tpuic.runtime.mesh import make_mesh, replicated_sharding
 from tpuic.train.optimizer import make_optimizer, make_schedule
 from tpuic.train.state import create_train_state
-from tpuic.train.step import make_eval_step, make_train_step
+from tpuic.train.step import STEP_METRICS, make_eval_step, make_train_step
 
 # What this module's import graph cost beyond what the caller had already
 # imported (docs/observability.md, "Spans").
 _record_span("import", _T_IMPORT, time.perf_counter(), module=__name__)
-
-
-# Step-metric prefixes of a looped model's exit counters: loss_pass<t>,
-# exit_p<t>, exit_expected_pass, exit_entropy.
-_EXIT_COUNTERS = ("loss_pass", "exit_")
 
 
 def _async_copy(tree) -> None:
@@ -182,7 +177,7 @@ class Trainer:
         with _span("trainer.build_steps"):
             self._build_steps()
         self.last_misclassified: list = []
-        self.last_exit_stats: dict = {}     # looped models; see the drain
+        self.last_counters: dict = {}   # the family's own; see the drain
         with _span("trainer.checkpoint"):
             self.ckpt = CheckpointManager(cfg.run.ckpt_dir, mcfg.name,
                                           cfg.run.save_period,
@@ -607,10 +602,9 @@ class Trainer:
         with _span("train_epoch", epoch=epoch) as sp:
             loss = self._train_epoch(epoch, start_step)
             sp.attrs["steps"] = self.last_epoch_steps - start_step
-            exits = getattr(self, "last_exit_stats", {})
-            if "exit_expected_pass" in exits:
-                # looped models: the epoch's last drained value
-                sp.attrs["exit_expected_pass"] = exits["exit_expected_pass"]
+            # the family's counters (a looped model's exits, a routed
+            # layer's pairs and load): the epoch's last drained values
+            sp.attrs.update(getattr(self, "last_counters", {}))
         return loss
 
     def _train_epoch(self, epoch: int, start_step: int) -> float:
@@ -762,10 +756,10 @@ class Trainer:
                     # deferred drain as the other metrics — rollback
                     # detection costs zero extra host syncs.
                     handles["skip_count"] = metrics["skip_count"]
-                # A looped model's exit counters (train/loss.py
-                # exit_expected_loss) ride the same drain.
+                # The family's counters (a looped model's exits, a routed
+                # layer's pairs and load) ride the same drain.
                 handles.update({k: v for k, v in metrics.items()
-                                if k.startswith(_EXIT_COUNTERS)})
+                                if k not in STEP_METRICS})
                 _async_copy(handles)
                 now = time.perf_counter()
                 imgs_per_sec = log_every * global_batch / max(now - t_log,
@@ -863,12 +857,12 @@ class Trainer:
             delta = streak - last if streak > last else streak
             _tm_publish("skip", step=step_num, streak=streak, delta=delta)
         self._last_skip_streak = streak
-        exits = {k: float(v) for k, v in vals.items()
-                 if k.startswith(_EXIT_COUNTERS)}
-        if exits:
+        counters = {k: float(v) for k, v in vals.items()
+                    if k not in STEP_METRICS}
+        if counters:
             # kept for the train_epoch span and the Prometheus rows
-            self.last_exit_stats = exits
-            extra.update(exits)
+            self.last_counters = counters
+            extra.update(counters)
         self.logger.write(step_num, loss=loss,
                           accuracy=float(vals["accuracy"]),
                           lr=float(vals.get("lr", 0.0)),

@@ -63,6 +63,18 @@ def _moe_router_stats(intermediates) -> list:
     return [tuple(v) for v in by_path.values()]
 
 
+def _sown_counters(sown) -> dict:
+    """The backbone's own counters of this step: what its modules sowed
+    into the ``counters`` collection, by name, each the mean over the
+    modules that sowed it (a routed layer's pairs and load,
+    models/kanana.py: the mean over the expert layers)."""
+    by_name = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(sown):
+        name = next(k.key for k in reversed(path) if hasattr(k, "key"))
+        by_name.setdefault(name, []).append(leaf)
+    return {name: sum(v) / len(v) for name, v in by_name.items()}
+
+
 def _replicated(mesh: Mesh):
     return NamedSharding(mesh, P())
 
@@ -107,6 +119,12 @@ def resolve_remat_policy(model_cfg: ModelConfig):
             "whole-forward remat.",
             stacklevel=2)
     return None
+
+
+# The step's own metrics; any other key of the metrics dict is a counter
+# of the model's family, which the Trainer drains without knowing it.
+STEP_METRICS = frozenset({"loss", "accuracy", "grad_norm", "skipped",
+                          "skip_count", "lr"})
 
 
 def make_train_step(optim_cfg: OptimConfig, model_cfg: ModelConfig,
@@ -296,9 +314,11 @@ def make_train_step(optim_cfg: OptimConfig, model_cfg: ModelConfig,
         def forward(params, batch_stats, images, rng):
             variables = {"params": params, "batch_stats": batch_stats}
             # 'intermediates' carries sown MoE load-balancing losses
-            # (models/moe.py); empty for dense models.
+            # (models/moe.py), 'counters' what a backbone counts in a step
+            # (models/kanana.py); empty for dense models.
             return state.apply_fn(variables, images, train=True,
-                                  mutable=["batch_stats", "intermediates"],
+                                  mutable=["batch_stats", "intermediates",
+                                           "counters"],
                                   rngs={"dropout": rng})
 
         if remat_policy is not None:
@@ -339,6 +359,8 @@ def make_train_step(optim_cfg: OptimConfig, model_cfg: ModelConfig,
 
             with jax.named_scope("loss"):
                 loss, counters = loss_of(labels)
+                counters = {**counters,
+                            **_sown_counters(mutated.get("counters", {}))}
                 if labels_mix is not None:
                     loss = lam * loss + (1.0 - lam) * loss_of(labels_mix)[0]
                 routers = _moe_router_stats(mutated.get("intermediates",
@@ -448,8 +470,11 @@ def make_train_step(optim_cfg: OptimConfig, model_cfg: ModelConfig,
                 acc_mean = jnp.sum(acc * m) / jnp.maximum(jnp.sum(m), 1.0)
             else:
                 acc_mean = jnp.mean(acc)
-        # ``counters``: a looped model's exit statistics (loss_pass<t>,
-        # exit_p<t>, exit_expected_pass, exit_entropy); empty otherwise.
+        # ``counters``: what the family counts in a step, by the names it
+        # gives them: a looped model's exit statistics (loss_pass<t>,
+        # exit_p<t>, exit_expected_pass, exit_entropy), a routed layer's
+        # pairs and load; empty otherwise. Every key of the metrics that
+        # is not in STEP_METRICS is one of them.
         metrics = {"loss": loss, "accuracy": acc_mean,
                    "grad_norm": grad_norm, **counters}
         if optim_cfg.skip_nonfinite:

@@ -413,7 +413,7 @@ def main(argv=None) -> int:
                     slo=slo.report() if slo is not None else None,
                     memory=trainer.telemetry.memory.snapshot(),
                     profile=prof.last if prof is not None else None,
-                    exits=trainer.last_exit_stats))
+                    counters=trainer.last_counters))
             subscribe(_prom_dump, kinds=("goodput",))
     try:
         best = trainer.fit()
