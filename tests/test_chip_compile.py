@@ -10,7 +10,8 @@ pass says the kernel compiles at this shape, not that it is right or fast
 the kernels on the chip).
 
 Shapes are the main path's published widths: ViT-B/16 attention at 224 px
-(N=197) and 768 px (N=2305), the 1000-class loss at batch 128, ResNet-50
+(N=197) and 768 px (N=2305), latent attention's core at the routed cell's
+(batch 32, N=196, 32 heads of 128 + 64 / 128), the 1000-class loss at batch 128, ResNet-50
 leaves and layers. The whole-step compile (~36 s) is not tier-1; see
 scripts/chip_compile_rehearsal.py.
 """
@@ -24,9 +25,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from tpuic.kernels import (flash_attention, fused_conv_bn_relu,
-                           fused_weighted_cross_entropy, lamb_leaf_update,
-                           lars_leaf_update)
+from tpuic.kernels import (causal_attention, flash_attention,
+                           fused_conv_bn_relu, fused_weighted_cross_entropy,
+                           lamb_leaf_update, lars_leaf_update)
 
 
 @pytest.fixture(scope="module")
@@ -78,6 +79,36 @@ def test_flash_attention(chip, shape, grad):
     fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
     n = _compile(fn, chip, *[(shape, BF16)] * 3)
     assert n >= (2 if grad else 1)
+
+
+# Latent attention's core at the routed cell's shapes (batch 32, 196 tokens,
+# 32 heads of 128 + 64 / 128; a head's key beside its value as kv_b writes
+# them), and the same kernel without rotary pieces at the looped stack's
+# (16 heads of 128, keys and values apart).
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("heads,rope,packed", [(32, 64, True),
+                                               (16, 0, False)],
+                         ids=["kanana_30b_packed_kv", "ouro_no_rotary"])
+def test_causal_attention(chip, heads, rope, packed, grad):
+    def fwd(q_nope, *rest):
+        keys = dict(kv=rest[0]) if packed else dict(k_nope=rest[0],
+                                                    v=rest[1])
+        rotary = dict(q_rope=rest[-2], k_rope=rest[-1]) if rope else {}
+        return causal_attention.causal_attention(
+            q_nope, **keys, **rotary, interpret=False)
+
+    def loss(*pieces):
+        return jnp.sum(fwd(*pieces).astype(F32))
+
+    def piece(*dims):
+        return ((32, 196) + dims, BF16)
+    shapes = [piece(heads, 128)] + (
+        [piece(heads, 256)] if packed else [piece(heads, 128)] * 2) + (
+        [piece(heads, rope), piece(rope)] if rope else [])
+    fn = jax.grad(loss, argnums=tuple(range(len(shapes)))) if grad else fwd
+    # one kernel forward; a gradient holds it (the output and the rows'
+    # log-sum-exp are the residuals) and ONE backward kernel
+    assert _compile(fn, chip, *shapes) == (2 if grad else 1)
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])

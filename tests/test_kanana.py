@@ -184,9 +184,103 @@ def test_the_flags_build_the_configurations_widths_and_the_count_is_by_hand():
     attention = 26_345_984 - 512 + 32 * 196 * (192 + 128)
     by_hand = 196 * (6 * attention + 3 * 2048 * 6144
                      + 5 * (3 * 2048 * 1536 + 2048 * 128)) + 196 * 768 * 2048
-    assert abs(counted / by_hand - 1.0) < 0.001
-    assert abs((counted + routed)
+    # ... and, since PR 35, of the attention core's contractions all but
+    # one grid cell's: flops.py counts a pallas_call's body once, which is
+    # one head group of 8 where 32 heads are computed (PERF.md section
+    # 7: count a pallas_call at cells x body)
+    core = 32 * 196 * 196 * (192 + 128)
+    assert abs(counted / (by_hand - 6 * core * 3 / 4) - 1.0) < 0.001
+    assert abs(6 * core * 3 / 4 / by_hand - 0.0350) < 0.001
+    assert abs((by_hand + routed)
                / (FULL["forward_gmacs_per_image_here"] * 1e9) - 1) < 0.005
+
+
+# -- the attention core: one kernel or the dense path, by shape -------------
+
+def _attention(heads=2, nope=128, rope=64, v_dim=128):
+    return kanana.LatentAttention(heads, nope, rope, v_dim, kv_rank=32)
+
+
+def _applied(module, x, seed=0):
+    """``(output, counters)`` of ``module`` on ``x`` with seeded weights."""
+    v = harness.plain_variables(module.init(jax.random.key(seed), x))
+    out, sown = module.apply(v, x, mutable=["counters"])
+    from tpuic.train.step import _sown_counters
+    return out, _sown_counters(sown["counters"]), v
+
+
+@pytest.mark.parametrize("tokens,widths,fused", [
+    (20, dict(), True),                             # the published head
+    (20, dict(nope=16, rope=8, v_dim=12), False),   # kanana_tiny's
+    (1200, dict(), False),                          # the scores leave VMEM
+], ids=["published_widths", "tiny_widths", "over_the_vmem_limit"])
+def test_the_core_is_chosen_by_shape_and_says_which_ran(tokens, widths,
+                                                        fused):
+    module = _attention(**widths)
+    x = jnp.asarray(np.random.default_rng(0).standard_normal(
+        (1, tokens, 64)).astype(np.float32))
+    v = jax.eval_shape(lambda: module.init(jax.random.key(0), x))
+    program = str(jax.make_jaxpr(lambda v, x: module.apply(
+        v, x, mutable=["counters"]))(harness.plain_variables(v), x))
+    assert ("pallas_call" in program) is fused
+    # the dense path's [B, H, N, N] scores
+    assert (f"f32[1,2,{tokens},{tokens}]" in program) is not fused
+    if tokens < 100:
+        _, counters, _ = _applied(module, x)
+        assert counters == {"attention_core_fused": float(fused)}
+
+
+def test_the_registered_names_take_the_core_their_widths_allow():
+    from tpuic.kernels import causal_attention
+    for name, fused in (("kanana-2-30b-a3b", True),
+                        ("kanana-2-30b-a3b-l6e8", True),
+                        ("kanana-tiny", False)):
+        b = create_model(name, 10, dtype="bfloat16").backbone
+        assert causal_attention.supports(
+            (224 // b.patch) ** 2, b.num_heads, b.nope, b.rope, b.v_dim,
+            2) is fused, name
+
+
+def test_a_stack_of_aligned_widths_counts_every_layer_fused():
+    stack = kanana.LatentMoeStack(
+        patch=8, hidden=64, depth=2, dense_layers=1, num_heads=2,
+        kv_rank=32, dense_width=96, num_experts=8, held=(0, 4),
+        expert_width=16, top_k=2, remat_blocks=True)
+    x = jnp.asarray(np.random.default_rng(1).standard_normal(
+        (2, 32, 32, 3)).astype(np.float32))
+    _, counters, _ = _applied(stack, x)
+    assert counters["attention_core_fused"] == 1.0
+    assert counters["routed_pairs"] == 2 * 16 * 2
+
+
+def test_a_remat_block_has_the_same_gradients_through_either_core(
+        monkeypatch):
+    from flax import linen as nn
+    from tpuic.kernels import causal_attention
+    block = nn.remat(kanana.LatentMoeBlock)(
+        dense_width=None, num_heads=2, nope=128, rope=64, v_dim=128,
+        kv_rank=32, num_experts=8, held=(0, 4), expert_width=16, top_k=2,
+        shared_width=32, routed_scale=2.448)
+    x = jnp.asarray(np.random.default_rng(2).standard_normal(
+        (2, 20, 64)).astype(np.float32))
+    v = harness.plain_variables(block.init(jax.random.key(2), x))
+    weigh = jnp.asarray(np.random.default_rng(3).standard_normal(
+        x.shape).astype(np.float32))
+
+    def gradients():
+        return jax.grad(lambda p, x: jnp.sum(block.apply(
+            {"params": p}, x) * weigh), argnums=(0, 1))(v["params"], x)
+    through_kernel = gradients()
+    monkeypatch.setattr(causal_attention, "supports", lambda *a: False)
+    through_dense = gradients()
+    leaves = jax.tree_util.tree_leaves_with_path
+    assert len(leaves(through_kernel)) == len(leaves(through_dense)) > 8
+    for (path, a), (_, b) in zip(leaves(through_kernel),
+                                 leaves(through_dense)):
+        # the selection bias selects and does not weigh: no gradient
+        assert (float(jnp.abs(b).max()) > 0) is (
+            "selection_bias" not in jax.tree_util.keystr(path)), path
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-6)
 
 
 # -- a share of the experts -------------------------------------------------
@@ -490,9 +584,10 @@ def test_counters_reach_the_log_the_prometheus_rows_and_the_span(tmp_path):
     logged = [r for r in rows if "routed_pairs" in r]
     names = ("routed_pairs", "routed_pairs_held", "routed_pairs_dropped",
              "routed_layers_over_buffer", "expert_load_max_over_mean",
-             "router_entropy")
+             "router_entropy", "attention_core_fused")
     assert len(logged) == 2 and all(n in r for r in logged for n in names)
     for r in logged:
+        assert r["attention_core_fused"] == 0.0     # 16 / 8 / 12-wide heads
         assert r["routed_pairs"] == 8 * 64 * 3      # T x top_k, every layer
         assert 0 < r["routed_pairs_held"] < r["routed_pairs"]
         assert r["routed_pairs_dropped"] == 0

@@ -24,7 +24,14 @@ layer's equations are the published ones; no bias in any projection:
   un-rotated key dimensions and ``v_dim`` value dimensions a head, beside
   ONE ``rope``-wide rotary key shared by every head; rotary on interleaved
   pairs ``(2i, 2i+1)``, position = raster index; causal softmax in float32
-  over ``q k^T / sqrt(nope + rope)``;
+  over ``(q_nope k_nope^T + q_rope k_rope^T) / sqrt(nope + rope)``. The
+  core runs in one fused whole-sequence kernel (kernels/
+  causal_attention.py: the four pieces scored where they lie, the shared
+  key never broadcast, scores accumulated in float32 and kept in VMEM)
+  when the heads are whole lane tiles and a cell fits VMEM, which the
+  published widths are and do; otherwise (``kanana_tiny``) in the dense
+  path, which concatenates the pieces and rounds the scores to the compute
+  dtype. ``attention_core_fused`` in the ``counters`` says which ran;
 - expert layer (:class:`ExpertLayer`): ``s = sigmoid(x W_r)`` in float32
   over all ``num_experts``; the ``top_k`` largest of ``s + b`` are chosen
   (``b``: the selection bias, which selects and does not weigh, and gets
@@ -128,25 +135,40 @@ class LatentAttention(nn.Module):
                          name="kv_norm")(kv[..., :self.kv_rank])
         kv_up = dense(h * (nope + v_dim), "kv_b", ("unsharded", "model"))(
             latent).reshape(b, n, h, nope + v_dim)
-        q_rope = interleaved_rotary(q[..., nope:], self.rope_theta)
-        k_rope = interleaved_rotary(kv[..., self.kv_rank:], self.rope_theta)
-        q = jnp.concatenate([q[..., :nope], q_rope.astype(self.dtype)], -1)
-        k = jnp.concatenate(
-            [kv_up[..., :nope], jnp.broadcast_to(
-                k_rope.astype(self.dtype)[:, :, None], (b, n, h, rope))], -1)
+        q_nope = q[..., :nope]
+        q_rope = interleaved_rotary(
+            q[..., nope:], self.rope_theta).astype(self.dtype)
+        k_rope = interleaved_rotary(
+            kv[..., self.kv_rank:], self.rope_theta).astype(self.dtype)
+        # Pallas is imported when a model is traced, not with the registry
+        from tpuic.kernels import causal_attention
+        # by shape alone: heads of whole lane tiles whose scores fit VMEM
+        fused = causal_attention.supports(
+            n, h, nope, rope, v_dim, jnp.dtype(self.dtype).itemsize)
+
         scale = 1.0 / np.sqrt(nope + rope)
 
-        @jax.named_scope("attention_core")
-        def core(q, k, v):
+        def dense_core():
+            q = jnp.concatenate([q_nope, q_rope], -1)
+            k = jnp.concatenate([kv_up[..., :nope], jnp.broadcast_to(
+                k_rope[:, :, None], (b, n, h, rope))], -1)
             logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(
                 jnp.float32) * scale
             causal = np.tril(np.ones((n, n), bool))
             logits = jnp.where(causal[None, None], logits,
                                jnp.finfo(jnp.float32).min)
             probs = nn.softmax(logits, axis=-1).astype(self.dtype)
-            return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+            return jnp.einsum("bhqk,bkhd->bqhd", probs, kv_up[..., nope:])
 
-        out = core(q, k, kv_up[..., nope:]).reshape(b, n, h * v_dim)
+        with jax.named_scope("attention_core"):
+            # the kernel reads a head's key beside its value where kv_b
+            # wrote them
+            out = (causal_attention.causal_attention(
+                q_nope, q_rope=q_rope, k_rope=k_rope, kv=kv_up) if fused
+                else dense_core()).reshape(b, n, h * v_dim)
+        if not self.is_initializing():
+            # a counter of the step, as the routed layer's (ExpertLayer)
+            self.sow("counters", "attention_core_fused", jnp.float32(fused))
         return dense(d, "o", ("model", "embed"))(out)
 
 

@@ -629,7 +629,10 @@ def train_exposition(report: dict, steptime: Optional[dict] = None,
             ("expert_load_max_over_mean", "routed layer: the busiest held "
              "expert's rows over the mean held expert's"),
             ("router_entropy", "routed layer: mean over tokens of the "
-             "entropy of the normalised router scores, nats")):
+             "entropy of the normalised router scores, nats"),
+            ("attention_core_fused", "latent attention: share of the "
+             "stack's attention layers whose core ran in the fused "
+             "whole-sequence kernel (by shape; else the dense path)")):
         rows.append((name, counters.get(name), "gauge", doc, None))
     for k, v in sorted(counters.items()):
         by_pass = re.fullmatch(r"(exit_p|loss_pass)(\d+)", k)
