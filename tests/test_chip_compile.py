@@ -11,7 +11,9 @@ the kernels on the chip).
 
 Shapes are the main path's published widths: ViT-B/16 attention at 224 px
 (N=197) and 768 px (N=2305), latent attention's core at the routed cell's
-(batch 32, N=196, 32 heads of 128 + 64 / 128), the 1000-class loss at batch 128, ResNet-50
+(batch 32, N=196, 32 heads of 128 + 64 / 128), the banded flash kernels at
+the long-sequence cell's (batch 4, N=4096, 32 heads over 4 of 128, a window
+of 1,024 and none), the 1000-class loss at batch 128, ResNet-50
 leaves and layers. The whole-step compile (~36 s) is not tier-1; see
 scripts/chip_compile_rehearsal.py.
 """
@@ -109,6 +111,25 @@ def test_causal_attention(chip, heads, rope, packed, grad):
     # one kernel forward; a gradient holds it (the output and the rows'
     # log-sum-exp are the residuals) and ONE backward kernel
     assert _compile(fn, chip, *shapes) == (2 if grad else 1)
+
+
+# The banded flash kernels at the long-sequence cell's shapes (batch 4,
+# 4,096 tokens, 32 query heads over 4 key-value heads of 128): a sliding
+# layer's window and a full layer's causal mask, through the one function.
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("window", [1024, None], ids=["sliding", "full"])
+def test_banded_flash_attention(chip, window, grad):
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, None, None, False, None, None, True,
+                               window)
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(F32))
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
+    shapes = [((4, 4096, 32, 128), BF16)] + [((4, 4096, 4, 128), BF16)] * 2
+    # forward; a gradient holds it and the dq and dk/dv kernels
+    assert _compile(fn, chip, *shapes) == (3 if grad else 1)
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])

@@ -485,3 +485,132 @@ class TestKernelWiring:
         with pytest.raises(ValueError, match="unknown loss impl"):
             classification_loss(jnp.zeros((2, 3)), jnp.zeros((2,), jnp.int32),
                                 impl="fused-typo")
+
+
+def _masked_attention(q, k, v, causal, window):
+    """Dense softmax attention under the band's mask, the key-value heads
+    repeated over their groups: what the banded kernels must match."""
+    n, group = q.shape[1], q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(t, group, axis=2) for t in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    i, t = np.arange(n)[:, None], np.arange(n)[None]
+    seen = np.ones((n, n), bool)
+    if causal or window is not None:
+        seen &= t <= i
+    if window is not None:
+        seen &= t > i - window
+    p = jax.nn.softmax(jnp.where(seen[None, None], s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+class TestBandedFlashAttention:
+    """``causal``, ``window`` and grouped key-value heads (PR 36): the
+    kernels whose grids hold only the tiles with an unmasked pair."""
+
+    # n = 64 in blocks of 16: window smaller than, equal to and larger
+    # than the length; 50 and 72 are no multiple of a block; Hkv = H, H/2
+    # and H/8; unequal blocks; grouped heads without any mask
+    CASES = [
+        (64, 4, 4, True, None, 16, 16), (64, 4, 2, True, 24, 16, 16),
+        (64, 8, 1, True, 64, 16, 16), (64, 4, 2, False, 100, 16, 32),
+        (50, 4, 2, True, 20, 16, 16), (72, 4, 1, True, 17, 32, 8),
+        (50, 4, 2, False, None, 16, 16)]
+
+    @pytest.mark.parametrize("n,h,hkv,causal,window,bq,bk", CASES)
+    def test_forward_and_all_three_gradients_match_dense(
+            self, n, h, hkv, causal, window, bq, bk):
+        q = _rand(1, (2, n, h, 16))
+        k, v = (_rand(i, (2, n, hkv, 16)) for i in (2, 3))
+        w = _rand(4, (2, n, h, 16))
+
+        def banded(q, k, v):
+            return flash_attention(q, k, v, bq, bk, True, None, None, causal,
+                                   window)
+        np.testing.assert_allclose(
+            np.asarray(banded(q, k, v)),
+            np.asarray(_masked_attention(q, k, v, causal, window)),
+            rtol=1e-5, atol=1e-5)
+        got = jax.grad(lambda *a: jnp.sum(banded(*a) * w), (0, 1, 2))(q, k, v)
+        want = jax.grad(lambda *a: jnp.sum(_masked_attention(
+            *a, causal, window) * w), (0, 1, 2))(q, k, v)
+        for a, b in zip(got, want):
+            assert a.shape == b.shape
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-4, atol=1e-4)
+
+    @pytest.mark.parametrize("n,bq,bk,causal,window,visited", [
+        # a causal square of 8 x 8 blocks: the diagonal and below, 36
+        (4096, 512, 512, True, None, 36),
+        # a band of 1,024 over blocks of 512: rows 0 and 1 see 1 and 2
+        # blocks, every later row its own, the one before and the far
+        # edge's: 1 + 2 + 6 x 3 = 21
+        (4096, 512, 512, True, 1024, 21),
+        # blocks of 128: a causal square 32 x 33 / 2; a band 1 + ... + 8
+        # then 24 rows of 9
+        (4096, 128, 128, True, None, 528),
+        (4096, 128, 128, True, 1024, 36 + 24 * 9),
+        # the window implies the causal mask; one larger than the length
+        # is the causal square
+        (4096, 512, 512, False, 1024, 21), (4096, 512, 512, True, 8192, 36),
+        # key blocks half the query blocks' size: 2 (j + 1) a row
+        (2048, 512, 256, True, None, 2 + 4 + 6 + 8),
+        # bidirectional: the whole square
+        (4096, 512, 512, False, None, 64)])
+    def test_the_grid_holds_the_tiles_counted_by_hand(self, n, bq, bk, causal,
+                                                      window, visited):
+        from tpuic.kernels.flash_attention import _band, blocks_visited
+        assert blocks_visited(n, bq, bk, causal, window) == (
+            visited, (n // bq) * (n // bk))
+        # and it is the launched grid's own list, in both orders
+        causal = causal or window is not None
+        for by_key in (False, True):
+            q_of, k_of, edge = _band(n, bq, bk, causal, window, n, by_key)
+            assert len(q_of) == len(k_of) == len(edge) == visited
+            own = k_of if by_key else q_of
+            firsts = [i for i in range(visited) if edge[i] & 1]
+            lasts = [i for i in range(visited) if edge[i] & 2]
+            assert len(firsts) == len(lasts) == len(set(own.tolist()))
+            assert list(own) == sorted(own)     # own blocks are contiguous
+
+    def test_the_three_calls_carry_their_names_and_the_defaults_none(self):
+        q = jax.ShapeDtypeStruct((2, 64, 4, 16), jnp.float32)
+        k = jax.ShapeDtypeStruct((2, 64, 2, 16), jnp.float32)
+
+        def text(k, **kw):
+            return str(jax.make_jaxpr(jax.grad(
+                lambda q, k, v: jnp.sum(flash_attention(
+                    q, k, v, 16, 16, True, **kw)), (0, 1, 2)))(q, k, k))
+        banded = text(k, causal=True, window=24)
+        for name in ("banded_attention_fwd", "banded_attention_dq",
+                     "banded_attention_dkv"):
+            assert name in banded
+        assert "banded_attention" not in text(q)
+
+    def test_the_defaults_lower_to_the_text_they_did_before_the_band(self):
+        """sha256 of the lowered forward + backward of the bidirectional
+        path, taken on the parent of PR 36 with this installation's jax
+        (folded layout at head size 16, packed at 64): an argument that
+        is not passed changes nothing, to the byte. The text is jax
+        0.9.0's: after an upgrade, unpack the parent (``git archive
+        43728ef | tar -x -C <dir>``), lower this same function there
+        under the new jax and write its digests here; if they then
+        differ from this tree's, the band did change the default path."""
+        import hashlib
+        for shape, want in (((2, 197, 12, 64), "14673288ae7fd1df"),
+                            ((2, 100, 4, 16), "3b4b7045a9fd16fe")):
+            x = jax.ShapeDtypeStruct(shape, jnp.float32)
+
+            def loss(q, k, v):      # the text carries the function's name
+                return jnp.sum(flash_attention(q, k, v, None, None, True))
+            text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(x, x, x).as_text()
+            assert hashlib.sha256(text.encode()).hexdigest()[:16] == want
+
+    def test_heads_that_do_not_divide_are_refused(self):
+        q, k = _rand(1, (1, 16, 4, 8)), _rand(2, (1, 16, 3, 8))
+        with pytest.raises(ValueError, match="whole group"):
+            flash_attention(q, k, k, 8, 8, True, None, None, True)
+
+    def test_on_the_chip_a_head_is_a_whole_lane_tile(self):
+        q = _rand(1, (1, 16, 4, 8))
+        with pytest.raises(ValueError, match="multiple of 128"):
+            flash_attention(q, q, q, 8, 8, False, None, None, True)

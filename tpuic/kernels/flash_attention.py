@@ -1,11 +1,12 @@
-"""Flash attention (blockwise online-softmax) as a Pallas TPU kernel.
+"""Flash attention (blockwise online-softmax) as Pallas TPU kernels.
 
 The reference has no attention op at all (SURVEY.md §2c: vision CNNs only);
 attention enters this framework through the ViT backbone (BASELINE.md config
-4) and the sequence-parallel path (tpuic/parallel/ring_attention.py). This
-kernel is the per-device block primitive: the forward never materializes the
-[N, N] probability matrix in HBM — only [block_q, block_k] tiles in VMEM —
-and contractions are MXU-shaped with a float32 online softmax carried across
+4), the sequence-parallel path (tpuic/parallel/ring_attention.py) and the
+decoder stacks that serve as backbones (models/mellum.py). The kernels are
+the per-device block primitive: the forward never materializes the [N, N]
+probability matrix in HBM — only [block_q, block_k] tiles in VMEM — and
+contractions are MXU-shaped with a float32 online softmax carried across
 key blocks.
 
 Backward is blockwise Pallas too (jax.custom_vjp): the forward saves only
@@ -15,6 +16,31 @@ logsumexp: a dq kernel gridded over query blocks and a dk/dv kernel gridded
 over key blocks, both using the standard FlashAttention identity
 ds = p * (dp - rowsum(do·o)). Peak HBM stays O(N·D) end to end.
 
+``flash_attention(q, k, v, ..., causal=False, window=None)`` is the one
+entry; three variants stand behind it, chosen by what the call asks for:
+
+- **folded** ([B, N, H, D] -> [B*H, N, D]) and **lane-packed** (two 64-wide
+  heads a 128-lane block of the natural [B, N, H*D] layout): bidirectional
+  attention, every head its own key and value, the whole square of tiles
+  visited and only the keys past the sequence masked. A ViT's, and the
+  ring / ulysses compositions' per-step calls. The defaults take this
+  path, as before the mask existed, to the byte of the lowered program.
+- **banded**: a causal mask (``causal``), a sliding window (``window``:
+  query i sees keys i - window < t <= i; implies ``causal``) and grouped
+  key-value heads (``k``, ``v`` of Hkv heads, H % Hkv == 0, query head h
+  reading h // (H // Hkv)). Its grids hold only the (query block, key
+  block) tiles with an unmasked pair: the list of tiles is built on the
+  host and rides in as scalar-prefetch operands that the block index maps
+  read, so a tile above the diagonal or beyond the window costs neither a
+  grid step nor a DMA (``blocks_visited``: a causal square visits 36 of 64
+  tiles of 512 at N = 4096, a window of 1,024 visits 21); the diagonal and
+  the window's far edge are masked by position inside the tile, interior
+  tiles skip the mask. The dk/dv kernel sums over the group's query heads
+  in its scratch. I/O stays in the model's [B, N, H*D] layout, a head one
+  D-wide column block: on the chip D is a multiple of 128. The three calls
+  are named ``banded_attention_fwd``, ``banded_attention_dq`` and
+  ``banded_attention_dkv`` in a device trace.
+
 Sharding: a Pallas call is an opaque custom call — GSPMD/Shardy cannot
 partition it and would all-gather batch-sharded operands onto every device.
 Pass ``mesh`` (with a ``data`` axis) and the wrapper runs the kernel inside
@@ -22,8 +48,8 @@ Pass ``mesh`` (with a ``data`` axis) and the wrapper runs the kernel inside
 batch-parallel; attention itself is per-sample so no collectives are needed.
 
 Layout: [B, N, H, D] ("bqhd", matching models/vit.py einsums). N is padded to
-the key-block size with masked (-inf) keys, so callers can pass any length
-(ViT's 197 tokens included).
+the block size (padded keys masked, or after every real query under a causal
+mask), so callers can pass any length (ViT's 197 tokens included).
 """
 
 from __future__ import annotations
@@ -78,14 +104,26 @@ def _f32_for(ref_dtype, x):
     return x.astype(ref_dtype) if ref_dtype != jnp.float32 else x
 
 
-def _fwd_tile(q_t, k_t, v_t, kpos, vl, m, l, acc, *, scale, prec, dt):
+def _masked(s, kpos, vl, keep):
+    """The score tile with what may not be seen at ``_NEG_INF``: the keys
+    at or past ``vl`` (the bidirectional kernels' only mask), or whatever
+    ``keep`` [bq, bk] leaves out (the banded kernels': a causal diagonal, a
+    window's far edge); ``kpos`` None and no ``keep`` is a tile that lies
+    whole inside its band."""
+    if keep is not None:
+        return jnp.where(keep, s, _NEG_INF)
+    return s if kpos is None else jnp.where(kpos < vl, s, _NEG_INF)
+
+
+def _fwd_tile(q_t, k_t, v_t, kpos, vl, m, l, acc, *, scale, prec, dt,
+              keep=None):
     """One (q-tile, k-tile) online-softmax update — the single copy of the
-    forward tile math shared by the folded and lane-packed kernels.
+    forward tile math shared by the folded, lane-packed and banded kernels.
     Returns (m_new, l_new, acc_new)."""
     s = jax.lax.dot_general(q_t, k_t, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32,
                             precision=prec) * scale          # [bq, bk]
-    s = jnp.where(kpos < vl, s, _NEG_INF)
+    s = _masked(s, kpos, vl, keep)
     m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
     alpha = jnp.exp(m - m_new)
@@ -107,13 +145,14 @@ def _finish_tile(m, l, acc, masked_sentinel):
 
 
 def _bwd_dq_tile(q_t, k_t, v_t, do_t, lse, delta, kpos, vl, *, scale, prec,
-                 dt):
+                 dt, keep=None):
     """dq increment for one (q-tile, k-tile): ds @ k (the caller applies
-    the final ``scale``). Shared by folded and packed dq kernels."""
+    the final ``scale``). Shared by the folded, packed and banded dq
+    kernels."""
     s = scale * jax.lax.dot_general(q_t, k_t, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32,
                                     precision=prec)
-    s = jnp.where(kpos < vl, s, _NEG_INF)
+    s = _masked(s, kpos, vl, keep)
     p = jnp.exp(s - lse)
     dp = jax.lax.dot_general(do_t, v_t, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32,
@@ -124,14 +163,14 @@ def _bwd_dq_tile(q_t, k_t, v_t, do_t, lse, delta, kpos, vl, *, scale, prec,
 
 
 def _bwd_dkv_tile(q_t, k_t, v_t, do_t, lse, delta, kpos, vl, *, scale, prec,
-                  dt):
+                  dt, keep=None):
     """(dk_increment_unscaled, dv_increment) for one (k-tile, q-tile) —
-    the caller applies ``scale`` to dk. Shared by folded and packed
-    dk/dv kernels."""
+    the caller applies ``scale`` to dk. Shared by the folded, packed and
+    banded dk/dv kernels."""
     s = scale * jax.lax.dot_general(q_t, k_t, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32,
                                     precision=prec)
-    s = jnp.where(kpos < vl, s, _NEG_INF)
+    s = _masked(s, kpos, vl, keep)
     p = jnp.exp(s - lse)
     dv_inc = jax.lax.dot_general(_f32_for(dt, p), do_t,
                                  (((0,), (0,)), ((), ())),
@@ -850,6 +889,342 @@ def _flash_bwd_packed(q, k, v, o, lse, do, block_q: int, block_k: int,
     return _unpack(dq, b, h, n, d), dk, dv
 
 
+# -- banded variant: causal mask, sliding window, grouped key-value heads ----
+#
+# A decoder stack's attention (models/mellum.py): query ``i`` sees key ``t``
+# iff ``t <= i`` and, under a window ``W``, ``t > i - W``; ``Hkv`` key-value
+# heads serve ``H`` query heads, head ``h`` reading ``h // (H // Hkv)``. Of
+# the square of (query block, key block) tiles only those that hold an
+# unmasked pair exist: :func:`_band` lists them on the host, the list rides
+# into the kernel as scalar-prefetch operands, and the grid's reduction
+# axis walks the LIST, not the square, so a tile above the diagonal or
+# beyond the window costs neither a grid step nor a DMA. The block index
+# maps read the list (``q_of[s]``, ``k_of[s]``); ``edge[s]`` says whether
+# step ``s`` is the first or the last of its own block (initialise, write
+# back) and whether the tile is cut by the mask or lies whole inside the
+# band (the diagonal and the window's far edge are masked by position in
+# the tile; an interior tile skips the mask's compares and selects).
+#
+# Kernel I/O stays in the model's layout, ``[B, N, H*D]`` (a free reshape):
+# a head is one ``D``-wide column block, so on the chip ``D`` has to be a
+# multiple of the 128 lanes (interpret mode takes any). The key-value
+# block index map divides the head by the group; the dk/dv kernel's grid
+# gains an innermost axis over the group's query heads and sums them in
+# its scratch. lse/delta keep the ``[B*H, 1, N_padded]`` layout of the
+# other variants.
+
+_FIRST, _LAST, _CUT = 1, 2, 4
+
+
+def _band(n_padded: int, block_q: int, block_k: int, causal: bool,
+          window: Optional[int], valid_len: int, by_key: bool = False):
+    """``(q_of, k_of, edge)`` int32 arrays, one entry a tile that holds an
+    unmasked (query, key) pair: ordered by query block then key block, or
+    (``by_key``) by key block then query block; ``edge`` flags the first
+    and last tile of each own block and the tiles the mask cuts."""
+    import numpy as np
+    tiles = []
+    for j in range(n_padded // block_q):
+        lo_row, hi_row = j * block_q, (j + 1) * block_q - 1
+        for ki in range(n_padded // block_k):
+            lo_key = ki * block_k
+            hi_key = min(lo_key + block_k, valid_len) - 1
+            if hi_key < lo_key or (causal and lo_key > hi_row) or (
+                    window is not None and hi_key <= lo_row - window):
+                continue
+            cut = (hi_key < lo_key + block_k - 1
+                   or (causal and hi_key > lo_row)
+                   or (window is not None and lo_key <= hi_row - window))
+            tiles.append((j, ki, _CUT * cut))
+    own = 1 if by_key else 0
+    tiles.sort(key=lambda t: (t[own], t[1 - own]))
+    edge = [cut | (_FIRST * (i == 0 or tiles[i - 1][own] != t[own]))
+            | (_LAST * (i == len(tiles) - 1 or tiles[i + 1][own] != t[own]))
+            for i, (*t, cut) in enumerate(tiles)]
+    q_of, k_of = ([t[i] for t in tiles] for i in (0, 1))
+    return tuple(np.asarray(a, np.int32) for a in (q_of, k_of, edge))
+
+
+def blocks_visited(n: int, block_q: Optional[int] = None,
+                   block_k: Optional[int] = None, causal: bool = False,
+                   window: Optional[int] = None):
+    """``(visited, square)``: the (query block, key block) tiles the banded
+    forward grid is launched with for a sequence of ``n`` (a head of one
+    batch row), and all the tiles of its square."""
+    block_q, block_k = _resolve_blocks(n, block_q, block_k)
+    n_padded = _padded_len(n, block_q, block_k)
+    visited = len(_band(n_padded, block_q, block_k, _causal(causal, window),
+                        window, n)[0])
+    return visited, (n_padded // block_q) * (n_padded // block_k)
+
+
+def _keep(j, ki, block_q: int, block_k: int, causal: bool,
+          window: Optional[int], valid_len: int):
+    """The pairs of tile (``j``, ``ki``) that may be seen, [bq, bk] bool,
+    by position (under a causal mask a padded key lies after every real
+    query and needs no compare of its own)."""
+    qpos = j * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 0)
+    kpos = ki * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 1)
+    keep = kpos <= qpos if causal else kpos < valid_len
+    if window is not None:
+        keep = keep & (qpos - kpos < window)
+    return keep
+
+
+def _either(cut, body):
+    """``body(masked)`` under the branch the tile takes."""
+    pl.when(cut)(lambda: body(True))
+    pl.when(jnp.logical_not(cut))(lambda: body(False))
+
+
+def _banded_fwd_kernel(q_of, k_of, edge, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                       m_s, l_s, acc_s, *, scale: float, keep):
+    s = pl.program_id(2)
+    e = edge[s]
+
+    @pl.when(e & _FIRST != 0)
+    def _init():
+        m_s[...] = jnp.full_like(m_s, _NEG_INF)
+        l_s[...] = jnp.zeros_like(l_s)
+        acc_s[...] = jnp.zeros_like(acc_s)
+
+    dt = q_ref.dtype
+
+    def update(masked):
+        m_new, l_new, acc_new = _fwd_tile(
+            q_ref[0], k_ref[0], v_ref[0], None, None, m_s[:, :1], l_s[:, :1],
+            acc_s[...], scale=scale, prec=_dot_precision(dt), dt=dt,
+            keep=keep(q_of[s], k_of[s]) if masked else None)
+        acc_s[...] = acc_new
+        m_s[...] = jnp.broadcast_to(m_new, m_s.shape)
+        l_s[...] = jnp.broadcast_to(l_new, l_s.shape)
+    _either(e & _CUT != 0, update)
+
+    @pl.when(e & _LAST != 0)
+    def _finish():
+        o, lse = _finish_tile(m_s[:, :1], l_s[:, :1], acc_s[...], 0.0)
+        o_ref[0] = o.astype(o_ref.dtype)
+        lse_ref[0, 0] = lse
+
+
+def _banded_dq_kernel(q_of, k_of, edge, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                      delta_ref, dq_ref, acc_s, *, scale: float, keep):
+    s = pl.program_id(2)
+    e = edge[s]
+
+    @pl.when(e & _FIRST != 0)
+    def _init():
+        acc_s[...] = jnp.zeros_like(acc_s)
+
+    dt = q_ref.dtype
+
+    def update(masked):
+        acc_s[...] += _bwd_dq_tile(
+            q_ref[0], k_ref[0], v_ref[0], do_ref[0], lse_ref[0, 0][:, None],
+            delta_ref[0, 0][:, None], None, None, scale=scale,
+            prec=_dot_precision(dt), dt=dt,
+            keep=keep(q_of[s], k_of[s]) if masked else None)
+    _either(e & _CUT != 0, update)
+
+    @pl.when(e & _LAST != 0)
+    def _finish():
+        dq_ref[0] = (scale * acc_s[...]).astype(dq_ref.dtype)
+
+
+def _banded_dkv_kernel(q_of, k_of, edge, q_ref, k_ref, v_ref, do_ref,
+                       lse_ref, delta_ref, dk_ref, dv_ref, dk_s, dv_s, *,
+                       scale: float, group: int, keep):
+    """One (batch, key-value head, tile of a key block's list, query head
+    of the group) program: dk and dv of the key block summed in scratch
+    over its query blocks and over the group's query heads."""
+    s, g = pl.program_id(2), pl.program_id(3)
+    e = edge[s]
+
+    @pl.when((e & _FIRST != 0) & (g == 0))
+    def _init():
+        dk_s[...] = jnp.zeros_like(dk_s)
+        dv_s[...] = jnp.zeros_like(dv_s)
+
+    dt = q_ref.dtype
+
+    def update(masked):
+        dk_inc, dv_inc = _bwd_dkv_tile(
+            q_ref[0], k_ref[0], v_ref[0], do_ref[0], lse_ref[0, 0][:, None],
+            delta_ref[0, 0][:, None], None, None, scale=scale,
+            prec=_dot_precision(dt), dt=dt,
+            keep=keep(q_of[s], k_of[s]) if masked else None)
+        dv_s[...] += dv_inc
+        dk_s[...] += scale * dk_inc
+    _either(e & _CUT != 0, update)
+
+    @pl.when((e & _LAST != 0) & (g == group - 1))
+    def _finish():
+        dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
+
+
+def _banded_setup(q, k, block_q, block_k, interpret, causal, window,
+                  static_valid):
+    b, n, h, d = q.shape
+    hkv = k.shape[2]
+    if h % hkv:
+        raise ValueError(f"{h} query heads over {hkv} key-value heads: "
+                         "each key-value head serves a whole group")
+    if not interpret and d % _LANES:
+        raise ValueError(
+            f"the banded kernels read a head as a column block of the "
+            f"[B, N, H*D] operand: on the chip D = {d} has to be a "
+            f"multiple of {_LANES}")
+    n_padded = _padded_len(n, block_q, block_k)
+    valid_len = n if static_valid is None else static_valid
+    keep = functools.partial(
+        _keep, block_q=block_q, block_k=block_k, causal=causal,
+        window=window, valid_len=valid_len)
+    tiles = functools.partial(_band, n_padded, block_q, block_k, causal,
+                              window, valid_len)
+    return b, n, h, hkv, d, n_padded, keep, tiles
+
+
+def _band_spec(rows, d, index):
+    return pl.BlockSpec((1, rows, d), index, memory_space=pltpu.VMEM)
+
+
+def _row_spec(rows, index):
+    return pl.BlockSpec((1, 1, rows), index, memory_space=pltpu.VMEM)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "block_q", "block_k", "interpret", "causal", "window", "static_valid"))
+def _banded_fwd(q, k, v, block_q: int, block_k: int, interpret: bool,
+                causal: bool, window: Optional[int], static_valid=None):
+    """q [B, N, H, D], k, v [B, N, Hkv, D] -> (out [B, N, H, D], logsumexp
+    [B*H, 1, N_padded])."""
+    b, n, h, hkv, d, n_padded, keep, tiles = _banded_setup(
+        q, k, block_q, block_k, interpret, causal, window, static_valid)
+    group = h // hkv
+    q_of, k_of, edge = tiles()
+    steps = len(edge)
+    own = lambda bi, hi, s, q_of, k_of, edge: (bi, q_of[s], hi)
+    red = lambda bi, hi, s, q_of, k_of, edge: (bi, k_of[s], hi // group)
+    out, lse = pl.pallas_call(
+        functools.partial(_banded_fwd_kernel, scale=1.0 / (d ** 0.5),
+                          keep=keep),
+        out_shape=[jax.ShapeDtypeStruct((b, n_padded, h * d), q.dtype),
+                   jax.ShapeDtypeStruct((b * h, 1, n_padded), jnp.float32)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(b, h, steps),
+            in_specs=[_band_spec(block_q, d, own),
+                      _band_spec(block_k, d, red),
+                      _band_spec(block_k, d, red)],
+            out_specs=[_band_spec(block_q, d, own),
+                       _row_spec(block_q,
+                                 lambda bi, hi, s, q_of, k_of, edge:
+                                 (bi * h + hi, 0, q_of[s]))],
+            scratch_shapes=[pltpu.VMEM((block_q, _LANES), jnp.float32),
+                            pltpu.VMEM((block_q, _LANES), jnp.float32),
+                            pltpu.VMEM((block_q, d), jnp.float32)]),
+        compiler_params=_compiler_params(),
+        interpret=interpret,
+        name="banded_attention_fwd",
+        cost_estimate=pl.CostEstimate(
+            flops=4 * b * h * steps * block_q * block_k * d,
+            bytes_accessed=(2 * h + 2 * hkv) * b * n_padded * d
+            * q.dtype.itemsize,
+            transcendentals=b * h * steps * block_q * block_k),
+    )(q_of, k_of, edge, _pack(q, b, n, h, d, n_padded),
+      _pack(k, b, n, hkv, d, n_padded), _pack(v, b, n, hkv, d, n_padded))
+    return _unpack(out, b, h, n, d), lse
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "block_q", "block_k", "interpret", "causal", "window", "static_valid"))
+def _banded_bwd(q, k, v, o, lse, do, block_q: int, block_k: int,
+                interpret: bool, causal: bool, window: Optional[int],
+                static_valid=None):
+    """(dq [B, N, H, D], dk, dv [B, N, Hkv, D]) over the same band."""
+    b, n, h, hkv, d, n_padded, keep, tiles = _banded_setup(
+        q, k, block_q, block_k, interpret, causal, window, static_valid)
+    group = h // hkv
+    scale = 1.0 / (d ** 0.5)
+    qp, dop = (_pack(t, b, n, h, d, n_padded) for t in (q, do))
+    kp, vp = (_pack(t, b, n, hkv, d, n_padded) for t in (k, v))
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    delta = _pad_seq(jnp.transpose(delta, (0, 2, 1)).reshape(b * h, n, 1),
+                     n_padded)[..., 0][:, None, :]
+    flops = lambda steps: 5 * b * h * steps * block_q * block_k * d
+    bytes_accessed = (3 * h + 3 * hkv) * b * n_padded * d * q.dtype.itemsize
+
+    q_of, k_of, edge = tiles()
+    own = lambda bi, hi, s, q_of, k_of, edge: (bi, q_of[s], hi)
+    red = lambda bi, hi, s, q_of, k_of, edge: (bi, k_of[s], hi // group)
+    row = lambda bi, hi, s, q_of, k_of, edge: (bi * h + hi, 0, q_of[s])
+    dq = pl.pallas_call(
+        functools.partial(_banded_dq_kernel, scale=scale, keep=keep),
+        out_shape=jax.ShapeDtypeStruct((b, n_padded, h * d), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(b, h, len(edge)),
+            in_specs=[_band_spec(block_q, d, own),
+                      _band_spec(block_k, d, red),
+                      _band_spec(block_k, d, red),
+                      _band_spec(block_q, d, own),
+                      _row_spec(block_q, row), _row_spec(block_q, row)],
+            out_specs=_band_spec(block_q, d, own),
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]),
+        compiler_params=_compiler_params(),
+        interpret=interpret,
+        name="banded_attention_dq",
+        cost_estimate=pl.CostEstimate(
+            flops=flops(len(edge)), bytes_accessed=bytes_accessed,
+            transcendentals=b * h * len(edge) * block_q * block_k),
+    )(q_of, k_of, edge, qp, kp, vp, dop, lse, delta)
+
+    # the same tiles by key block; the innermost axis walks the group's
+    # query heads (the key and value blocks stay where they are meanwhile)
+    q_of, k_of, edge = tiles(by_key=True)
+    head = lambda bi, hi, s, g, q_of, k_of, edge: (
+        bi, q_of[s], hi * group + g)
+    kv = lambda bi, hi, s, g, q_of, k_of, edge: (bi, k_of[s], hi)
+    row = lambda bi, hi, s, g, q_of, k_of, edge: (
+        bi * h + hi * group + g, 0, q_of[s])
+    dk, dv = pl.pallas_call(
+        functools.partial(_banded_dkv_kernel, scale=scale, group=group,
+                          keep=keep),
+        out_shape=[jax.ShapeDtypeStruct((b, n_padded, hkv * d), k.dtype),
+                   jax.ShapeDtypeStruct((b, n_padded, hkv * d), v.dtype)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(b, hkv, len(edge), group),
+            in_specs=[_band_spec(block_q, d, head),
+                      _band_spec(block_k, d, kv), _band_spec(block_k, d, kv),
+                      _band_spec(block_q, d, head),
+                      _row_spec(block_q, row), _row_spec(block_q, row)],
+            out_specs=[_band_spec(block_k, d, kv),
+                       _band_spec(block_k, d, kv)],
+            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                            pltpu.VMEM((block_k, d), jnp.float32)]),
+        compiler_params=_compiler_params(
+            ("parallel", "parallel", "arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="banded_attention_dkv",
+        cost_estimate=pl.CostEstimate(
+            flops=flops(len(edge)), bytes_accessed=bytes_accessed,
+            transcendentals=b * h * len(edge) * block_q * block_k),
+    )(q_of, k_of, edge, qp, kp, vp, dop, lse, delta)
+    return (_unpack(dq, b, h, n, d), _unpack(dk, b, hkv, n, d),
+            _unpack(dv, b, hkv, n, d))
+
+
+def _causal(causal: bool, window) -> bool:
+    """A window looks back: it implies the causal mask."""
+    return bool(causal) or window is not None
+
+
+def _is_banded(q, k, causal: bool, window) -> bool:
+    """Whether a call leaves the bidirectional kernels' ground."""
+    return _causal(causal, window) or k.shape[2] != q.shape[2]
+
+
 def _shard_batch(mesh: Optional[Mesh], b: int) -> bool:
     """True when the kernel should run under shard_map over the data axis."""
     if mesh is None or "data" not in mesh.axis_names:
@@ -858,19 +1233,35 @@ def _shard_batch(mesh: Optional[Mesh], b: int) -> bool:
     return n_data > 1 and b % n_data == 0
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def flash_attention(q, k, v, block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
                     mesh: Optional[Mesh] = None,
-                    valid_len: Optional[int] = None):
-    """Softmax attention, [B, N, H, D] in/out, no causal mask (ViT is
-    bidirectional). ``block_q``/``block_k`` default to a length-adaptive
-    size (``_resolve_blocks``); ``interpret=None`` auto-selects interpret
-    mode off-TPU; ``mesh`` keeps the kernel batch-parallel under a sharded
-    jit (see module docstring); ``valid_len`` masks keys beyond a static
-    count when the inputs carry caller-side padding (ulysses)."""
+                    valid_len: Optional[int] = None,
+                    causal: bool = False, window: Optional[int] = None):
+    """Softmax attention, ``q`` [B, N, H, D] in and out, ``k`` and ``v``
+    [B, N, Hkv, D] with ``H % Hkv == 0`` (query head ``h`` reads key-value
+    head ``h // (H // Hkv)``). Bidirectional by default (a ViT's);
+    ``causal`` lets query ``i`` see the keys ``t <= i`` and ``window``
+    (which implies ``causal``: a window looks back) only those with ``t >
+    i - window``. A call with a mask or with fewer key-value heads than
+    heads takes the banded kernels, whose grids hold only the tiles with
+    an unmasked pair (:func:`blocks_visited`).
+
+    ``block_q``/``block_k`` default to a length-adaptive size
+    (``_resolve_blocks``); ``interpret=None`` auto-selects interpret mode
+    off-TPU; ``mesh`` keeps the kernel batch-parallel under a sharded jit
+    (see module docstring); ``valid_len`` masks keys beyond a static count
+    when the inputs carry caller-side padding (ulysses)."""
     block_q, block_k = _resolve_blocks(q.shape[1], block_q, block_k)
+    if _is_banded(q, k, causal, window):
+        with jax.named_scope("flash_attention"):
+            return _batch_parallel(
+                lambda interp, *ops: _banded_fwd(
+                    *ops, block_q, block_k, interp, _causal(causal, window),
+                    window, static_valid=valid_len)[0],
+                mesh, interpret, 1, q, k, v)
     fwd, _ = _select_kernels(q.shape[2], q.shape[3])
     # Scope tag for the device-time waterfall (telemetry/profile.py):
     # the Pallas custom-call rolls up under 'flash_attention' instead of
@@ -902,8 +1293,17 @@ def _batch_parallel(fn, mesh, interpret, n_out, *operands):
     )(*operands)
 
 
-def _vjp_fwd(q, k, v, block_q, block_k, interpret, mesh, valid_len=None):
+def _vjp_fwd(q, k, v, block_q, block_k, interpret, mesh, valid_len=None,
+             causal=False, window=None):
     block_q, block_k = _resolve_blocks(q.shape[1], block_q, block_k)
+    if _is_banded(q, k, causal, window):
+        with jax.named_scope("flash_attention"):
+            out, lse = _batch_parallel(
+                lambda interp, *ops: _banded_fwd(
+                    *ops, block_q, block_k, interp, _causal(causal, window),
+                    window, static_valid=valid_len),
+                mesh, interpret, 2, q, k, v)
+        return out, (q, k, v, out, lse)
     fwd, _ = _select_kernels(q.shape[2], q.shape[3])
     with jax.named_scope("flash_attention"):
         out, lse = _batch_parallel(
@@ -914,10 +1314,18 @@ def _vjp_fwd(q, k, v, block_q, block_k, interpret, mesh, valid_len=None):
     return out, (q, k, v, out, lse)
 
 
-def _vjp_bwd(block_q, block_k, interpret, mesh, valid_len, res, g):
+def _vjp_bwd(block_q, block_k, interpret, mesh, valid_len, causal, window,
+             res, g):
     q, k, v, out, lse = res
     # Same resolution as the forward: lse was padded with these blocks.
     block_q, block_k = _resolve_blocks(q.shape[1], block_q, block_k)
+    if _is_banded(q, k, causal, window):
+        with jax.named_scope("flash_attention_bwd"):
+            return _batch_parallel(
+                lambda interp, *ops: _banded_bwd(
+                    *ops, block_q, block_k, interp, _causal(causal, window),
+                    window, static_valid=valid_len),
+                mesh, interpret, 3, q, k, v, out, lse, g)
     _, bwd = _select_kernels(q.shape[2], q.shape[3])
     with jax.named_scope("flash_attention_bwd"):
         return _batch_parallel(

@@ -24,6 +24,7 @@ from tpuic.models import inception as _inception
 from tpuic.models import vit as _vit
 from tpuic.models import ouro as _ouro
 from tpuic.models import kanana as _kanana
+from tpuic.models import mellum as _mellum
 
 
 # The ``ModelConfig.remat_policy`` values that are flags of a backbone;
@@ -178,6 +179,24 @@ def _register_builtins():
     _latent_moe("kanana-2-30b-a3b-l6e8", _kanana.kanana_2_30b_a3b, depth=6,
                 held=(0, 8))
     _latent_moe("kanana-tiny", _kanana.kanana_tiny)
+
+    def _band_moe(name, ctor, **extra):
+        def build(cfg, mesh):
+            # RMSNorm only; the core is the banded flash kernel (causal,
+            # windowed, grouped heads), whatever cfg.attention says
+            return ctor(**_dtypes(cfg), mesh=mesh,
+                        remat_blocks=_remat(cfg, "blocks"), **extra)
+        register(name, build, remat_policies=("blocks",))
+
+    # Sliding-window and full layers, grouped key-value heads, a softmax
+    # router (models/mellum.py): Mellum2-12B-A2.5B at its published counts,
+    # and expert-parallel rank 0 of 8 in the first pipeline stage of seven
+    # (one period of 4 of the 28 layers, 8 of the 64 experts of each;
+    # every width, the window, the tables and the router as published).
+    _band_moe("mellum2-12b-a2.5b", _mellum.mellum2_12b_a2_5b)
+    _band_moe("mellum2-12b-a2.5b-l4e8", _mellum.mellum2_12b_a2_5b, depth=4,
+              held=(0, 8))
+    _band_moe("mellum-tiny", _mellum.mellum_tiny)
 
     def _inc(cfg, mesh):
         # torch inception: eps 1e-3 (module default, not cfg.bn_eps); f32

@@ -82,6 +82,11 @@ SELECTION_BIAS_SIGMA = 0.02
 # that is off balance by a factor of two, past which a step pays for the
 # worst case's gather and scatter-add instead.
 BUFFER_OVER_EVEN_LOAD = 2
+# A worst case of up to this many rows goes through one buffer of its own;
+# a larger one (16,384 tokens choosing 8 of 8 held experts are 131,072 rows,
+# 7.9 GiB of reserved temporaries at width 2,304) through the usual buffer
+# as many times over as it takes. ROADMAP Queue 1: the passes alone.
+ONE_BUFFER_WORST_ROWS = 65_536
 HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -231,6 +236,31 @@ def buffer_rows(tokens: int, top_k: int, held: int, num_experts: int):
     return min(rows, worst), worst
 
 
+def _routed_passes(x, order, sizes, weights, gate_up, down, *, rows: int,
+                   worst: int):
+    """The worst case through the buffer of ``rows``, as many times over
+    as it takes: pass ``c`` carries the sorted pairs ``[c rows, (c + 1)
+    rows)`` and the part of each expert's group that lies there. One pass
+    is alive at a time, in the backward pass too (each is recomputed), so
+    the worst case costs the memory of the usual one."""
+    passes = -(-worst // rows)
+    order = jnp.pad(order, (0, max(0, passes * rows - order.shape[0])))
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+
+    def one(y, lo):
+        part = jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo, lo + rows)
+        more, _ = _routed_rows(
+            x, jax.lax.dynamic_slice(order, (lo,), (rows,)), part, weights,
+            gate_up, down, rows=rows)
+        return y + more, None
+    y, _ = jax.lax.scan(
+        jax.checkpoint(one), jnp.zeros((x.shape[0], down.shape[-1]),
+                                       jnp.float32),
+        rows * jnp.arange(passes, dtype=jnp.int32))
+    return y, jnp.minimum(jnp.sum(sizes), passes * rows)
+
+
 def routed_sum(x: jnp.ndarray, chosen: jnp.ndarray, weights: jnp.ndarray,
                gate_up: jnp.ndarray, down: jnp.ndarray, first: int,
                num_experts: int):
@@ -241,7 +271,9 @@ def routed_sum(x: jnp.ndarray, chosen: jnp.ndarray, weights: jnp.ndarray,
     D] float32, sizes [held], computed, over)``: the rows each held expert
     got, how many pairs were computed (all of them: nothing is dropped),
     and whether they exceeded the buffer and went through the worst
-    case's."""
+    case's: one buffer that no routing overflows or, where that one
+    would hold more than ``ONE_BUFFER_WORST_ROWS``, the usual buffer as
+    many times over as it takes."""
     tokens, top_k = chosen.shape
     held = gate_up.shape[0]
     local = chosen - first
@@ -256,9 +288,52 @@ def routed_sum(x: jnp.ndarray, chosen: jnp.ndarray, weights: jnp.ndarray,
     if rows == worst:
         y, computed = through(rows=worst)
     else:
-        y, computed = jax.lax.cond(over, lambda: through(rows=worst),
+        worst_case = functools.partial(
+            _routed_passes, x, order, sizes, weights, gate_up, down,
+            rows=rows, worst=worst) if worst > ONE_BUFFER_WORST_ROWS \
+            else functools.partial(through, rows=worst)
+        y, computed = jax.lax.cond(over, worst_case,
                                    lambda: through(rows=rows))
     return y, sizes, computed, over
+
+
+def held_experts(module: nn.Module, held: int, d: int, width: int):
+    """``(gate_up [held, d, 2 width], down [held, width, d])`` of
+    ``module`` (which has ``dtype`` and ``param_dtype``), in its compute
+    dtype: the experts a routed layer holds, as ``routed_sum`` takes
+    them."""
+    def experts(name, shape, logical):
+        return module.param(
+            name, nn.with_logical_partitioning(
+                nn.initializers.variance_scaling(
+                    1.0, "fan_avg", "uniform", in_axis=-2, out_axis=-1,
+                    batch_axis=(0,)), logical),
+            (held,) + shape, module.param_dtype).astype(module.dtype)
+    return (experts("experts_gate_up", (d, 2 * width),
+                    ("unsharded", "embed", "unsharded")),
+            experts("experts_down", (width, d),
+                    ("unsharded", "unsharded", "embed")))
+
+
+def sow_routing_counters(module: nn.Module, pairs: int, sizes, computed, over,
+                         scores) -> None:
+    """A routed layer's counters of the step (train/step.py), under the
+    names every family's routed layer sows; nothing outside a step.
+    ``scores`` [T, experts] are the router's, of any scale."""
+    if module.is_initializing():
+        return
+    load = sizes.astype(jnp.float32)
+    share = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    for name, value in (
+            ("routed_pairs", jnp.float32(pairs)),
+            ("routed_pairs_held", jnp.sum(load)),
+            ("routed_pairs_dropped", jnp.sum(load) - computed),
+            ("routed_layers_over_buffer", over.astype(jnp.float32)),
+            ("expert_load_max_over_mean",
+             jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9)),
+            ("router_entropy", jnp.mean(-jnp.sum(
+                share * jnp.log(jnp.maximum(share, 1e-30)), axis=-1)))):
+        module.sow("counters", name, jax.lax.stop_gradient(value))
 
 
 class ExpertLayer(nn.Module):
@@ -301,17 +376,7 @@ class ExpertLayer(nn.Module):
             weights = self.routed_scale * picked / (
                 jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
 
-        def experts(name, shape, logical):
-            return self.param(
-                name, nn.with_logical_partitioning(
-                    nn.initializers.variance_scaling(
-                        1.0, "fan_avg", "uniform", in_axis=-2, out_axis=-1,
-                        batch_axis=(0,)), logical),
-                (held,) + shape, self.param_dtype).astype(self.dtype)
-        gate_up = experts("experts_gate_up", (d, 2 * self.width),
-                          ("unsharded", "embed", "unsharded"))
-        down = experts("experts_down", (self.width, d),
-                       ("unsharded", "unsharded", "embed"))
+        gate_up, down = held_experts(self, held, d, self.width)
         with jax.named_scope("routed_experts"):
             y, sizes, computed, over = routed_sum(
                 xf.astype(self.dtype), chosen, weights, gate_up, down, first,
@@ -320,21 +385,8 @@ class ExpertLayer(nn.Module):
             shared = GatedMlp(self.shared_width, self.dtype,
                               self.param_dtype, name="shared")(x)
 
-        if not self.is_initializing():
-            # the step's counters (train/step.py); nothing outside a step
-            load = sizes.astype(jnp.float32)
-            share = scores / jnp.sum(scores, axis=-1, keepdims=True)
-            for name, value in (
-                    ("routed_pairs", jnp.float32(b * n * self.top_k)),
-                    ("routed_pairs_held", jnp.sum(load)),
-                    ("routed_pairs_dropped", jnp.sum(load) - computed),
-                    ("routed_layers_over_buffer", over.astype(jnp.float32)),
-                    ("expert_load_max_over_mean",
-                     jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9)),
-                    ("router_entropy", jnp.mean(-jnp.sum(
-                        share * jnp.log(jnp.maximum(share, 1e-30)),
-                        axis=-1)))):
-                self.sow("counters", name, jax.lax.stop_gradient(value))
+        sow_routing_counters(self, b * n * self.top_k, sizes, computed, over,
+                             scores)
         return shared + y.astype(self.dtype).reshape(b, n, d)
 
 

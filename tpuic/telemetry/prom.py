@@ -632,7 +632,16 @@ def train_exposition(report: dict, steptime: Optional[dict] = None,
              "entropy of the normalised router scores, nats"),
             ("attention_core_fused", "latent attention: share of the "
              "stack's attention layers whose core ran in the fused "
-             "whole-sequence kernel (by shape; else the dense path)")):
+             "whole-sequence kernel (by shape; else the dense path)"),
+            ("attention_key_blocks_visited", "banded attention: (query "
+             "block, key block) tiles the kernel's forward grids hold for "
+             "a head of one image, summed over the layers"),
+            ("attention_key_blocks_square", "banded attention: all the "
+             "tiles of those layers' squares, masked or not"),
+            ("attention_window_layers", "banded attention: layers with a "
+             "sliding window"),
+            ("attention_full_layers", "banded attention: causal layers "
+             "without a window")):
         rows.append((name, counters.get(name), "gauge", doc, None))
     for k, v in sorted(counters.items()):
         by_pass = re.fullmatch(r"(exit_p|loss_pass)(\d+)", k)
