@@ -391,13 +391,17 @@ def test_remat_changes_nothing_but_the_residuals():
 
 def test_the_routed_cells_and_the_looped_cells_steps_lower_as_before():
     """``models/kanana.py`` gained two helpers and ``routed_sum`` a
-    second worst case in PR 36, and ``ouro.py`` is imported from: the
-    lowered train steps of the two cells that use them, at batch 2, are
-    the parent's to the byte (sha256 taken on the parent with this
-    installation's jax, 0.9.0). After an upgrade of jax the digests go
-    stale with no fault in the tree: unpack the parent (``git archive
-    43728ef | tar -x -C <dir>``), run this test's body there under the new
-    jax, and write the digests it gives here."""
+    second worst case in PR 36: the routed cell's lowered train step, at
+    batch 2, is the parent's to the byte (sha256 taken on the parent with
+    this installation's jax, 0.9.0), and PR 38's counter of its rotary
+    paths, a Python count, leaves it so. The looped cell's digest is PR
+    38's own: its rotary became one pass each way under a hand-written
+    VJP in the projection's layout (``layers.rotate``), so its step text
+    moved on purpose (the parent's was 410193a1e483ec11); this pin holds
+    it there. After an upgrade of jax the digests go stale with no fault
+    in the tree: unpack the commit that last wrote them (``git archive
+    <commit> | tar -x -C <dir>``), run this test's body there under the
+    new jax, and write the digests it gives here."""
     import hashlib
     from flax.core import meta
     from tpuic.config import ModelConfig, OptimConfig
@@ -406,7 +410,7 @@ def test_the_routed_cells_and_the_looped_cells_steps_lower_as_before():
     from tpuic.train.state import TrainState
     from tpuic.train.step import make_train_step
     for name, want in (("kanana-2-30b-a3b-l6e8", "d81c45772d1655c0"),
-                       ("ouro-2.6b-l6", "410193a1e483ec11")):
+                       ("ouro-2.6b-l6", "e05ef1f4ce254bae")):
         mc = ModelConfig(name=name, num_classes=1000, dtype="bfloat16",
                          remat=True, remat_policy="blocks")
         oc = OptimConfig(optimizer="adam", class_weights=(), milestones=())
@@ -484,3 +488,6 @@ def test_counters_reach_the_log_the_prometheus_rows_and_the_span(tmp_path):
     assert all(epoch["attrs"][n] == logged[-1][n] for n in names)
     from tpuic.train.step import STEP_METRICS
     assert not set(names) & STEP_METRICS
+    built = [r for r in spans.ledger.snapshot()
+             if r["name"] == "trainer.build_steps"][-1]
+    assert built["attrs"]["rotary_one_pass_share"] == 1.0

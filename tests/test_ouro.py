@@ -116,7 +116,8 @@ def test_attention_is_causal_and_the_read_out_sees_every_token():
 
 
 def test_rotary_turns_pairs_by_position_and_keeps_their_length():
-    from tpuic.models.ouro import apply_rotary, rotary_tables
+    from tpuic.models.layers import rotate
+    from tpuic.models.ouro import rotary_tables
     cos, sin = rotary_tables(196, 128, 1e6)
     assert cos.shape == sin.shape == (196, 128) and cos.dtype == np.float32
     np.testing.assert_allclose(cos[0], 1.0)
@@ -126,17 +127,135 @@ def test_rotary_turns_pairs_by_position_and_keeps_their_length():
     np.testing.assert_allclose(cos[5, 0], math.cos(5.0), rtol=1e-5)
     np.testing.assert_allclose(sin[7, 63], math.sin(7.0 * 1e6 ** (-126 / 128)),
                                rtol=1e-4)
-    x = jax.random.normal(jax.random.key(0), (2, 196, 3, 128))
-    y = apply_rotary(x, cos, sin)
-    np.testing.assert_allclose(jnp.linalg.norm(y, axis=-1),
-                               jnp.linalg.norm(x, axis=-1), rtol=1e-5)
+    x = jax.random.normal(jax.random.key(0), (2, 196, 3 * 128))
+    y = rotate(x, cos, sin)
+    np.testing.assert_allclose(
+        jnp.linalg.norm(y.reshape(2, 196, 3, 128), axis=-1),
+        jnp.linalg.norm(x.reshape(2, 196, 3, 128), axis=-1), rtol=1e-5)
     # q.k depends on the distance alone
-    q, k = x[:, :1, :1], x[:, 1:2, :1]
+    q, k = x[:, :1, :128], x[:, 1:2, :128]
     def dot(i, j):
-        qi = apply_rotary(q, cos[i:i + 1], sin[i:i + 1])
-        kj = apply_rotary(k, cos[j:j + 1], sin[j:j + 1])
+        qi = rotate(q, cos[i:i + 1], sin[i:i + 1])
+        kj = rotate(k, cos[j:j + 1], sin[j:j + 1])
         return jnp.sum(qi * kj)
     np.testing.assert_allclose(dot(9, 4), dot(105, 100), rtol=1e-4)
+
+
+def _rotary_in_float32_passes(x, cos, sin):
+    """The rotary as it was before PR 38, the oracle of the one-pass one:
+    ``x`` [B, N, H, D] upcast, split, negated, concatenated, multiplied
+    and added in float32 (autodiff builds its gradient)."""
+    x = x.astype(jnp.float32)
+    x1, x2 = jnp.split(x, 2, axis=-1)
+    rotated = jnp.concatenate([-x2, x1], axis=-1)
+    return x * cos[None, :, None, :] + rotated * sin[None, :, None, :]
+
+
+@pytest.mark.parametrize("yarn", [False, True], ids=["plain", "yarn"])
+@pytest.mark.parametrize("n,h,d", [(196, 16, 128), (64, 4, 128), (7, 3, 8)])
+def test_the_one_pass_rotary_is_the_float32_passes(n, h, d, yarn):
+    """``layers.rotate`` against the passes it replaced: bfloat16 out
+    within one bfloat16 ulp of their float32 result, its hand-written VJP
+    their ``jax.vjp`` to float32 rounding (and to one ulp in bfloat16),
+    on the plain and the YaRN tables, at a head of 128 and a tiny one, at
+    row counts a tile's 8 divides and does not. Where the two terms
+    cancel, the ulp of the result is below float32's rounding of the
+    terms, so that rounding (``2^-20`` of the largest input) is allowed
+    beside it."""
+    from tpuic.models.layers import rotate
+    from tpuic.models.mellum import YARN, layer_rotary_tables
+    cos, sin = layer_rotary_tables(n, d, 500000.0, YARN if yarn else None)
+    b = 1 if n == 7 else 2      # 7 rows: no tile of 8 to view them by
+    x = 2.0 * jax.random.normal(jax.random.key(n + d), (b, n, h * d))
+    g = jax.random.normal(jax.random.key(n + d + 1), (b, n, h * d))
+
+    def before(x):
+        return _rotary_in_float32_passes(
+            x.reshape(b, n, h, d), cos, sin).reshape(b, n, h * d)
+
+    def after(x):
+        return rotate(x, cos, sin)
+
+    def ulp(v, of):     # of bfloat16 at |v|, and float32's of the terms
+        return (2.0 ** (np.floor(np.log2(np.maximum(np.abs(v), 1e-30))) - 7)
+                + 2.0 ** -20 * float(jnp.max(jnp.abs(of))))
+
+    xb, gb = x.astype(jnp.bfloat16), g.astype(jnp.bfloat16)
+    want = np.asarray(before(xb))
+    got = after(xb)
+    assert got.dtype == jnp.bfloat16
+    assert np.all(np.abs(np.asarray(got, np.float32) - want)
+                  <= ulp(want, xb))
+    want_dx = np.asarray(jax.vjp(before, xb.astype(jnp.float32))[1](
+        gb.astype(jnp.float32))[0])
+    got_dx = jax.vjp(after, xb)[1](gb)[0]
+    assert got_dx.dtype == jnp.bfloat16
+    assert np.all(np.abs(np.asarray(got_dx, np.float32) - want_dx)
+                  <= ulp(want_dx, gb))
+    # float32 in, float32 out: the same numbers to rounding, both ways
+    np.testing.assert_allclose(after(x), before(x), rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(jax.vjp(after, x)[1](g)[0],
+                               jax.vjp(before, x)[1](g)[0],
+                               rtol=1e-6, atol=1e-6)
+
+
+def test_each_rotary_path_is_counted_as_a_model_traces_it():
+    """``ROTARY_TRACED`` counts in Python, at trace time: the looped
+    stack's two rotations a block go the one-pass way, latent
+    attention's two a layer the interleaved way."""
+    from tpuic.models.layers import ROTARY_TRACED
+    before = ROTARY_TRACED.copy()
+    for name in ("ouro-tiny", "kanana-tiny"):
+        model = create_model(name, num_classes=10)
+        jax.eval_shape(lambda: model.init(jax.random.key(0),
+                                          jnp.zeros((1, 32, 32, 3))))
+        traced = ROTARY_TRACED - before
+        before = ROTARY_TRACED.copy()
+        assert set(traced) == ({"one_pass"} if name == "ouro-tiny"
+                               else {"interleaved"}), (name, traced)
+        assert traced[next(iter(traced))] % 2 == 0     # queries and keys
+
+
+def test_tracing_the_looped_step_imports_no_pallas():
+    """PR 37 lost its gain to set-up seconds in this cell; the likely
+    cause was Pallas lowered inside the looped stack's scan. Tracing and
+    lowering the cell's train step (batch 2, CPU) must leave
+    ``jax.experimental.pallas`` unimported."""
+    import subprocess
+    import sys
+    script = """
+import sys
+import jax, jax.numpy as jnp
+from flax.core import meta
+from tpuic.config import ModelConfig, OptimConfig
+from tpuic.models import create_model_from_config
+from tpuic.train.optimizer import make_optimizer
+from tpuic.train.state import TrainState
+from tpuic.train.step import make_train_step
+mc = ModelConfig(name="ouro-2.6b-l6", num_classes=1000, dtype="bfloat16",
+                 remat=True, remat_policy="blocks", attention="dense")
+oc = OptimConfig(optimizer="adam", class_weights=(), milestones=())
+model = create_model_from_config(mc)
+x = jnp.zeros((2, 224, 224, 3))
+v = meta.unbox(jax.eval_shape(
+    lambda: model.init(jax.random.key(0), x, train=False)))
+tx = make_optimizer(oc, 8, 1, global_batch=2)
+state = jax.eval_shape(lambda p: TrainState(
+    step=jnp.zeros((), jnp.int32), params=p, batch_stats={},
+    opt_state=tx.init(p), apply_fn=model.apply, tx=tx, ema_params=None,
+    skip_count=jnp.zeros((), jnp.int32)), v["params"])
+batch = {"image": jax.ShapeDtypeStruct(x.shape, jnp.float32),
+         "label": jax.ShapeDtypeStruct((2,), jnp.int32),
+         "mask": jax.ShapeDtypeStruct((2,), jnp.float32)}
+make_train_step(oc, mc, mesh=None, donate=False).lower(state, batch)
+print("PALLAS", sorted(m for m in sys.modules if "pallas" in m))
+"""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=root)
+    out = subprocess.run([sys.executable, "-c", script], cwd=root, env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "PALLAS []" in out.stdout, out.stdout[-2000:]
 
 
 # -- the objective ----------------------------------------------------------

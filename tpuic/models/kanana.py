@@ -71,7 +71,8 @@ import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
 
-from tpuic.models.layers import GatedMlp, RMSNorm, patch_tokens, proj
+from tpuic.models.layers import (ROTARY_TRACED, GatedMlp, RMSNorm,
+                                 patch_tokens, proj)
 
 # The seeded selection bias is normal and this wide: of the scores' size
 # near the top-k boundary (about 0.01 between neighbours under random
@@ -93,6 +94,7 @@ HIGHEST = jax.lax.Precision.HIGHEST
 def interleaved_rotary(x: jnp.ndarray, theta: float) -> jnp.ndarray:
     """``x`` [B, N, ..., D] with the pairs ``(x[2i], x[2i+1])`` turned by
     ``position * theta^(-2i/D)``, in float32."""
+    ROTARY_TRACED["interleaved"] += 1
     n, d = x.shape[1], x.shape[-1]
     inv_freq = (1.0 / theta ** (np.arange(0, d, 2, dtype=np.float32)
                                 / np.float32(d))).astype(np.float32)

@@ -1,8 +1,9 @@
 """Shared model building blocks.
 
 The decoder stacks that serve as backbones (models/ouro.py,
-models/kanana.py) share the bias-free projection, ``RMSNorm``, the gated
-SiLU MLP and the patch tokeniser below.
+models/kanana.py, models/mellum.py) share the bias-free projection,
+``RMSNorm``, the gated SiLU MLP and the patch tokeniser below; the looped
+and the banded stacks share the one-pass rotary, ``rotate``.
 
 The MLP classifier head reproduces the reference's
 ``in_features -> 128 -> ReLU -> 64 -> ReLU -> 32 -> ReLU -> num_classes`` head
@@ -20,10 +21,12 @@ The MLP classifier head reproduces the reference's
 
 from __future__ import annotations
 
+import collections
 from typing import Any, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 
 
@@ -124,6 +127,71 @@ class GatedMlp(nn.Module):
                   ("embed", "model"))(x)
         return proj(d, "down", self.dtype, self.param_dtype,
                     ("model", "embed"))(nn.silu(gate) * up)
+
+
+# Rotary applications traced in this process, by path: ``one_pass`` (the
+# function below) or ``interleaved`` (models/kanana.py). Counted in Python
+# as a model is traced, no device op; the trainer reports the share.
+ROTARY_TRACED = collections.Counter()
+
+
+def rotate(x: jnp.ndarray, cos, sin) -> jnp.ndarray:
+    """``x`` [B, N, H*D], as a projection writes it, turned by position:
+    ``x cos + R(x) sin`` with ``R(x) = [-x2, x1]`` within each head (the
+    rotate-half layout), from the tables ``cos``, ``sin`` [N, D] (numpy
+    float32, one pair for every head). float32 arithmetic, ``x``'s dtype
+    in and out.
+
+    One pass each way under a hand-written VJP. With ``S = sin`` signed
+    ``[-1, +1]`` by half, ``R(x) sin = swap(x) S`` where ``swap`` trades
+    the halves; and since ``R^T = -R``, the gradient ``g cos + R^T(g sin)``
+    is ``g cos + swap(g) swap(S)``: the same pass over ``g`` with another
+    table, so autodiff builds no slices, pads or concatenates. The swap
+    acts on ``x``'s own dtype (a permutation: exact), and ``x`` is read as
+    ``[B*N/8, H, 8, D]``, the bytes of ``[B, N, H*D]`` under the chip's
+    (8, 128) tiles, so the pass needs no relayout and the [N, D] tables
+    broadcast over heads without being tiled. Jitted, so that an eager
+    ``model.init`` dispatches one program a shape."""
+    ROTARY_TRACED["one_pass"] += 1
+    d = cos.shape[-1]
+    signed = sin * np.where(np.arange(d) < d // 2, -1, 1).astype(np.float32)
+    with jax.named_scope("rotary"):
+        return _rotate(x, cos, signed, np.roll(signed, d // 2, axis=-1))
+
+
+def _turn(x, cos, signed):
+    """``x cos + swap(x) signed`` per head, ``x`` [B, N, H*D]."""
+    b, n, width = x.shape
+    d = cos.shape[-1]
+    rows = b * n
+    group = 8 if rows % 8 == 0 else 1      # the rows of a tile
+    v = x.reshape(rows // group, group, width // d, d).transpose(0, 2, 1, 3)
+    swapped = jnp.roll(v, d // 2, axis=-1)
+
+    def table(t):
+        return jnp.broadcast_to(t, (b, n, d)).reshape(
+            rows // group, 1, group, d)
+    y = (v.astype(jnp.float32) * table(cos) +
+         swapped.astype(jnp.float32) * table(signed))
+    return y.astype(x.dtype).transpose(0, 2, 1, 3).reshape(b, n, width)
+
+
+@jax.custom_vjp
+def _rotate_once(x, cos, signed, swapped_signed):
+    return _turn(x, cos, signed)
+
+
+def _rotate_fwd(x, cos, signed, swapped_signed):
+    return _turn(x, cos, signed), (cos, swapped_signed)
+
+
+def _rotate_bwd(tables, g):
+    cos, swapped_signed = tables
+    return _turn(g, cos, swapped_signed), None, None, None
+
+
+_rotate_once.defvjp(_rotate_fwd, _rotate_bwd)
+_rotate = jax.jit(_rotate_once)
 
 
 def patch_tokens(images: jnp.ndarray, hidden: int, patch: int, dtype,

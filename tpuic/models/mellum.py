@@ -65,8 +65,8 @@ from flax import linen as nn
 
 from tpuic.models.kanana import (HIGHEST, held_experts, routed_sum,
                                  sow_routing_counters, standardized)
-from tpuic.models.layers import RMSNorm, patch_tokens, proj
-from tpuic.models.ouro import apply_rotary, rotary_tables
+from tpuic.models.layers import RMSNorm, patch_tokens, proj, rotate
+from tpuic.models.ouro import rotary_tables
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 # (factor, original_max_position_embeddings, beta_fast, beta_slow,
@@ -127,15 +127,13 @@ class GroupedBandAttention(nn.Module):
 
         def heads(name, count):
             return proj(count * self.head_dim, name, self.dtype,
-                        self.param_dtype, ("embed", "model"))(x).reshape(
-                            b, n, count, self.head_dim)
+                        self.param_dtype, ("embed", "model"))(x)
         q, k, v = (heads("q", self.num_heads), heads("k", self.kv_heads),
                    heads("v", self.kv_heads))
-        with jax.named_scope("rotary"):
-            cos, sin = layer_rotary_tables(n, self.head_dim, self.rope_theta,
-                                           self.yarn)
-            q = apply_rotary(q, cos, sin).astype(self.dtype)
-            k = apply_rotary(k, cos, sin).astype(self.dtype)
+        cos, sin = layer_rotary_tables(n, self.head_dim, self.rope_theta,
+                                       self.yarn)
+        q, k = rotate(q, cos, sin), rotate(k, cos, sin)
+        q, k, v = (t.reshape(b, n, -1, self.head_dim) for t in (q, k, v))
         # Pallas is imported when a model is traced, not with the registry
         from tpuic.kernels.flash_attention import flash_attention
         block_q, block_k = self.blocks or (None, None)

@@ -38,7 +38,7 @@ import numpy as np
 from flax import linen as nn
 from flax import struct
 
-from tpuic.models.layers import GatedMlp, RMSNorm, patch_tokens
+from tpuic.models.layers import GatedMlp, RMSNorm, patch_tokens, rotate
 from tpuic.models.layers import proj as _proj
 
 
@@ -61,14 +61,6 @@ def rotary_tables(positions: int, head_dim: int, theta: float):
     return np.cos(angles), np.sin(angles)
 
 
-def apply_rotary(x: jnp.ndarray, cos, sin) -> jnp.ndarray:
-    """``x`` [B, N, H, Dh] rotated by its position, in float32."""
-    x = x.astype(jnp.float32)
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    rotated = jnp.concatenate([-x2, x1], axis=-1)
-    return x * cos[None, :, None, :] + rotated * sin[None, :, None, :]
-
-
 class CausalRotaryAttention(nn.Module):
     num_heads: int
     head_dim: int
@@ -81,12 +73,11 @@ class CausalRotaryAttention(nn.Module):
         b, n, d = x.shape
         width = self.num_heads * self.head_dim
         q, k, v = (_proj(width, name, self.dtype, self.param_dtype,
-                         ("embed", "model"))(x).reshape(
-                             b, n, self.num_heads, self.head_dim)
-                   for name in ("q", "k", "v"))
+                         ("embed", "model"))(x) for name in ("q", "k", "v"))
         cos, sin = rotary_tables(n, self.head_dim, self.rope_theta)
-        q = apply_rotary(q, cos, sin).astype(self.dtype)
-        k = apply_rotary(k, cos, sin).astype(self.dtype)
+        q, k = rotate(q, cos, sin), rotate(k, cos, sin)
+        q, k, v = (t.reshape(b, n, self.num_heads, self.head_dim)
+                   for t in (q, k, v))
         scale = 1.0 / np.sqrt(self.head_dim)
 
         @jax.named_scope("attention_core")

@@ -45,6 +45,7 @@ from tpuic.data.pipeline import Loader
 from tpuic.metrics.logging import MetricLogger, host0_print, is_host0
 from tpuic.metrics.meters import AverageMeter
 from tpuic.models import create_model_from_config
+from tpuic.models.layers import ROTARY_TRACED
 from tpuic.runtime.mesh import make_mesh, replicated_sharding
 from tpuic.train.optimizer import make_optimizer, make_schedule
 from tpuic.train.state import create_train_state
@@ -92,6 +93,7 @@ class Trainer:
                 self.val_ds = pack_dataset(self.val_ds, cache, verbose=is_host0())
             global_batch = self._build_loaders()
             sp.attrs["images"] = len(self.train_ds)
+        rotary_before = ROTARY_TRACED.copy()
         with _span("trainer.state_init") as sp:
             num_classes = cfg.model.num_classes or self.train_ds.num_classes
             mcfg = cfg.model
@@ -174,8 +176,13 @@ class Trainer:
                 # carry different input shardings and compile the step twice.
                 self.state = jax.device_put(self.state,
                                             replicated_sharding(self.mesh))
-        with _span("trainer.build_steps"):
+        with _span("trainer.build_steps") as sp:
             self._build_steps()
+            # What the model's init traced, by rotary path (no device op)
+            rotary = ROTARY_TRACED - rotary_before
+            if rotary:
+                sp.attrs["rotary_one_pass_share"] = (
+                    rotary["one_pass"] / sum(rotary.values()))
         self.last_misclassified: list = []
         self.last_counters: dict = {}   # the family's own; see the drain
         with _span("trainer.checkpoint"):
