@@ -200,20 +200,23 @@ def test_the_one_pass_rotary_is_the_float32_passes(n, h, d, yarn):
 
 
 def test_each_rotary_path_is_counted_as_a_model_traces_it():
-    """``ROTARY_TRACED`` counts in Python, at trace time: the looped
-    stack's two rotations a block go the one-pass way, latent
-    attention's two a layer the interleaved way."""
-    from tpuic.models.layers import ROTARY_TRACED
-    before = ROTARY_TRACED.copy()
-    for name in ("ouro-tiny", "kanana-tiny"):
-        model = create_model(name, num_classes=10)
-        jax.eval_shape(lambda: model.init(jax.random.key(0),
-                                          jnp.zeros((1, 32, 32, 3))))
-        traced = ROTARY_TRACED - before
-        before = ROTARY_TRACED.copy()
-        assert set(traced) == ({"one_pass"} if name == "ouro-tiny"
-                               else {"interleaved"}), (name, traced)
-        assert traced[next(iter(traced))] % 2 == 0     # queries and keys
+    """Each rotary path lies under the scope ``rotary`` in the compiled
+    train step, where the device trace's reader finds it, forward and
+    backward: the looped stack's through the one-pass ``rotate`` (ops
+    inside ``jit(_rotate_once)``), latent attention's through
+    ``interleaved_rotary`` (none there)."""
+    from tpuic.telemetry.profile import Programs, scope_path
+    for name, one_pass in (("ouro-tiny", True), ("kanana-tiny", False)):
+        mcfg = dataclasses.replace(MCFG, name=name)
+        state = jax.eval_shape(lambda: _state(mcfg))
+        progs = Programs()
+        progs.note("step", make_train_step(OCFG, mcfg, donate=False),
+                   (state, jax.eval_shape(_batch)))
+        rotary = [n for n, _, _ in progs.scope_map("step")["ops"].values()
+                  if "rotary" in scope_path(n)]
+        assert any("transpose(" in n for n in rotary), name
+        assert any("transpose(" not in n for n in rotary), name
+        assert any("_rotate_once" in n for n in rotary) == one_pass, name
 
 
 def test_tracing_the_looped_step_imports_no_pallas():

@@ -120,12 +120,13 @@ def make_device_prep(mean=None, std=None, out_dtype=jnp.float32,
     ``sharding``: the batch's data-axis NamedSharding under a mesh — the
     prep is elementwise per sample, so it runs shard-local with no
     collectives."""
-    fn = lambda imgs, packed: apply_batch_augment(
-        imgs, _unpack_params(packed), mean=mean, std=std,
-        out_dtype=out_dtype)
+    # named: the function's name is the program's in a device trace
+    def device_prep(imgs, packed):
+        return apply_batch_augment(imgs, _unpack_params(packed), mean=mean,
+                                   std=std, out_dtype=out_dtype)
     if sharding is None:
-        return jax.jit(fn)
-    return jax.jit(fn, in_shardings=(sharding, sharding),
+        return jax.jit(device_prep)
+    return jax.jit(device_prep, in_shardings=(sharding, sharding),
                    out_shardings=sharding, donate_argnums=(0,))
 
 
@@ -197,7 +198,8 @@ def make_resident_prep(size: int, mean=None, std=None,
     row = size * size * 3
     tiles, pieces = _resident_geometry(size)
 
-    def fn(data, idx, packed):
+    # named: the function's name is the program's in a device trace
+    def resident_prep(data, idx, packed):
         batch = idx.shape[0]
         # [N,R,128] -> [N*pieces, 8*tiles, 128] splits whole tiles off the
         # untiled axis: no bytes move. One piece a row at 224 px.
@@ -209,8 +211,9 @@ def make_resident_prep(size: int, mean=None, std=None,
         return apply_batch_augment(imgs, _unpack_params(packed), mean=mean,
                                    std=std, out_dtype=out_dtype)
     if sharding is None:
-        return jax.jit(fn)
-    return jax.jit(fn, in_shardings=(replicated, sharding, sharding),
+        return jax.jit(resident_prep)
+    return jax.jit(resident_prep,
+                   in_shardings=(replicated, sharding, sharding),
                    out_shardings=sharding)
 
 

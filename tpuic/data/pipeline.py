@@ -274,6 +274,7 @@ class Loader:
         self.resident_bytes = 0
         self._device_prep = None
         self._resident_prep = None
+        self.prep_specs = None
         self._data_dev = None
         # Running ahead engages where a batch costs the host a loop over
         # its samples (the augment draws); see the class docstring.
@@ -315,6 +316,13 @@ class Loader:
             else:
                 self._device_prep = make_device_prep(
                     mean=c.mean, std=c.std, sharding=self._sharding)
+
+    @property
+    def prep_fn(self):
+        """The jitted per-batch device program of the packed path (resident
+        or streaming), None on the decode path; ``prep_specs`` are the
+        abstract arguments of its first dispatch."""
+        return self._resident_prep or self._device_prep
 
     def __len__(self) -> int:
         n = len(self.dataset)
@@ -492,13 +500,15 @@ class Loader:
                 payload, labels, mask, ids, params, gidx = item
                 if params is None:            # decode path: host float32
                     image = self._to_global(payload)
-                elif self.resident:           # host ships indices + params
-                    image = self._resident_prep(
-                        self._data_dev, self._to_device(payload),
-                        self._to_device(params))
-                else:                         # streaming uint8 + params
-                    image = self._device_prep(self._to_device(payload),
-                                              self._to_device(params))
+                else:
+                    args = ((self._data_dev,) if self.resident else ()) + (
+                        self._to_device(payload), self._to_device(params))
+                    if self.prep_specs is None:
+                        from tpuic.telemetry.profile import abstract
+                        self.prep_specs = abstract(args)
+                    # resident: the host ships indices + params; streaming:
+                    # the uint8 batch + params
+                    image = self.prep_fn(*args)
                 batch = Batch(image=image,
                               label=self._to_global(labels),
                               mask=self._to_global(mask))

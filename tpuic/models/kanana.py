@@ -71,8 +71,7 @@ import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
 
-from tpuic.models.layers import (ROTARY_TRACED, GatedMlp, RMSNorm,
-                                 patch_tokens, proj)
+from tpuic.models.layers import GatedMlp, RMSNorm, patch_tokens, proj
 
 # The seeded selection bias is normal and this wide: of the scores' size
 # near the top-k boundary (about 0.01 between neighbours under random
@@ -94,17 +93,17 @@ HIGHEST = jax.lax.Precision.HIGHEST
 def interleaved_rotary(x: jnp.ndarray, theta: float) -> jnp.ndarray:
     """``x`` [B, N, ..., D] with the pairs ``(x[2i], x[2i+1])`` turned by
     ``position * theta^(-2i/D)``, in float32."""
-    ROTARY_TRACED["interleaved"] += 1
     n, d = x.shape[1], x.shape[-1]
     inv_freq = (1.0 / theta ** (np.arange(0, d, 2, dtype=np.float32)
                                 / np.float32(d))).astype(np.float32)
     angles = np.arange(n, dtype=np.float32)[:, None] * inv_freq[None]
     shape = (1, n) + (1,) * (x.ndim - 3) + (d // 2,)
     cos, sin = np.cos(angles).reshape(shape), np.sin(angles).reshape(shape)
-    x = x.astype(jnp.float32)
-    even, odd = x[..., 0::2], x[..., 1::2]
-    return jnp.stack([even * cos - odd * sin, odd * cos + even * sin],
-                     axis=-1).reshape(x.shape)
+    with jax.named_scope("rotary"):
+        x = x.astype(jnp.float32)
+        even, odd = x[..., 0::2], x[..., 1::2]
+        return jnp.stack([even * cos - odd * sin, odd * cos + even * sin],
+                         axis=-1).reshape(x.shape)
 
 
 def standardized(images: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:

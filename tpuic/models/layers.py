@@ -21,7 +21,6 @@ The MLP classifier head reproduces the reference's
 
 from __future__ import annotations
 
-import collections
 from typing import Any, Sequence
 
 import jax
@@ -129,12 +128,6 @@ class GatedMlp(nn.Module):
                     ("model", "embed"))(nn.silu(gate) * up)
 
 
-# Rotary applications traced in this process, by path: ``one_pass`` (the
-# function below) or ``interleaved`` (models/kanana.py). Counted in Python
-# as a model is traced, no device op; the trainer reports the share.
-ROTARY_TRACED = collections.Counter()
-
-
 def rotate(x: jnp.ndarray, cos, sin) -> jnp.ndarray:
     """``x`` [B, N, H*D], as a projection writes it, turned by position:
     ``x cos + R(x) sin`` with ``R(x) = [-x2, x1]`` within each head (the
@@ -152,7 +145,6 @@ def rotate(x: jnp.ndarray, cos, sin) -> jnp.ndarray:
     (8, 128) tiles, so the pass needs no relayout and the [N, D] tables
     broadcast over heads without being tiled. Jitted, so that an eager
     ``model.init`` dispatches one program a shape."""
-    ROTARY_TRACED["one_pass"] += 1
     d = cos.shape[-1]
     signed = sin * np.where(np.arange(d) < d // 2, -1, 1).astype(np.float32)
     with jax.named_scope("rotary"):

@@ -8,15 +8,17 @@ ops are compute-bound vs HBM-bound; the 15-minute-ImageNet line
 (arXiv 1711.04325) and every TPU scaling paper start from exactly this
 per-op accounting.  This module is that accounting:
 
-- **Trace analyzer** (:func:`parse_trace`): parses captured
-  ``jax.profiler`` artifacts (the Chrome-trace ``*.trace.json[.gz]``
-  every capture writes) into per-op-class device time — matmul/conv vs
-  elementwise vs reduce vs copy/transpose vs collective — with a
-  per-layer rollup from the ``jax.named_scope``/flax module paths in
-  each op's metadata.  Device-side per-op events exist on TPU/GPU
-  captures; a CPU capture carries none, and the analyzer says so
-  (returns None) instead of fabricating a waterfall.
-- **HLO cost model** (:func:`hlo_waterfall`): where the runtime exposes
+- **Trace reader** (:func:`parse_trace`): reads the ``.xplane.pb`` a
+  ``jax.profiler`` capture writes and gives a device's time a step, on
+  the device's clock, by program, by scope (the ``jax.named_scope`` and
+  flax module paths in each op's HLO metadata, from the scope maps of the
+  executables the run registered: :data:`programs`) and by op class —
+  each busy instant charged once, to the innermost op, so the parts sum
+  to the busy time — and its idle time by the host's ``tpuic.*``
+  annotation over each gap.  A CPU capture has no device plane, and the
+  reader says so (returns None) instead of fabricating a waterfall.
+- **HLO cost model** (:func:`hlo_waterfall`), the fallback where a
+  capture has no device plane: where the runtime exposes
   it, the already-AOT-lowered executables (train/step.py warmup,
   serve/engine.py buckets) yield ``compiled.as_text()`` +
   ``compiled.cost_analysis()``; the model classifies every entry-
@@ -62,8 +64,8 @@ field instead of killing the run (the tracing.py discipline).
 
 from __future__ import annotations
 
+import bisect
 import glob
-import gzip
 import json
 import os
 import re
@@ -167,7 +169,7 @@ def classify_fusion(called_opcodes: Sequence[str]) -> str:
 # ops of the same layer land in the same bucket (the backward's extra
 # time is part of that layer's cost).
 _DROP_WRAPPERS = re.compile(r"^(jit|pjit|xla_call|vmap|pmap|shard_map|"
-                            r"while|body|cond)\b")
+                            r"while|body|cond|closed_call|branch_\d+_fun)\b")
 _UNWRAP_WRAPPERS = re.compile(r"^(transpose|jvp|vjp|remat|checkpoint|"
                               r"rematted_computation|custom_jvp|"
                               r"custom_vjp|named)\b")
@@ -212,87 +214,510 @@ def layer_of(op_name: str, depth: int = 3) -> str:
     return "/".join(segs) if segs else "(unattributed)"
 
 
-# -- chrome-trace parsing (real captures) -------------------------------------
-def _trace_files(path: str) -> List[str]:
-    """Trace JSON files of a capture: accepts the session dir a
-    TraceTrigger wrote (``trace-NNNN-<ts>/``), the ``plugins`` parent, or
-    a direct ``*.trace.json[.gz]`` file."""
+def scope_path(op_name: str) -> List[str]:
+    """The scopes an op lies under: its metadata ``op_name`` less the
+    primitive it ends in, through :func:`scope_segments`, each scope once
+    (a rematerialised backward names its forward's path a second time:
+    ``transpose(jvp(Classifier))/backbone/jvp(Classifier)/backbone/...``)."""
+    out: List[str] = []
+    for seg in scope_segments("/".join(str(op_name).split("/")[:-1])):
+        if seg not in out:
+            out.append(seg)
+    return out
+
+
+# -- the programs a run dispatches --------------------------------------------
+# The device trace names each op by its HLO instruction and each execution
+# by its module; what the instruction was in the program (its scope, its
+# opcode) is in the compiled executable's text. So the Trainer registers
+# the programs it dispatches every step, by role, with the abstract
+# arguments of their first dispatch, and a reader asks for the map of an
+# executable only after the window: lowering the same function on the same
+# abstract arguments finds JAX's in-memory executable, so nothing compiles.
+UNSCOPED = "(unscoped)"
+UNLABELLED = "(unlabelled)"
+
+
+def abstract(tree):
+    """``tree`` with every array replaced by a ``jax.ShapeDtypeStruct`` of
+    its shape, dtype and weak type, and the sharding of a committed array
+    (an uncommitted one was dispatched without one). Abstract leaves and
+    anything else pass through. Holds no array."""
+    import jax
+    import numpy as np
+
+    def leaf(x):
+        if isinstance(x, jax.Array):
+            return jax.ShapeDtypeStruct(
+                x.shape, x.dtype, weak_type=x.aval.weak_type,
+                sharding=x.sharding if x.committed else None)
+        if isinstance(x, (np.ndarray, np.generic)):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype)
+        return x
+    return jax.tree.map(leaf, tree)
+
+
+class _Program:
+    __slots__ = ("fn", "specs", "map")
+
+    def __init__(self, fn, specs) -> None:
+        self.fn, self.specs, self.map = fn, specs, None
+
+
+class Programs:
+    """The jitted programs of a run, by role (``"step"``,
+    ``"input_prep"``): each the callable and the abstract arguments of its
+    first dispatch, no array and no owner."""
+
+    def __init__(self) -> None:
+        self._held: Dict[str, _Program] = {}
+
+    def note(self, role: str, fn, args: Sequence) -> None:
+        """Register ``fn`` under ``role`` unless it is already there. Meant
+        for every dispatch: a second call with the same callable costs a
+        dict lookup. A callable that cannot be lowered is not a program
+        (a stand-in that wraps the step) and is skipped."""
+        held = self._held.get(role)
+        if held is not None and held.fn is fn:
+            return
+        if not callable(getattr(fn, "lower", None)):
+            return
+        self._held[role] = _Program(fn, abstract(tuple(args)))
+
+    def roles(self) -> List[str]:
+        return list(self._held)
+
+    def compiled(self, role: str):
+        """The executable registered under ``role`` (JAX's cached one), or
+        None."""
+        held = self._held.get(role)
+        return (None if held is None
+                else held.fn.lower(*held.specs).compile())
+
+    def scope_map(self, role: str) -> Optional[dict]:
+        """:func:`hlo_scope_map` of the executable under ``role``, built
+        once, when first asked for."""
+        held = self._held.get(role)
+        if held is None:
+            return None
+        if held.map is None:
+            held.map = hlo_scope_map(self.compiled(role).as_text())
+        return held.map
+
+    def scope_maps(self) -> Dict[str, dict]:
+        """``{role: scope map}`` of every registered program."""
+        return {role: self.scope_map(role) for role in self.roles()}
+
+
+programs = Programs()
+
+
+def note(role: str, fn, args: Sequence) -> None:
+    programs.note(role, fn, args)
+
+
+def scope_map(role: str) -> Optional[dict]:
+    return programs.scope_map(role)
+
+
+_MODULE_RE = re.compile(r"^\s*HloModule\s+([\w.\-]+)")
+_INSTR_NAME_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*")
+_OPCODE_RE = re.compile(r"\s*([\w\-]+)\(")
+
+
+def _skip_type(rest: str) -> str:
+    """``rest`` after the result type it starts with (a tuple type nests
+    parentheses and holds spaces; any other type is one word)."""
+    if not rest.startswith("("):
+        return rest.split(" ", 1)[-1] if " " in rest else ""
+    depth = 0
+    for i, ch in enumerate(rest):
+        depth += {"(": 1, ")": -1}.get(ch, 0)
+        if depth == 0:
+            return rest[i + 1:]
+    return ""
+
+
+def hlo_opcode(instruction: str) -> Optional[str]:
+    """Opcode of one HLO instruction's text (``%x = f32[] add(...)`` ->
+    ``add``); None for a line that is not an instruction."""
+    m = _INSTR_NAME_RE.match(instruction)
+    if m is None:
+        return None
+    op = _OPCODE_RE.match(_skip_type(instruction[m.end():]))
+    return op.group(1) if op else None
+
+
+def _shared(paths: List[List[str]]) -> List[str]:
+    """The scopes every one of ``paths`` starts with."""
+    out: List[str] = []
+    for segs in zip(*paths):
+        if any(seg != segs[0] for seg in segs):
+            break
+        out.append(segs[0])
+    return out
+
+
+def hlo_scope_map(hlo_text: str) -> dict:
+    """``{"module": name, "ops": {instruction: (op_name, opcode,
+    op_class)}}`` over the instructions of every computation of a compiled
+    module (a ``while`` body's and a ``conditional`` branch's ops run as
+    events of their own); ``op_name`` is the metadata's, and a fusion's
+    class is :func:`classify_fusion`'s of the computation it calls. An
+    instruction whose metadata names no scope (or is missing: a
+    multi-output fusion's root is a tuple; a conditional the compiler
+    cloned) and that runs computations is charged to the scopes that all
+    of their instructions under a scope share (a routed sum's branches:
+    ``routed_experts``), or where they share none to the scopes of the
+    last of them, the nearest the root (an optimizer's update fused with
+    the gradient it reads and the non-finite guard's select: the
+    update's), given as ``<scopes>/<opcode>``. "" where there is none (the
+    compiler's own copies and their ``*-done``). ``inferred`` names each
+    instruction whose scopes came so, by the rule that gave them:
+    ``shared`` or ``last``."""
+    module = ""
+    rows: List[tuple] = []
+    comps: Dict[str, list] = {}         # name -> [opcodes, scope paths]
+    current: Optional[list] = None
+    for line in hlo_text.splitlines():
+        stripped = line.strip()
+        if not module:
+            m = _MODULE_RE.match(line)
+            if m:
+                module = m.group(1)
+                continue
+        if stripped.endswith("{") and ("->" in stripped
+                                       or stripped.startswith("ENTRY")):
+            name = stripped.split()[1] if stripped.startswith("ENTRY") \
+                else stripped.split()[0]
+            current = comps.setdefault(name.lstrip("%").split("(")[0],
+                                       [[], []])
+            continue
+        if stripped == "}":
+            current = None
+            continue
+        opcode = hlo_opcode(line) if current is not None else None
+        if opcode is None:
+            continue
+        name = _INSTR_NAME_RE.match(line).group(1)
+        op_name = _OPNAME_RE.search(line)
+        op_name = op_name.group(1) if op_name else ""
+        called = [n for one, many in _CALLED_RE.findall(line)
+                  for n in ([one] if one else many.replace("%", "")
+                            .split(", "))]
+        current[0].append(opcode)
+        if scope_path(op_name):
+            current[1].append(scope_path(op_name))
+        rows.append((name, op_name, opcode, called))
+
+    ops: Dict[str, tuple] = {}
+    inferred: Dict[str, str] = {}
+    for name, op_name, opcode, called in rows:
+        if not scope_path(op_name):
+            paths = [p for c in called for p in comps.get(c, ([], []))[1]]
+            shared = _shared(paths)
+            scopes = shared or (paths[-1] if paths else [])
+            if scopes:
+                op_name = "/".join(scopes + [opcode])
+                inferred[name] = "shared" if shared else "last"
+        ops[name] = (op_name, opcode,
+                     classify_fusion(comps[called[0]][0])
+                     if opcode == "fusion" and called and called[0] in comps
+                     else classify_op(opcode))
+    return {"module": module, "ops": ops, "inferred": inferred}
+
+
+# -- device time by scope, from the profiler's trace --------------------------
+# What a TPU trace holds (benchmark/trace_reduce.py reads the same file):
+# a plane ``/device:TPU:<n>`` per chip with a line ``XLA Modules`` (an
+# event per execution, ``jit_<fn>(<fingerprint>)``) and a line ``XLA Ops``
+# (an event per op, named by its HLO text); a ``while`` or ``conditional``
+# op's event encloses the events of the ops its body or branch runs. Host
+# threads are lines of ``/host:`` planes, where every
+# ``jax.profiler.TraceAnnotation`` appears on the same clock.
+_DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
+_DISPATCH = re.compile(r"^PjitFunction\((\w+)\)$")
+ANNOTATION_PREFIX = "tpuic."
+# the compiler's own data movement: with no metadata it stays (unscoped)
+_COMPILER_MOVES = frozenset({"copy", "copy-start", "copy-done",
+                             "async-start", "async-done"})
+
+
+def find_xplane(path: str) -> Optional[str]:
+    """``path`` if it is a file, else the newest ``.xplane.pb`` under it
+    (the profiler writes ``plugins/profile/<time>/<host>.xplane.pb``)."""
     if os.path.isfile(path):
-        return [path]
-    pats = (os.path.join(path, "plugins", "profile", "*", "*.trace.json*"),
-            os.path.join(path, "*", "*.trace.json*"),
-            os.path.join(path, "*.trace.json*"))
-    for pat in pats:
-        hits = sorted(glob.glob(pat))
-        if hits:
-            return hits
-    return []
+        return path
+    found = glob.glob(os.path.join(path, "**", "*.xplane.pb"),
+                      recursive=True)
+    return max(found, key=os.path.getmtime) if found else None
 
 
-def _load_trace_events(path: str) -> List[dict]:
-    opener = gzip.open if path.endswith(".gz") else open
-    with opener(path, "rt") as f:
-        data = json.load(f)
-    if isinstance(data, dict):
-        return list(data.get("traceEvents") or ())
-    return list(data) if isinstance(data, list) else []
+def read_xplane(path: str) -> dict:
+    """``{"devices": {index: {"modules", "ops"}}, "annotations",
+    "dispatches"}``, every event ``(name, start_s, duration_s)``:
+    annotations are the host's ``tpuic.*`` ones, dispatches JAX's own
+    ``PjitFunction(<fn>)`` calls, named by the module they run
+    (``jit_<fn>``)."""
+    from jax.profiler import ProfileData
+    data = ProfileData.from_file(path)
+    devices: Dict[int, dict] = {}
+    annotations: list = []
+    dispatches: set = set()
+    for plane in data.planes:
+        m = _DEVICE_PLANE.match(plane.name)
+        if m:
+            lines = {ln.name: ln for ln in plane.lines}
+            devices[int(m.group(1))] = {
+                key: [(e.name, e.start_ns * 1e-9, e.duration_ns * 1e-9)
+                      for e in lines[line].events] if line in lines else []
+                for key, line in (("modules", "XLA Modules"),
+                                  ("ops", "XLA Ops"))}
+        elif plane.name.startswith("/host:"):
+            for ln in plane.lines:
+                for e in ln.events:
+                    at = (e.start_ns * 1e-9, e.duration_ns * 1e-9)
+                    sent = _DISPATCH.match(e.name)
+                    if e.name.startswith(ANNOTATION_PREFIX):
+                        annotations.append((e.name,) + at)
+                    elif sent:      # one event on two lines: a set
+                        dispatches.add(("jit_" + sent.group(1),) + at)
+    return {"devices": devices, "annotations": annotations,
+            "dispatches": sorted(dispatches, key=lambda e: e[1])}
 
 
-def parse_trace(path: str, layer_depth: int = 3) -> Optional[dict]:
-    """Per-op-class device time from a jax.profiler capture.
+def _window(modules: Sequence[tuple], ops: Sequence[tuple],
+            skip_first: int) -> Tuple[float, float, int]:
+    """``(lo, hi, steps)``: from the start of the dominant program's
+    execution after the first ``skip_first`` to the start of its last
+    (``benchmark/trace_reduce.py::reduce_device``'s window)."""
+    per_module: Dict[str, float] = {}
+    for name, _, dur in modules:
+        per_module[name] = per_module.get(name, 0.0) + dur
+    dominant = max(per_module, key=per_module.get) if per_module else None
+    starts = sorted(s for name, s, _ in modules if name == dominant)
+    if len(starts) - skip_first >= 2:
+        starts = starts[skip_first:]
+    if len(starts) >= 2:
+        return starts[0], starts[-1], len(starts) - 1
+    return (min(s for _, s, _ in ops), max(s + d for _, s, d in ops),
+            len(starts))
 
-    Selects processes whose ``process_name`` names a device (contains
-    ``/device:`` — the TPU/GPU op-timeline convention; the ``/host:CPU``
-    python/runtime timelines are never device time) and sums complete
-    ('X') event durations per op class and per layer.  Returns None when
-    the capture carries **no device op events at all** — a CPU capture —
-    so callers fall back to the HLO cost model instead of reading an
-    empty waterfall as "zero device time"."""
-    files = _trace_files(path)
-    if not files:
+
+def _innermost(intervals: Sequence[tuple]):
+    """Yield ``(key, start, end)`` pieces of ``(start, end, key)``
+    intervals, each instant of their union once, charged to the innermost
+    interval covering it: the last to start of those still open (an
+    interval that lies inside another is its child)."""
+    stack: List[tuple] = []                 # (end, key), innermost last
+    at = float("-inf")
+    for start, end, key in sorted(intervals, key=lambda iv: (iv[0], -iv[1])):
+        while stack:
+            top_end, top_key = stack[-1]
+            upto = min(top_end, start)
+            if upto > at:
+                yield top_key, at, upto
+                at = upto
+            if top_end > start:
+                break
+            stack.pop()
+        at = max(at, start)
+        stack.append((end, key))
+    while stack:
+        top_end, top_key = stack.pop()
+        if top_end > at:
+            yield top_key, at, top_end
+            at = top_end
+
+
+def _label(t: float, annotations: Sequence[tuple]) -> str:
+    """The innermost (shortest) annotation covering time ``t``."""
+    best = None
+    for name, s, d in annotations:
+        if s <= t < s + d and (best is None or d < best[1]):
+            best = (name, d)
+    return best[0] if best else UNLABELLED
+
+
+def attribute_device(modules: Sequence[tuple], ops: Sequence[tuple],
+                     annotations: Sequence[tuple] = (),
+                     maps: Optional[Dict[str, dict]] = None,
+                     skip_first: int = 2,
+                     dispatches: Sequence[tuple] = ()) -> Optional[dict]:
+    """Device time a step of one device, by program, scope and op class.
+
+    Events are ``(name, start_s, duration_s)``; ``maps`` are
+    :func:`hlo_scope_map`'s by role. Each instant the device is busy in the
+    window is charged once, to the innermost op covering it (a ``while`` or
+    ``conditional`` keeps its self time), and that op is keyed by the
+    program whose execution encloses it (its role where a map names the
+    module), its scope path from the map (:func:`scope_path`; an op with no
+    metadata or no map is ``(unscoped)``, but for one that a compiler
+    pass made inside a ``while`` or ``conditional``, which takes that op's
+    scope; the compiler's copies stay) and its class. ``partition``
+    (program, then scope path) sums to the busy time, and so does
+    ``programs``; ``scopes`` gives each op's time to every scope of its
+    path, so a scope holds its children; ``inferred`` holds the part of
+    each scope charged to ops whose own metadata named none, by the rule
+    that gave them their scopes (``shared`` and ``last``,
+    :func:`hlo_scope_map`'s; ``encloser``, the op around it), so that a
+    change of attribution can be told from a change of time. An idle gap
+    whose midpoint lies inside an execution is ``inside <program>``; one
+    before an execution that the host had dispatched (``dispatches``:
+    ``(module, start_s, duration_s)``) by the time the gap began is
+    ``queued <program>``; any other is labelled by the innermost
+    ``tpuic.*`` host annotation over its midpoint, ``(unlabelled)`` where
+    there is none. ``unmapped`` names, by program, the ops its map lacks.
+    None where no op ran."""
+    if not ops:
         return None
+    lo, hi, steps = _window(modules, ops, skip_first)
+    by_module = {m["module"]: (role, m["ops"], m.get("inferred", {}))
+                 for role, m in (maps or {}).items() if m}
+    runs = sorted((s, s + d, name.split("(", 1)[0]) for name, s, d in modules)
+    run_starts = [s for s, _, _ in runs]
+    keyed: Dict[tuple, tuple] = {}          # (program, op) -> keys
+    unmapped: Dict[str, List[str]] = {}
+
+    def keys(name: str, start: float, parent: Optional[tuple]) -> tuple:
+        i = bisect.bisect_right(run_starts, start) - 1
+        module = runs[i][2] if i >= 0 and start < runs[i][1] else "(none)"
+        role, table, inferred = by_module.get(module, (module, None, {}))
+        op = name.split(" = ", 1)[0].strip().lstrip("%")
+        if (role, op) not in keyed:
+            row = table.get(op) if table is not None else None
+            how = None
+            if row is None:
+                unmapped.setdefault(role, []).append(op)
+                path = []
+                opcode = hlo_opcode(name) or op
+                cls = classify_op(opcode)
+            else:
+                path, cls = scope_path(row[0]), row[2]
+                how = inferred.get(op)
+                if (not path and row[1] not in _COMPILER_MOVES
+                        and parent is not None and parent[0] == role):
+                    # an op a compiler pass made without metadata (a
+                    # ragged dot's custom call, a broadcast) inside a
+                    # while or a conditional: the scope of that op
+                    path, how = list(parent[3]), "encloser"
+            keyed[(role, op)] = (role, op, "/".join(path) or UNSCOPED,
+                                 tuple(path), cls, how if path else None)
+        return keyed[(role, op)]
+
+    intervals = []
+    open_: List[tuple] = []                 # (end, key) of enclosing ops
+    for n, s, d in sorted(ops, key=lambda e: (e[1], -e[2])):
+        if d <= 0:
+            continue
+        while open_ and open_[-1][0] <= s:
+            open_.pop()
+        key = keys(n, s, open_[-1][1] if open_ else None)
+        intervals.append((s, s + d, key))
+        open_.append((s + d, key))
+    per_op: Dict[tuple, float] = {}
+    busy = []
+    for key, s, e in _innermost(intervals):
+        s, e = max(s, lo), min(e, hi)
+        if e > s:
+            per_op[key] = per_op.get(key, 0.0) + (e - s)
+            busy.append((s, e))
+    ms = 1e3 / max(steps, 1)
+    programs_ms: Dict[str, float] = {}
+    partition: Dict[str, Dict[str, float]] = {}
+    scopes: Dict[str, float] = {}
     classes: Dict[str, float] = {}
-    layers: Dict[str, float] = {}
-    n_ops = 0
-    for f in files:
-        try:
-            events = _load_trace_events(f)
-        except (OSError, ValueError):
-            continue
-        device_pids = set()
-        for e in events:
-            if (e.get("ph") == "M" and e.get("name") == "process_name"
-                    and "/device:" in str(
-                        (e.get("args") or {}).get("name", ""))):
-                device_pids.add(e.get("pid"))
-        if not device_pids:
-            continue
-        for e in events:
-            if e.get("ph") != "X" or e.get("pid") not in device_pids:
-                continue
-            dur_us = float(e.get("dur", 0.0))
-            if dur_us <= 0:
-                continue
-            args = e.get("args") or {}
-            cls = classify_op(str(e.get("name", "")),
-                              category=args.get("hlo_category"))
-            classes[cls] = classes.get(cls, 0.0) + dur_us / 1000.0
-            n_ops += 1
-            scope = next((str(v) for k in ("long_name", "tf_op", "op_name",
-                                           "name")
-                          if "/" in str(args.get(k, ""))
-                          for v in (args[k],)), None)
-            if scope:
-                key = layer_of(scope, depth=layer_depth)
-                layers[key] = layers.get(key, 0.0) + dur_us / 1000.0
-    if not classes:
+    inferred_ms: Dict[str, Dict[str, float]] = {}
+    for (role, _, path, segs, cls, how), sec in per_op.items():
+        programs_ms[role] = programs_ms.get(role, 0.0) + sec * ms
+        part = partition.setdefault(role, {})
+        part[path] = part.get(path, 0.0) + sec * ms
+        for seg in segs:
+            scopes[seg] = scopes.get(seg, 0.0) + sec * ms
+            if how:
+                rule = inferred_ms.setdefault(how, {})
+                rule[seg] = rule.get(seg, 0.0) + sec * ms
+        classes[cls] = classes.get(cls, 0.0) + sec * ms
+    busy_s = sum(e - s for s, e in busy)
+
+    # each execution's dispatch by the host, matched from the last back:
+    # a capture ends after what was dispatched has run, and may begin
+    # after the first executions in it were dispatched
+    queued: Dict[int, float] = {}
+    for module in {m for _, _, m in runs}:
+        mine = [i for i, r in enumerate(runs) if r[2] == module]
+        sent = [s + d for name, s, d in dispatches if name == module]
+        queued.update(zip(reversed(mine), reversed(sent)))
+
+    def role(module: str) -> str:
+        return by_module.get(module, (module,))[0]
+
+    def label(start: float, end: float) -> str:
+        # inside an execution the program itself waited (a launch between
+        # two of its ops, a transfer); before one the host had already
+        # dispatched, the device was launching it: no host phase caused
+        # either
+        t = (start + end) / 2.0
+        i = bisect.bisect_right(run_starts, t) - 1
+        if i >= 0 and t < runs[i][1]:
+            return "inside " + role(runs[i][2])
+        j = bisect.bisect_right(run_starts, end) - 1
+        if j > i and queued.get(j, end) <= start:
+            return "queued " + role(runs[j][2])
+        return _label(t, annotations)
+
+    idle: Dict[str, float] = {}
+    gaps: List[list] = []
+    edge = lo
+    for s, e in sorted(busy) + [(hi, hi)]:
+        if s > edge:
+            name = label(edge, s)
+            idle[name] = idle.get(name, 0.0) + (s - edge) * ms
+            gaps.append([1e3 * (edge - lo), 1e3 * (s - edge), name])
+        edge = max(edge, e)
+    desc = lambda d: dict(sorted(d.items(), key=lambda kv: -kv[1]))
+    return {
+        "source": "xplane", "steps": steps,
+        "window_s": hi - lo, "busy_s": busy_s,
+        "device_ms_per_step": busy_s * ms,
+        "programs": desc(programs_ms),
+        "partition": {k: desc(v) for k, v in partition.items()},
+        "scopes": desc(scopes), "classes": desc(classes),
+        "inferred": {how: desc(v) for how, v in inferred_ms.items()},
+        "idle": desc(idle),
+        "unmapped": {role: sorted(ops) for role, ops in unmapped.items()},
+        # the longest gaps: ms from the window's start, ms long, label
+        "gaps": sorted(gaps, key=lambda g: -g[1])[:16],
+        "ops": [[role, op, path, sec * ms] for (role, op, path, *_), sec
+                in sorted(per_op.items(), key=lambda kv: -kv[1])],
+    }
+
+
+def parse_trace(path: str, maps: Optional[Dict[str, dict]] = None,
+                device: Optional[int] = None,
+                skip_first: int = 2) -> Optional[dict]:
+    """:func:`attribute_device` of one device of the ``.xplane.pb`` at
+    ``path`` (or the newest under it): ``device``, else the
+    lowest-numbered device that ran an op. ``maps`` default to those of
+    the programs registered in this process (:data:`programs`). None where
+    the capture has no device plane with ops (every CPU capture) or no
+    trace at all."""
+    found = find_xplane(path) if path else None
+    if found is None:
         return None
-    total = sum(classes.values())
-    return {"source": "trace", "device_ms_total": round(total, 3),
-            "ops": n_ops,
-            "classes": {k: round(v, 3) for k, v in sorted(classes.items())},
-            "layers": {k: round(v, 3) for k, v in sorted(
-                layers.items(), key=lambda kv: -kv[1])}}
+    raw = read_xplane(found)
+    ran = sorted(i for i, d in raw["devices"].items() if d["ops"])
+    if device is None and ran:
+        device = ran[0]
+    if device not in ran:
+        return None
+    dev = raw["devices"][device]
+    out = attribute_device(dev["modules"], dev["ops"], raw["annotations"],
+                           programs.scope_maps() if maps is None else maps,
+                           skip_first=skip_first,
+                           dispatches=raw["dispatches"])
+    out["device"] = device
+    return out
 
 
 # -- HLO text cost model ------------------------------------------------------
@@ -301,6 +726,11 @@ _INSTR_RE = re.compile(
     r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(\([^=]*?\)|[\w\[\]{},]+)\s+"
     r"([\w\-]+)\(")
 _CALLS_RE = re.compile(r"calls=%?([\w.\-]+)")
+# every computation an instruction runs: a fusion's, an async op's, a
+# while's body and condition, a conditional's branches
+_CALLED_RE = re.compile(r"(?:calls|body|condition|true_computation|"
+                        r"false_computation)=%?([\w.\-]+)|"
+                        r"branch_computations=\{([^}]*)\}")
 _OPNAME_RE = re.compile(r'op_name="([^"]+)"')
 
 
@@ -504,6 +934,19 @@ def waterfall_summary(wf: dict) -> str:
 
 
 # -- the capture analyzer (bus wiring) ----------------------------------------
+def _registered_step() -> Tuple[str, dict]:
+    """(optimized HLO text, cost_analysis dict) of the registered step."""
+    from tpuic.telemetry.goodput import cost_analysis_dict
+    compiled = programs.compiled("step")
+    if compiled is None:
+        raise LookupError("no step program registered yet")
+    try:
+        cost = cost_analysis_dict(compiled)
+    except Exception:
+        cost = {}
+    return compiled.as_text(), cost
+
+
 class CaptureAnalyzer:
     """Runs the analyzer on every triggered-trace capture and once at
     run end, publishing ``profile`` events.
@@ -511,12 +954,16 @@ class CaptureAnalyzer:
     Subscribes to ``step`` events (host-side floats only — the zero-
     syncs/zero-compiles discipline is test-asserted on-vs-off);
     ``on_capture`` is handed to :class:`tpuic.telemetry.tracing.
-    TraceTrigger`, ``finalize()`` runs from TrainTelemetry.flush().  The
-    HLO provider (Trainer wires the real train step's AOT lowering) is
-    called lazily ONCE and cached — compiling for analysis is off the
-    hot path by construction, and on CPU it is a persistent-cache hit.
-    Every failure publishes a ``profile`` event with an ``error`` field
-    and stands down: observability must never kill the run."""
+    TraceTrigger`, ``finalize()`` runs from TrainTelemetry.flush().  A
+    capture with a device plane is published as measured
+    (:func:`parse_trace` with the registered programs' scope maps); one
+    without (every CPU capture, and the run-end analysis) falls back to
+    the HLO cost model of the step the Trainer registered
+    (:data:`programs`, role ``"step"``; ``hlo_provider`` stands in for it),
+    called lazily ONCE and cached — JAX's own executable, nothing
+    compiles.  Every failure publishes a ``profile`` event with an
+    ``error`` field and stands down: observability must never kill the
+    run."""
 
     def __init__(self, *, hlo_provider: Optional[Callable] = None,
                  peak: float = 1e12, hbm_bytes_per_s: float = 50e9,
@@ -590,11 +1037,8 @@ class CaptureAnalyzer:
     def _model(self) -> Optional[dict]:
         if self._model_wf is not None or self._model_err is not None:
             return self._model_wf
-        if self.hlo_provider is None:
-            self._model_err = "no HLO provider wired"
-            return None
         try:
-            hlo_text, cost = self.hlo_provider()
+            hlo_text, cost = (self.hlo_provider or _registered_step)()
             flops = float(cost.get("flops", 0.0)) if cost else 0.0
             self._model_wf = hlo_waterfall(
                 hlo_text, total_flops=flops, peak=self.peak,
@@ -623,17 +1067,20 @@ class CaptureAnalyzer:
     def _analyze(self, trace_path: Optional[str], final: bool) -> None:
         try:
             wf = None
-            trace_wf = (parse_trace(trace_path, layer_depth=self.layer_depth)
-                        if trace_path else None)
+            trace_wf = parse_trace(trace_path) if trace_path else None
             model = self._model()
             if trace_wf is not None:
-                # Real per-op device timings: the measured waterfall,
-                # enriched with the model's verdicts where classes match.
-                wf = {**trace_wf, "final": final}
+                # Real per-op device timings: the measured attribution
+                # (bounded: the largest scopes and ops), enriched with the
+                # model's verdicts where classes match.
+                total = trace_wf["device_ms_per_step"]
+                wf = {**trace_wf, "final": final,
+                      "scopes": dict(list(trace_wf["scopes"].items())[:48]),
+                      "partition": {k: dict(list(v.items())[:24])
+                                    for k, v in trace_wf["partition"].items()},
+                      "ops": trace_wf["ops"][:24]}
                 wf["classes"] = {
-                    k: {"ms": v,
-                        "frac": round(v / trace_wf["device_ms_total"], 4)
-                        if trace_wf["device_ms_total"] else 0.0,
+                    k: {"ms": v, "frac": round(v / total, 4) if total else 0.0,
                         **({f: model["classes"][k][f]
                             for f in ("verdict", "intensity", "flops",
                                       "bytes")}
@@ -814,7 +1261,7 @@ def main(argv=None) -> int:
                 f.write(text + "\n")
 
     if args.trace:
-        wf = parse_trace(args.trace, layer_depth=args.layer_depth)
+        wf = parse_trace(args.trace)
         if wf is None:
             print(f"[profile] no device op events in {args.trace} "
                   "(CPU captures carry none; use --step-waterfall for "

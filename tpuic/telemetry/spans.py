@@ -20,6 +20,7 @@ arrives first) are kept for the life of the process, the rest in a ring.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import sys
 import threading
@@ -94,6 +95,15 @@ def record(name: str, t0: float, t1: float, *, span_id: Optional[int] = None,
     return rec
 
 
+def annotation(name: str):
+    """``jax.profiler.TraceAnnotation("tpuic." + name)``: the interval on
+    the profiler's clock alone, nothing in the ledger (the step loop's
+    phases, ``tpuic.step.*``); a null context while jax is not imported."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    return (profiler.TraceAnnotation("tpuic." + name)
+            if profiler is not None else contextlib.nullcontext())
+
+
 class span:
     """``with span("trainer.data", images=n) as sp:`` — ``sp.attrs`` may
     gain keys until the block ends."""
@@ -103,20 +113,15 @@ class span:
 
     def __enter__(self) -> "span":
         self.id = next(_ids)
-        jax = sys.modules.get("jax")
-        profiler = getattr(jax, "profiler", None)   # None while jax imports
-        self._annotation = (profiler.TraceAnnotation("tpuic." + self.name)
-                            if profiler is not None else None)
-        if self._annotation is not None:
-            self._annotation.__enter__()
+        self._annotation = annotation(self.name)
+        self._annotation.__enter__()
         self.t0 = time.perf_counter()
         _open.stack.append(self.id)
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = time.perf_counter()
-        if self._annotation is not None:
-            self._annotation.__exit__(*exc)
+        self._annotation.__exit__(*exc)
         _open.stack.pop()
         record(self.name, self.t0, t1, span_id=self.id, **self.attrs)
 

@@ -24,6 +24,15 @@ No new host syncs, no new compiles: everything here is
 by counting ``jax.device_get`` calls and the jit cache size with
 telemetry on vs. off).
 
+The same intervals sit on the profiler's clock: the loader's ``__next__``
+enters ``jax.profiler.TraceAnnotation("tpuic.step.next")``, the step call
+``tpuic.step.dispatch``, the ``step`` event's publication (what its
+subscribers do) ``tpuic.step.end`` (and the loop's deferred log drain
+``tpuic.step.drain``), so a device trace names what the host was doing in
+each idle gap between the device's executions. Annotations only, nothing
+in the span ledger; with no profiler session each is one TraceMe
+construction.
+
 Every completed step publishes one ``step`` event:
 ``{step, total_ms, data_ms, dispatch_ms, device_ms}``.  Percentile
 summaries ride the shared ``tpuic.metrics.LatencyMeter`` — the same
@@ -37,6 +46,7 @@ import time
 from typing import Iterable, Iterator, Optional
 
 from tpuic.metrics.meters import LatencyMeter
+from tpuic.telemetry.spans import annotation
 
 
 class StepTimer:
@@ -91,7 +101,8 @@ class StepTimer:
         while True:
             t0 = time.perf_counter()
             try:
-                item = next(it)
+                with annotation("step.next"):
+                    item = next(it)
             except StopIteration:
                 return
             t1 = time.perf_counter()
@@ -101,11 +112,14 @@ class StepTimer:
             yield item
 
     def dispatch_start(self) -> None:
+        self._dispatching = annotation("step.dispatch")
+        self._dispatching.__enter__()
         self._t_dispatch = time.perf_counter()
 
     def dispatch_end(self) -> None:
         if self._t_dispatch is not None:
             now = time.perf_counter()
+            self._dispatching.__exit__(None, None, None)
             self._dispatch_s += now - self._t_dispatch
             if self.first_dispatch is None:
                 self.first_dispatch = (self._t_dispatch, now)
@@ -134,7 +148,8 @@ class StepTimer:
                "data_ms": round(1000.0 * data, 3),
                "dispatch_ms": round(1000.0 * disp, 3),
                "device_ms": round(1000.0 * device, 3)}
-        self.bus.publish("step", **out)
+        with annotation("step.end"):
+            self.bus.publish("step", **out)
         return out
 
     # -- reads ---------------------------------------------------------

@@ -36,7 +36,8 @@ from tqdm import tqdm
 
 from tpuic.runtime import faults as _faults
 from tpuic.telemetry.events import publish as _tm_publish
-from tpuic.telemetry.spans import record as _record_span, span as _span
+from tpuic.telemetry.spans import (annotation as _annotation,
+                                   record as _record_span, span as _span)
 
 from tpuic.checkpoint.manager import CheckpointManager
 from tpuic.config import Config
@@ -45,8 +46,8 @@ from tpuic.data.pipeline import Loader
 from tpuic.metrics.logging import MetricLogger, host0_print, is_host0
 from tpuic.metrics.meters import AverageMeter
 from tpuic.models import create_model_from_config
-from tpuic.models.layers import ROTARY_TRACED
 from tpuic.runtime.mesh import make_mesh, replicated_sharding
+from tpuic.telemetry.profile import note as _note_program
 from tpuic.train.optimizer import make_optimizer, make_schedule
 from tpuic.train.state import create_train_state
 from tpuic.train.step import STEP_METRICS, make_eval_step, make_train_step
@@ -93,7 +94,6 @@ class Trainer:
                 self.val_ds = pack_dataset(self.val_ds, cache, verbose=is_host0())
             global_batch = self._build_loaders()
             sp.attrs["images"] = len(self.train_ds)
-        rotary_before = ROTARY_TRACED.copy()
         with _span("trainer.state_init") as sp:
             num_classes = cfg.model.num_classes or self.train_ds.num_classes
             mcfg = cfg.model
@@ -176,13 +176,8 @@ class Trainer:
                 # carry different input shardings and compile the step twice.
                 self.state = jax.device_put(self.state,
                                             replicated_sharding(self.mesh))
-        with _span("trainer.build_steps") as sp:
+        with _span("trainer.build_steps"):
             self._build_steps()
-            # What the model's init traced, by rotary path (no device op)
-            rotary = ROTARY_TRACED - rotary_before
-            if rotary:
-                sp.attrs["rotary_one_pass_share"] = (
-                    rotary["one_pass"] / sum(rotary.values()))
         self.last_misclassified: list = []
         self.last_counters: dict = {}   # the family's own; see the drain
         with _span("trainer.checkpoint"):
@@ -249,12 +244,6 @@ class Trainer:
             device=jax.devices()[0], tb=self.logger.tb,
             compute_dtype=(compute_dtype or (
                 "bf16" if mcfg.dtype == "bfloat16" else "f32")))
-        if self.telemetry.profile is not None:
-            # Device-time attribution (telemetry/profile.py): hand the
-            # analyzer the REAL train step's AOT view. Called lazily
-            # (once, cached) from the capture/finalize hooks — never on
-            # the hot path.
-            self.telemetry.profile.hlo_provider = self._train_step_hlo
         # Non-finite rollback bookkeeping (docs/robustness.md): the jitted
         # step skips poisoned updates in-graph (train/step.py guard) and
         # counts the consecutive-skip streak in state.skip_count; the
@@ -266,32 +255,6 @@ class Trainer:
         self._quarantine_seen = 0
         self._last_skip_streak = 0
         self._steps_exhausted = False
-
-    def _train_step_hlo(self):
-        """(optimized HLO text, cost_analysis dict) of THE train step —
-        the device-time analyzer's model source (docs/observability.md,
-        "Device-time attribution").
-
-        Lowered against the batch geometry this run trains with (image
-        as float32, the decode-path contract; the packed uint8 path
-        differs only in the cast/augment prologue, which class-level
-        attribution absorbs into elementwise).  The compile is off the
-        hot path by construction — analysis hooks only — and hits the
-        persistent compilation cache when one is configured."""
-        from tpuic.telemetry.goodput import cost_analysis_dict
-        d = self.cfg.data
-        gb = self.train_loader.global_batch
-        sds = jax.ShapeDtypeStruct
-        batch = {"image": sds((gb, d.resize_size, d.resize_size, 3),
-                              np.float32),
-                 "label": sds((gb,), np.int32),
-                 "mask": sds((gb,), np.float32)}
-        compiled = self.train_step.lower(self.state, batch).compile()
-        try:
-            cost = cost_analysis_dict(compiled)
-        except Exception:
-            cost = {}
-        return compiled.as_text(), cost
 
     def _init_from_torch(self, path: str) -> None:
         """Pretrained-weight initialization from a torch checkpoint.
@@ -743,6 +706,13 @@ class Trainer:
                 if int(self.telemetry.rank) == int(target or 0):
                     while True:
                         time.sleep(0.5)
+            # The programs of a step, for a trace reader to map the ops
+            # they ran to scopes after the fact (telemetry/profile.py):
+            # once registered, a dict lookup each.
+            _note_program("step", self.train_step, (self.state, fbatch))
+            _note_program("input_prep",
+                          getattr(self.train_loader, "prep_fn", None),
+                          getattr(self.train_loader, "prep_specs", None))
             steptime.dispatch_start()
             self.state, metrics = self.train_step(self.state, fbatch)
             steptime.dispatch_end()
@@ -844,45 +814,48 @@ class Trainer:
         non-finite streak; past run.skip_threshold it flags a rollback
         (detection latency <= ~2 log intervals — the price of keeping the
         hot path free of per-step host syncs)."""
-        step_num, imgs_per_sec, handles = pending
-        vals = jax.device_get(handles)
-        loss = float(vals["loss"])
-        losses.update(loss, 1)
-        bar.set_description(
-            f"Epoch: {epoch}; Loss {losses.val:.4f}|({losses.avg:.4f})")
-        extra = {}
-        streak = int(vals.get("skip_count", 0))
-        if streak:
-            extra["skipped_streak"] = streak
-            # 'skip' event (docs/observability.md): the streak at this
-            # drain plus the delta since the last one — the goodput
-            # tracker charges that many steps to the skip bucket. At
-            # log_every_steps=1 the delta is exact; at coarser cadences
-            # it undercounts streaks that reset inside an interval
-            # (documented estimate, same latency as rollback detection).
-            last = getattr(self, "_last_skip_streak", 0)
-            delta = streak - last if streak > last else streak
-            _tm_publish("skip", step=step_num, streak=streak, delta=delta)
-        self._last_skip_streak = streak
-        counters = {k: float(v) for k, v in vals.items()
-                    if k not in STEP_METRICS}
-        if counters:
-            # kept for the train_epoch span and the Prometheus rows
-            self.last_counters = counters
-            extra.update(counters)
-        self.logger.write(step_num, loss=loss,
-                          accuracy=float(vals["accuracy"]),
-                          lr=float(vals.get("lr", 0.0)),
-                          images_per_sec=round(imgs_per_sec, 1), **extra)
-        thr = self.cfg.run.skip_threshold
-        if (thr > 0 and streak >= thr and self.cfg.run.rollback
-                and not self._rollback_pending):
-            host0_print(
-                f"[rollback] {streak} consecutive non-finite steps "
-                f"(threshold {thr}) at step {step_num} — state is still "
-                f"finite (guard skipped the updates); restoring the last "
-                f"good checkpoint instead of grinding forward")
-            self._rollback_pending = True
+        # the whole drain on the profiler's clock: the blocking read and
+        # the log line it writes
+        with _annotation("step.drain"):
+            step_num, imgs_per_sec, handles = pending
+            vals = jax.device_get(handles)
+            loss = float(vals["loss"])
+            losses.update(loss, 1)
+            bar.set_description(
+                f"Epoch: {epoch}; Loss {losses.val:.4f}|({losses.avg:.4f})")
+            extra = {}
+            streak = int(vals.get("skip_count", 0))
+            if streak:
+                extra["skipped_streak"] = streak
+                # 'skip' event (docs/observability.md): the streak at this
+                # drain plus the delta since the last one — the goodput
+                # tracker charges that many steps to the skip bucket. At
+                # log_every_steps=1 the delta is exact; at coarser cadences
+                # it undercounts streaks that reset inside an interval
+                # (documented estimate, same latency as rollback detection).
+                last = getattr(self, "_last_skip_streak", 0)
+                delta = streak - last if streak > last else streak
+                _tm_publish("skip", step=step_num, streak=streak, delta=delta)
+            self._last_skip_streak = streak
+            counters = {k: float(v) for k, v in vals.items()
+                        if k not in STEP_METRICS}
+            if counters:
+                # kept for the train_epoch span and the Prometheus rows
+                self.last_counters = counters
+                extra.update(counters)
+            self.logger.write(step_num, loss=loss,
+                              accuracy=float(vals["accuracy"]),
+                              lr=float(vals.get("lr", 0.0)),
+                              images_per_sec=round(imgs_per_sec, 1), **extra)
+            thr = self.cfg.run.skip_threshold
+            if (thr > 0 and streak >= thr and self.cfg.run.rollback
+                    and not self._rollback_pending):
+                host0_print(
+                    f"[rollback] {streak} consecutive non-finite steps "
+                    f"(threshold {thr}) at step {step_num} — state is still "
+                    f"finite (guard skipped the updates); restoring the last "
+                    f"good checkpoint instead of grinding forward")
+                self._rollback_pending = True
 
     def val_epoch(self, epoch: int) -> float:
         """Reference val_epoch (train.py:78-97): exact global accuracy ×100,
