@@ -14,8 +14,10 @@ Shapes are the main path's published widths: ViT-B/16 attention at 224 px
 (batch 32, N=196, 32 heads of 128 + 64 / 128), the banded flash kernels at
 the long-sequence cell's (batch 4, N=4096, 32 heads over 4 of 128, a window
 of 1,024 and none), the 1000-class loss at batch 128, ResNet-50
-leaves and layers. The whole-step compile (~36 s) is not tier-1; see
-scripts/chip_compile_rehearsal.py.
+leaves and layers; and the routed sum at both routed cells' shapes, which
+has no kernel of its own but whose optimized program scatters no rows
+where its token-side sums are gathers. The whole-step compile (~36 s) is
+not tier-1; see scripts/chip_compile_rehearsal.py.
 """
 
 import os
@@ -111,6 +113,35 @@ def test_causal_attention(chip, heads, rope, packed, grad):
     # one kernel forward; a gradient holds it (the output and the rows'
     # log-sum-exp are the residuals) and ONE backward kernel
     assert _compile(fn, chip, *shapes) == (2 if grad else 1)
+
+
+# The routed sum forward and backward at the two routed cells' shapes (the
+# long-sequence cell's 16,384 tokens choosing 8 of 64 experts, 8 held, width
+# 2,304, through 32,768 rows; the routed cell's 6,272 tokens choosing 6 of
+# 128, 8 held, width 2,048, through 4,736): where its token-side sums are
+# gathers the optimized program scatters no rows, in either branch of its
+# cond, and where they are scatter-adds it does.
+@pytest.mark.parametrize("tokens,width,hidden,top_k,experts,gathers", [
+    (16384, 2304, 896, 8, 64, True), (6272, 2048, 768, 6, 128, False)],
+    ids=["mellum_12b", "kanana_30b"])
+def test_the_routed_sum_scatters_no_rows(chip, tokens, width, hidden, top_k,
+                                         experts, gathers):
+    import re
+    from tpuic.models import kanana
+
+    def loss(x, weights, gate_up, down, chosen):
+        y = kanana.routed_sum(x, chosen, weights, gate_up, down, 0,
+                              experts)[0]
+        return jnp.sum(y * y)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in (
+        ((tokens, width), BF16), ((tokens, top_k), F32),
+        ((8, width, 2 * hidden), BF16), ((8, hidden, width), BF16),
+        ((tokens, top_k), I32))]
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3))).lower(
+        *args).compile().as_text()
+    assert "ragged" in text
+    scatters = re.findall(r"= \w+\[\d+,\d+\]\S* scatter\(", text)
+    assert (scatters == []) == gathers
 
 
 # The banded flash kernels at the long-sequence cell's shapes (batch 4,

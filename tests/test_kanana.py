@@ -353,9 +353,9 @@ def test_the_bias_selects_and_does_not_weigh():
 # -- the routed sum ---------------------------------------------------------
 
 def _routed_case(tokens=512, top_k=3, held=8, num_experts=128, on_held=False,
-                 seed=0):
+                 seed=0, d=32):
     rng = np.random.default_rng(seed)
-    d, width = 32, 16
+    width = 16
     x = jnp.asarray(rng.standard_normal((tokens, d)).astype(np.float32))
     span = held if on_held else num_experts
     chosen = jnp.asarray(np.stack([rng.choice(span, top_k, replace=False)
@@ -412,26 +412,48 @@ def test_the_routed_sum_is_dropless(on_held):
 
 def test_rows_past_the_groups_are_masked_on_the_way_in_and_out():
     """On the CPU a grouped product writes zeros past its groups; on the
-    chip those rows hold whatever was there (finite and not zero: chip
-    probe, PR 33). So the mask is tested with the rows poisoned."""
+    chip those rows hold whatever was there (finite and not zero, on a
+    v5e). So the mask is tested with the rows poisoned, here through the
+    token-side sums as scatter-adds."""
+    _rows_past_the_groups_are_masked(gathers=False)
+
+
+def test_rows_past_the_groups_are_masked_through_the_gathers():
+    """The same, through the token-side sums as gathers of each token's
+    slots (``to_buffer`` / ``to_tokens``)."""
+    _rows_past_the_groups_are_masked(gathers=True)
+
+
+def _rows_past_the_groups_are_masked(gathers):
     group = jnp.asarray([8, 2, 8, 0, 8, 2, 8, 8], jnp.int32)
-    order, sizes = kanana.dispatch(group, 8)
+    order, rank, sizes = kanana.dispatch(group, 8, rank=gathers)
     pairs, valid = order[:6], jnp.arange(6) < jnp.sum(sizes)
     assert pairs[:3].tolist() == [3, 1, 5]
     assert sizes.tolist() == [1, 0, 2, 0, 0, 0, 0, 0]
+    assert (rank is not None) == gathers
+    if gathers:
+        assert rank[order].tolist() == list(range(8))
+        moves = kanana.buffer_moves(pairs, rank, jnp.sum(sizes), 2)
     out = jnp.ones((6, 4)).at[3:].set(jnp.nan).at[4].set(1e30)
-    weights = jnp.arange(1.0, 7.0)
+    weights = jnp.arange(1.0, 9.0).reshape(4, 2)
     token = pairs // 2
 
     def total(out, weights):
-        return kanana.combine(out, valid, weights, token, tokens=4)
+        if gathers:
+            return kanana.to_tokens(out, moves, weights)
+        return kanana.combine(out, valid, weights.reshape(-1)[pairs], token,
+                              tokens=4)
     y = total(out, weights)
     assert bool(jnp.all(jnp.isfinite(y)))
     np.testing.assert_array_equal(
-        y[:, 0], jnp.zeros(4).at[token[:3]].add(weights[:3]))
+        y[:, 0], jnp.zeros(4).at[token[:3]].add(
+            weights.reshape(-1)[pairs[:3]]))
     d_out, d_w = jax.grad(lambda o, w: jnp.sum(total(o, w)), argnums=(0, 1))(
         out, weights)
-    assert not np.any(d_out[3:]) and not np.any(d_w[3:])
+    # a row past the groups and the weight of a pair on an absent expert
+    # get nothing
+    assert not np.any(d_out[3:])
+    np.testing.assert_array_equal(d_w.reshape(-1) != 0, group < 8)
     assert bool(jnp.all(jnp.isfinite(d_out))) and bool(
         jnp.all(jnp.isfinite(d_w)))
     # on the way in: what the gather took for a row past the groups is
@@ -444,17 +466,197 @@ def test_rows_past_the_groups_are_masked_on_the_way_in_and_out():
     def spy(rows, gate_up, down, sizes):
         seen["rows"], seen["sizes"] = rows, sizes
         return jnp.full((rows.shape[0], 4), jnp.nan).at[:3].set(1.0)
+
+    def through(x):
+        if gathers:
+            return kanana._gathered_rows(x, pairs, rank, sizes,
+                                         jnp.ones((4, 2)), gate_up, down)
+        return kanana._routed_rows(x, order, sizes, jnp.ones((4, 2)),
+                                   gate_up, down, rows=6)
     real, kanana.expert_matmul = kanana.expert_matmul, spy
     try:
-        y, computed = kanana._routed_rows(
-            x, order, sizes, jnp.ones((4, 2)), gate_up, down, rows=6)
+        d_x = jax.grad(lambda x: jnp.sum(through(x)[0]))(x)
+        y, computed = through(x)
     finally:
         kanana.expert_matmul = real
     assert int(computed) == 3 and bool(jnp.all(jnp.isfinite(y)))
     assert not np.any(seen["rows"][3:]) and bool(
         jnp.all(seen["rows"][:3] > 0))
+    assert bool(jnp.all(jnp.isfinite(d_x)))
     # the grouped products are given the pairs' own groups and no more
     assert seen["sizes"].tolist() == sizes.tolist()
+
+
+def _moves_case(lo, tokens=64, top_k=3, held=4, rows=96, d=8):
+    """A buffer of ``rows`` of the sorted pairs from ``lo`` on, as the
+    routed sum's passes make one (``lo`` 0: its one buffer), with the rows
+    past the groups poisoned."""
+    rng = np.random.default_rng(lo)
+    group = jnp.asarray(rng.integers(0, 3 * held, tokens * top_k)
+                        .clip(max=held), jnp.int32)
+    order, rank, sizes = kanana.dispatch(group, held, rank=True)
+    count = int(jnp.clip(jnp.sum(sizes) - lo, 0, rows))
+    assert 0 < count < rows - 2
+    pairs = order[lo:lo + rows]
+    moves = kanana.buffer_moves(pairs, rank - lo, count, top_k)
+    buf = jnp.asarray(rng.standard_normal((rows, d)), jnp.float32)
+    buf = buf.at[count:].set(jnp.nan).at[count + 1].set(1e30)
+    weights = jnp.asarray(rng.random((tokens, top_k)), jnp.float32)
+    return moves, pairs, count, buf, weights
+
+
+@pytest.mark.parametrize("weighed", [False, True], ids=["plain", "weighed"])
+@pytest.mark.parametrize("lo", [0, 40], ids=["first_rows", "a_later_pass"])
+def test_to_tokens_is_the_scatter_add_it_replaces(lo, weighed):
+    """``to_tokens`` (a gather of each token's slots, summed) against the
+    scatter-add of the buffer's rows in groups into their tokens that it
+    replaces: values and both cotangents, with the rows past the groups
+    poisoned (NaN, 1e30)."""
+    moves, pairs, count, buf, weights = _moves_case(lo)
+    tokens, top_k = weights.shape
+
+    def got(buf, weights):
+        return kanana.to_tokens(buf, moves, weights if weighed else None)
+
+    def want(buf, weights):
+        rows = jnp.where((jnp.arange(buf.shape[0]) < count)[:, None], buf,
+                         0.0)
+        if weighed:
+            rows = rows * weights.reshape(-1)[pairs][:, None]
+        return jnp.zeros((tokens, buf.shape[1])).at[pairs // top_k].add(rows)
+    y, pull = jax.vjp(got, buf, weights)
+    y_want, pull_want = jax.vjp(want, buf, weights)
+    assert bool(jnp.all(jnp.isfinite(y)))
+    np.testing.assert_allclose(y, y_want, rtol=1e-6, atol=1e-6)
+    dy = jnp.asarray(np.random.default_rng(7).standard_normal(y.shape),
+                     jnp.float32)
+    for a, b in zip(pull(dy), pull_want(dy)):
+        assert bool(jnp.all(jnp.isfinite(a)))
+        np.testing.assert_allclose(a, b if weighed or a.shape != weights.shape
+                                   else jnp.zeros_like(b), rtol=1e-6,
+                                   atol=1e-6)
+
+
+def test_the_two_moves_are_each_others_transpose():
+    """The backward pass of ``to_buffer`` is ``to_tokens`` and that of
+    ``to_tokens`` is ``to_buffer``, and the pair are transposes of each
+    other as linear maps: <to_buffer(x), b> = <x, to_tokens(b)>. Each
+    cotangent comes back in its input's dtype."""
+    moves, _, _, buf, _ = _moves_case(40)
+    rng = np.random.default_rng(3)
+    buf = jnp.where(moves.valid[:, None], buf, 0.0)
+    x = jnp.asarray(rng.standard_normal((64, buf.shape[1])), jnp.float32)
+    dy = jnp.asarray(rng.standard_normal(x.shape), jnp.float32)
+    _, pull_in = jax.vjp(lambda x: kanana.to_buffer(x, moves), x)
+    _, pull_out = jax.vjp(lambda b: kanana.to_tokens(b, moves), buf)
+    np.testing.assert_array_equal(pull_in(buf)[0],
+                                  kanana.to_tokens(buf, moves))
+    np.testing.assert_array_equal(pull_out(dy)[0],
+                                  kanana.to_buffer(dy, moves))
+    np.testing.assert_allclose(
+        jnp.vdot(kanana.to_buffer(x, moves), buf),
+        jnp.vdot(x, kanana.to_tokens(buf, moves)), rtol=1e-5)
+    # summed in float32 and handed back in bfloat16, as x came
+    xb = x.astype(jnp.bfloat16)
+    _, pull_in = jax.vjp(lambda x: kanana.to_buffer(x, moves), xb)
+    assert pull_in(buf.astype(jnp.bfloat16))[0].dtype == jnp.bfloat16
+    _, pull_out = jax.vjp(lambda b: kanana.to_tokens(b, moves),
+                          buf.astype(jnp.bfloat16))
+    assert pull_out(dy)[0].dtype == jnp.bfloat16
+
+
+def test_a_large_source_is_gathered_by_blocks_of_columns(monkeypatch):
+    """Over ``GATHER_SOURCE_BYTES`` a source's rows are gathered by blocks
+    of whole lane tiles of columns, each under the limit, and come out as
+    the one gather would give them; the routed sum through such gathers
+    computes what it computes through one (values and gradients)."""
+    rng = np.random.default_rng(5)
+    source = jnp.asarray(rng.standard_normal((64, 384)), jnp.float32)
+    index = jnp.asarray(rng.integers(0, 64, (3, 50)), jnp.int32)
+    monkeypatch.setattr(kanana, "GATHER_SOURCE_BYTES", 40_000)
+    got = kanana.gather_rows(source, index)
+    np.testing.assert_array_equal(got, source[index])
+    gathers = [eqn for eqn in _equations(jax.make_jaxpr(
+        kanana.gather_rows)(source, index).jaxpr)
+        if eqn.primitive.name == "gather"]
+    assert [g.invars[0].aval.shape for g in gathers] == [(64, 128)] * 3
+    x, chosen, weights, gate_up, down, n = _routed_case(tokens=64, d=256)
+    moves = kanana.buffer_moves(jnp.arange(128), jnp.arange(192), 100, 3)
+    assert [g.invars[0].aval.shape for g in _equations(jax.make_jaxpr(
+        kanana.to_buffer)(x, moves).jaxpr) if g.primitive.name == "gather"] \
+        == [(64, 128)] * 2
+
+    def run():
+        def loss(x, weights, gate_up, down):
+            y = kanana.routed_sum(x, chosen, weights, gate_up, down, 0, n)[0]
+            return jnp.sum(y * y), y
+        return jax.value_and_grad(loss, (0, 1, 2, 3), has_aux=True)(
+            x, weights, gate_up, down)
+    (_, y), grads = run()
+    monkeypatch.setattr(kanana, "GATHER_SOURCE_BYTES", 96 * 2**20)
+    (_, want), want_grads = run()
+    np.testing.assert_allclose(y, want, rtol=1e-6, atol=1e-6)
+    for a, b in zip(grads, want_grads):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
+
+
+def test_the_token_side_sums_are_gathers_up_to_six_slots_a_buffer_row():
+    """Where each token's slots are at most ``SLOTS_OVER_BUFFER`` times the
+    buffer's rows the token-side sums are gathers (the long-sequence
+    cell's 16,384 tokens, top-8, a 32,768-row buffer: 4 times), past it
+    scatter-adds (the routed cell's 6,272 tokens, top-6, 4,736 rows: 7.9
+    times), each where it was the faster on the chip."""
+    assert kanana.SLOTS_OVER_BUFFER == 6
+    for tokens, top_k, experts, gathers in ((16384, 8, 64, True),
+                                            (6272, 6, 128, False)):
+        args = [jax.ShapeDtypeStruct(s, d) for s, d in (
+            ((tokens, 64), jnp.float32), ((tokens, top_k), jnp.float32),
+            ((8, 64, 32), jnp.float32), ((8, 16, 64), jnp.float32),
+            ((tokens, top_k), jnp.int32))]
+        eqns = list(_equations(jax.make_jaxpr(jax.grad(
+            lambda x, w, gate_up, down, chosen: jnp.sum(kanana.routed_sum(
+                x, chosen, w, gate_up, down, 0, experts)[0]),
+            argnums=(0, 1)))(*args).jaxpr))
+        scatters = [e for e in eqns if "scatter" in e.primitive.name
+                    and e.invars[0].aval.ndim == 2]
+        assert (not scatters) == gathers, (tokens, top_k, experts)
+
+
+@pytest.mark.parametrize("on_held", [False, True],
+                         ids=["even_load", "every_token_on_held_experts"])
+def test_the_two_forms_of_the_token_side_sums_agree(on_held, monkeypatch):
+    """The routed sum through gathers and through scatter-adds: the same
+    values and gradients (the weights' too), through either branch."""
+    x, chosen, weights, gate_up, down, n = _routed_case(on_held=on_held)
+
+    def run(slots_over_buffer):
+        monkeypatch.setattr(kanana, "SLOTS_OVER_BUFFER", slots_over_buffer)
+
+        def loss(x, weights, gate_up, down):
+            y = kanana.routed_sum(x, chosen, weights, gate_up, down, 0, n)[0]
+            return jnp.sum(y * y), y
+        return jax.value_and_grad(loss, (0, 1, 2, 3), has_aux=True)(
+            x, weights, gate_up, down)
+    (_, y), grads = run(6)
+    (_, want), want_grads = run(0)
+    np.testing.assert_allclose(y, want, rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(
+        y, _dense_routed(x, chosen, weights, gate_up, down), rtol=2e-4,
+        atol=2e-5)
+    for a, b in zip(grads, want_grads):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+
+def _equations(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it (branches,
+    loop bodies, remat, custom rules)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for item in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(item, "jaxpr", item)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
 
 
 def test_the_routed_sum_is_grouped_products_and_nothing_dense():
@@ -462,21 +664,37 @@ def test_the_routed_sum_is_grouped_products_and_nothing_dense():
     no ``dot_general`` (a pass over every held expert, a one-hot dispatch)
     anywhere in the routed sum, its branches included."""
     x, chosen, weights, gate_up, down, n = _routed_case()
-    names = []
-
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            names.append(eqn.primitive.name)
-            for v in eqn.params.values():
-                for item in (v if isinstance(v, (tuple, list)) else (v,)):
-                    inner = getattr(item, "jaxpr", item)
-                    if hasattr(inner, "eqns"):
-                        walk(inner)
-    walk(jax.make_jaxpr(lambda *a: kanana.routed_sum(*a, 0, n))(
-        x, chosen, weights, gate_up, down).jaxpr)
+    names = [eqn.primitive.name for eqn in _equations(jax.make_jaxpr(
+        lambda *a: kanana.routed_sum(*a, 0, n))(
+            x, chosen, weights, gate_up, down).jaxpr)]
     assert names.count("ragged_dot_general") == 4      # two a branch
     assert "cond" in names and "sort" in names
     assert "dot_general" not in names and "conv_general_dilated" not in names
+
+
+@pytest.mark.parametrize("worst_case", ["one_buffer", "passes"])
+def test_the_routed_sum_scatters_no_rows(worst_case, monkeypatch):
+    """Forward and backward (``jax.value_and_grad``), both branches of the
+    ``cond``, and the passes where the worst case takes them: no
+    scatter-add of rows (an operand of rank 2, ``[rows, D]`` or ``[T,
+    D]``) anywhere in the routed sum: each token-side sum is a gather
+    through the inverse permutation. Still no ``dot_general``."""
+    if worst_case == "passes":
+        monkeypatch.setattr(kanana, "ONE_BUFFER_WORST_ROWS", 0)
+    x, chosen, weights, gate_up, down, n = _routed_case()
+
+    def loss(x, weights, gate_up, down):
+        return jnp.sum(kanana.routed_sum(x, chosen, weights, gate_up, down,
+                                         0, n)[0] ** 2)
+    eqns = list(_equations(jax.make_jaxpr(jax.value_and_grad(
+        loss, argnums=(0, 1, 2, 3)))(x, weights, gate_up, down).jaxpr))
+    names = [eqn.primitive.name for eqn in eqns]
+    assert ("scan" in names) == (worst_case == "passes")
+    assert "cond" in names and "ragged_dot_general" in names
+    assert [eqn.invars[0].aval.shape for eqn in eqns
+            if "scatter" in eqn.primitive.name
+            and eqn.invars[0].aval.ndim >= 2] == []
+    assert "dot_general" not in names
 
 
 def test_interleaved_rotary_turns_neighbouring_pairs():

@@ -390,18 +390,21 @@ def test_remat_changes_nothing_but_the_residuals():
 
 
 def test_the_routed_cells_and_the_looped_cells_steps_lower_as_before():
-    """``models/kanana.py`` gained two helpers and ``routed_sum`` a
-    second worst case in PR 36: the routed cell's lowered train step, at
-    batch 2, is the parent's to the byte (sha256 taken on the parent with
-    this installation's jax, 0.9.0), and PR 38's counter of its rotary
-    paths, a Python count, leaves it so. The looped cell's digest is PR
-    38's own: its rotary became one pass each way under a hand-written
-    VJP in the projection's layout (``layers.rotate``), so its step text
-    moved on purpose (the parent's was 410193a1e483ec11); this pin holds
-    it there. After an upgrade of jax the digests go stale with no fault
-    in the tree: unpack the commit that last wrote them (``git archive
-    <commit> | tar -x -C <dir>``), run this test's body there under the
-    new jax, and write the digests it gives here."""
+    """The lowered train steps of the two routed cells and of the looped
+    cell, at batch 2, to the byte (sha256 with this installation's jax,
+    0.9.0). The long-sequence cell's digest was written when its routed
+    sum's token-side sums became gathers through the inverse permutation
+    under a VJP that makes the two moves each other's transpose
+    (``kanana.to_buffer`` / ``kanana.to_tokens``): its step text moved on
+    purpose, and this pin holds it at the program that was measured. The
+    routed cell, whose slots are 7.9 times its buffer's rows (over
+    ``kanana.SLOTS_OVER_BUFFER``), keeps the scatter-adds, and its step is
+    its parent's to the byte; so is the looped cell's, the one its
+    rotary's single pass each way under a hand-written VJP
+    (``layers.rotate``) gave it. After an upgrade of jax the digests go
+    stale with no fault in the tree: unpack the commit that last wrote
+    them (``git archive <commit> | tar -x -C <dir>``), run this test's
+    body there under the new jax, and write the digests it gives here."""
     import hashlib
     from flax.core import meta
     from tpuic.config import ModelConfig, OptimConfig
@@ -410,12 +413,14 @@ def test_the_routed_cells_and_the_looped_cells_steps_lower_as_before():
     from tpuic.train.state import TrainState
     from tpuic.train.step import make_train_step
     for name, want in (("kanana-2-30b-a3b-l6e8", "d81c45772d1655c0"),
-                       ("ouro-2.6b-l6", "e05ef1f4ce254bae")):
+                       ("ouro-2.6b-l6", "e05ef1f4ce254bae"),
+                       ("mellum2-12b-a2.5b-l4e8", "9d11239a8a33ecdc")):
         mc = ModelConfig(name=name, num_classes=1000, dtype="bfloat16",
                          remat=True, remat_policy="blocks")
         oc = OptimConfig(optimizer="adam", class_weights=(), milestones=())
         model = create_model_from_config(mc)
-        x = jnp.zeros((2, 224, 224, 3))
+        side = 1024 if name.startswith("mellum") else 224
+        x = jnp.zeros((2, side, side, 3))
         v = meta.unbox(jax.eval_shape(
             lambda: model.init(jax.random.key(0), x, train=False)))
         tx = make_optimizer(oc, 8, 1, global_batch=2)

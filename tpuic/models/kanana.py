@@ -47,15 +47,23 @@ would add is left out.
 
 The routed sum does the work of the pairs routed to held experts and no
 more (:func:`routed_sum`): the ``T x top_k`` choices are flattened, those on
-held experts stable-sorted by expert into a static buffer, two grouped
+held experts stable-sorted by expert into a static buffer, and two grouped
 matrix products (``jax.lax.ragged_dot``: gate|up, then down) run over the
-groups, the rows past the groups are masked, and the weighted rows are
-added back to their tokens. No pair is ever dropped: the buffer holds
-twice the even load's rows, and a step whose count exceeds it takes the
-worst-case buffer (``T * min(top_k, held)`` rows) under ``lax.cond``. A
-grouped product's time follows the rows that lie in groups, so a step's
-time follows its routing; the buffer's empty rows cost their gather and
-their scatter-add.
+groups. Rows move between tokens and buffer by two gathers, each the
+other's transpose under a hand-written VJP: a buffer row is gathered from
+its token (:func:`to_buffer`), and each token gathers its ``top_k`` slots
+from the buffer through the inverse of the sort, weighs them and sums them
+in float32 (:func:`to_tokens`); the rows past the groups are masked on the
+way in and never read on the way out, and no rows are scattered, forward
+or backward. Where the slots outnumber the buffer's rows by more than
+``SLOTS_OVER_BUFFER`` the gathers out read more than the scatter-adds
+write, and there the buffer's rows are scatter-added into their tokens
+instead. No pair is ever dropped: the buffer holds twice the even load's
+rows, and a step whose count exceeds it takes the worst-case buffer (``T *
+min(top_k, held)`` rows) under ``lax.cond``. A grouped product's time
+follows the rows that lie in groups, so a step's time follows its routing;
+the buffer's empty rows cost their gather in, and every slot of every
+token, held here or not, its gather out.
 
 Activations are what grows with the batch: the memory mode is per-block
 rematerialisation (``ModelConfig.remat_policy='blocks'``).
@@ -64,7 +72,7 @@ rematerialisation (``ModelConfig.remat_policy='blocks'``).
 from __future__ import annotations
 
 import functools
-from typing import Any, Tuple
+from typing import Any, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -80,13 +88,26 @@ SELECTION_BIAS_SIGMA = 0.02
 # The buffer of the routed sum, over the rows an even load would send to
 # the experts held (T * top_k * held / num_experts): room for a router
 # that is off balance by a factor of two, past which a step pays for the
-# worst case's gather and scatter-add instead.
+# worst case's gathers instead.
 BUFFER_OVER_EVEN_LOAD = 2
 # A worst case of up to this many rows goes through one buffer of its own;
 # a larger one (16,384 tokens choosing 8 of 8 held experts are 131,072 rows,
 # 7.9 GiB of reserved temporaries at width 2,304) through the usual buffer
 # as many times over as it takes. ROADMAP Queue 1: the passes alone.
 ONE_BUFFER_WORST_ROWS = 65_536
+# Each token's slots, T * top_k rows, are gathered back from the buffer
+# where they number at most this many times the buffer's rows; past it the
+# buffer's rows are scatter-added into their tokens instead. Measured on a
+# v5e, whole steps beside the parent: at 4 times (16,384 tokens, top-8, a
+# 32,768-row buffer) the gathers took 19.4 ms off a 375 ms step; at 7.9
+# times (6,272 tokens, top-6, 4,736 rows) they put 3.3 ms on a 168 ms one.
+SLOTS_OVER_BUFFER = 6
+# A gather reads its source's rows near the memory's rate where the
+# compiler can hold the source in the core's own memory, and at about a
+# fifth of it where it cannot (on a v5e, whose core holds 128 MiB: 131,072
+# rows of a 151 MB buffer in 4.97 ms, of its 75 MB half in 0.59 ms). A
+# larger source is gathered by blocks of columns of at most this many bytes.
+GATHER_SOURCE_BYTES = 96 * 2**20
 HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -178,13 +199,135 @@ class LatentAttention(nn.Module):
         return dense(d, "o", ("model", "embed"))(out)
 
 
-def dispatch(group: jnp.ndarray, held: int):
+def dispatch(group: jnp.ndarray, held: int, rank: bool = False):
     """``group`` [P] int32: each routed pair's expert among those held
     here, ``held`` for a pair whose expert is absent. Returns ``(order [P],
-    sizes [held])``: the pairs with those on held experts first and in
-    expert order (a stable sort), and the rows each held expert got."""
-    return (jnp.argsort(group, stable=True),
+    rank [P] or None, sizes [held])``: the pairs with those on held experts
+    first and in expert order (a stable sort), where ``rank`` each pair's
+    place in that order (the inverse permutation), and the rows each held
+    expert got."""
+    order = jnp.argsort(group, stable=True)
+    return (order, jnp.argsort(order) if rank else None,
             jnp.sum(jax.nn.one_hot(group, held, dtype=jnp.int32), axis=0))
+
+
+class Moves(NamedTuple):
+    """Where a buffer's rows come from and go. Row ``r`` holds pair
+    ``pair[r]`` (of token ``pair[r] // top_k``) where ``valid[r]``: the rows
+    past the groups hold none. Slot ``j`` of token ``t``, pair ``t * top_k
+    + j``, lies at row ``slot[j, t]`` where ``held[j, t]`` (elsewhere
+    ``slot`` is any row, read and selected away)."""
+    pair: jnp.ndarray       # [rows] int32
+    valid: jnp.ndarray      # [rows] bool
+    slot: jnp.ndarray       # [top_k, T] int32 in [0, rows)
+    held: jnp.ndarray       # [top_k, T] bool
+
+
+def buffer_moves(pairs: jnp.ndarray, rank: jnp.ndarray, count,
+                 top_k: int) -> Moves:
+    """The :class:`Moves` of a buffer of ``pairs`` [rows] (a run of the
+    sorted order) whose first ``count`` rows lie in groups; ``rank`` [T *
+    top_k] is each pair's row, its place in the sorted order less the
+    run's start."""
+    rows = pairs.shape[0]
+    held = (rank >= 0) & (rank < count)
+    # a slot that is not held reads a row of its own, not one row for all:
+    # a gather that reads one row over and over runs at a fraction of the
+    # rate of one that reads rows spread over the source
+    spread = jnp.arange(rank.shape[0], dtype=jnp.int32) % rows
+    slot = jnp.where(held, rank, spread)
+    return Moves(pairs, jnp.arange(rows, dtype=jnp.int32) < count,
+                 slot.reshape(-1, top_k).T, held.reshape(-1, top_k).T)
+
+
+def column_blocks(source: jnp.ndarray):
+    """``[(lo, hi)]``: the columns of ``source`` [N, D] in blocks of whole
+    lane tiles of at most :data:`GATHER_SOURCE_BYTES` each (one block where
+    the whole source is under it)."""
+    width = source.shape[1]
+    blocks = -(-source.size * source.dtype.itemsize // GATHER_SOURCE_BYTES)
+    step = min(-(-width // (128 * blocks)) * 128, width)
+    return [(lo, min(lo + step, width)) for lo in range(0, width, step)]
+
+
+def gather_rows(source: jnp.ndarray, index: jnp.ndarray) -> jnp.ndarray:
+    """``source[index]``: rows of ``source`` [N, D] at ``index`` (any
+    shape), gathered by :func:`column_blocks`, each cut from the source
+    before it is gathered."""
+    blocks = column_blocks(source)
+    if len(blocks) == 1:
+        return source[index]
+    parts = jax.lax.optimization_barrier(
+        [source[:, lo:hi] for lo, hi in blocks])
+    return jnp.concatenate([part[index] for part in parts], axis=-1)
+
+
+def _slot_sum(buf, moves, weights):
+    """:func:`to_tokens` of one block of the buffer's columns."""
+    rows = buf[moves.slot]
+    y = 0.0
+    for j in range(rows.shape[0]):
+        row = rows[j].astype(jnp.float32)
+        if weights is not None:
+            row = row * weights[:, j, None]
+        y = y + jnp.where(moves.held[j, :, None], row, 0.0)
+    return y
+
+
+@jax.custom_vjp
+def to_buffer(x: jnp.ndarray, moves: Moves) -> jnp.ndarray:
+    """``buf[r] = valid[r] * x[pair[r] // top_k]``: the buffer's rows
+    gathered from their tokens ``x`` [T, D], in ``x``'s dtype. Its
+    transpose is :func:`to_tokens`, a gather too: no scatter either way."""
+    token = moves.pair // moves.slot.shape[0]
+    return jnp.where(moves.valid[:, None], gather_rows(x, token), 0)
+
+
+@jax.custom_vjp
+def to_tokens(buf: jnp.ndarray, moves: Moves,
+              weights: Any = None) -> jnp.ndarray:
+    """``y[t] = sum over j of held[j, t] * weights[t, j] * buf[slot[j,
+    t]]``: each token's slots gathered from the buffer ``buf`` [rows, D],
+    weighed (``weights`` [T, top_k] float32, or none) and summed in
+    float32, [T, D]. A row that no held slot names (one past the groups)
+    is never read. Its transpose is :func:`to_buffer`, each row times its
+    pair's weight. The rows are weighed as gathered, in float32: weighed
+    first, the buffer would be written in float32 and gathered so."""
+    blocks = column_blocks(buf)
+    if len(blocks) == 1:
+        return _slot_sum(buf, moves, weights)
+    # a block of columns is cut only once the one before it is summed, so
+    # that one block at a time is held in the core's memory
+    y = jnp.zeros((moves.slot.shape[1], buf.shape[1]), jnp.float32)
+    for lo, hi in blocks:
+        part, y = jax.lax.optimization_barrier((buf[:, lo:hi], y))
+        y = jax.lax.dynamic_update_slice(
+            y, _slot_sum(part, moves, weights), (0, lo))
+    return y
+
+
+def _to_buffer_bwd(moves, d_buf):
+    # summed in float32, returned in the dtype of x, which is d_buf's
+    return to_tokens(d_buf, moves).astype(d_buf.dtype), None
+
+
+def _to_tokens_bwd(residuals, dy):
+    buf, moves, weights = residuals
+    d_buf = to_buffer(dy, moves)
+    if weights is None:
+        return d_buf.astype(buf.dtype), None, None
+    # a weight's cotangent is its row's dot with dy, read at its slot: a
+    # row past the groups (anything, NaN too) is never selected
+    d_pair = jnp.sum(d_buf * buf.astype(jnp.float32), axis=-1)
+    d_weights = jnp.where(moves.held, d_pair[moves.slot], 0.0).T
+    w_buf = weights.reshape(-1)[moves.pair]
+    return (d_buf * w_buf[:, None]).astype(buf.dtype), None, d_weights
+
+
+to_buffer.defvjp(lambda x, moves: (to_buffer(x, moves), moves),
+                 _to_buffer_bwd)
+to_tokens.defvjp(lambda buf, moves, weights: (
+    to_tokens(buf, moves, weights), (buf, moves, weights)), _to_tokens_bwd)
 
 
 def expert_matmul(rows: jnp.ndarray, gate_up: jnp.ndarray,
@@ -208,8 +351,9 @@ def combine(out: jnp.ndarray, valid: jnp.ndarray, weights: jnp.ndarray,
 
 
 def _routed_rows(x, order, sizes, weights, gate_up, down, *, rows: int):
-    """``routed_sum`` through a buffer of the first ``rows`` of ``order``;
-    also how many pairs it had room for."""
+    """``routed_sum`` through a buffer of the first ``rows`` of ``order``,
+    the token-side sums scatter-adds; also how many pairs it had room
+    for."""
     tokens, top_k = weights.shape
     count = jnp.sum(sizes)
     with jax.named_scope("dispatch"):
@@ -226,6 +370,25 @@ def _routed_rows(x, order, sizes, weights, gate_up, down, *, rows: int):
     return y, jnp.minimum(count, rows)
 
 
+def _gathered_rows(x, pairs, rank, sizes, weights, gate_up, down):
+    """``routed_sum`` through a buffer of the sorted pairs ``pairs``
+    [rows], ``rank`` [T * top_k] each pair's row there (off it for a pair
+    elsewhere), the rows moved both ways by gathers; also how many pairs
+    it had room for."""
+    top_k = weights.shape[1]
+    count = jnp.minimum(jnp.sum(sizes), pairs.shape[0])
+    with jax.named_scope("dispatch"):
+        # masked on the way in as on the way out: the backward pass of a
+        # grouped product leaves the rows past its groups as they were
+        moves = buffer_moves(pairs, rank, count, top_k)
+        taken = to_buffer(x, moves)
+    with jax.named_scope("expert_matmul"):
+        out = expert_matmul(taken, gate_up, down, sizes)
+    with jax.named_scope("combine"):
+        y = to_tokens(out, moves, weights)
+    return y, count
+
+
 def buffer_rows(tokens: int, top_k: int, held: int, num_experts: int):
     """``(rows, worst)``: the buffer a step's routed pairs go through
     (``BUFFER_OVER_EVEN_LOAD`` times the rows an even load would send to
@@ -237,8 +400,8 @@ def buffer_rows(tokens: int, top_k: int, held: int, num_experts: int):
     return min(rows, worst), worst
 
 
-def _routed_passes(x, order, sizes, weights, gate_up, down, *, rows: int,
-                   worst: int):
+def _routed_passes(x, order, rank, sizes, weights, gate_up, down, *,
+                   rows: int, worst: int):
     """The worst case through the buffer of ``rows``, as many times over
     as it takes: pass ``c`` carries the sorted pairs ``[c rows, (c + 1)
     rows)`` and the part of each expert's group that lies there. One pass
@@ -251,9 +414,11 @@ def _routed_passes(x, order, sizes, weights, gate_up, down, *, rows: int,
 
     def one(y, lo):
         part = jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo, lo + rows)
+        run = jax.lax.dynamic_slice(order, (lo,), (rows,))
         more, _ = _routed_rows(
-            x, jax.lax.dynamic_slice(order, (lo,), (rows,)), part, weights,
-            gate_up, down, rows=rows)
+            x, run, part, weights, gate_up, down, rows=rows) if rank is None \
+            else _gathered_rows(x, run, rank - lo, part, weights, gate_up,
+                                down)
         return y + more, None
     y, _ = jax.lax.scan(
         jax.checkpoint(one), jnp.zeros((x.shape[0], down.shape[-1]),
@@ -280,21 +445,27 @@ def routed_sum(x: jnp.ndarray, chosen: jnp.ndarray, weights: jnp.ndarray,
     local = chosen - first
     group = jnp.where((local >= 0) & (local < held), local,
                       held).reshape(-1).astype(jnp.int32)
-    with jax.named_scope("dispatch"):
-        order, sizes = dispatch(group, held)
     rows, worst = buffer_rows(tokens, top_k, held, num_experts)
-    through = functools.partial(_routed_rows, x, order, sizes, weights,
-                                gate_up, down)
+    with jax.named_scope("dispatch"):
+        order, rank, sizes = dispatch(
+            group, held, rank=tokens * top_k <= SLOTS_OVER_BUFFER * rows)
+
+    def through(rows):
+        if rank is None:
+            return _routed_rows(x, order, sizes, weights, gate_up, down,
+                                rows=rows)
+        return _gathered_rows(x, order[:rows], rank, sizes, weights, gate_up,
+                              down)
     over = jnp.sum(sizes) > rows
     if rows == worst:
-        y, computed = through(rows=worst)
+        y, computed = through(worst)
     else:
         worst_case = functools.partial(
-            _routed_passes, x, order, sizes, weights, gate_up, down,
+            _routed_passes, x, order, rank, sizes, weights, gate_up, down,
             rows=rows, worst=worst) if worst > ONE_BUFFER_WORST_ROWS \
-            else functools.partial(through, rows=worst)
+            else functools.partial(through, worst)
         y, computed = jax.lax.cond(over, worst_case,
-                                   lambda: through(rows=rows))
+                                   functools.partial(through, rows))
     return y, sizes, computed, over
 
 
