@@ -168,7 +168,7 @@ def test_banded_flash_attention(chip, window, grad):
 # The sparse flash kernels at the learned-sparse-attention cell's shapes
 # (one image of 16,384 tokens, 32 query heads over 4 key-value heads of
 # 128, a byte mask of [N, N], tiles of 512), and one query chunk's
-# selection (top 2,048 of 16,384 scores).
+# selection (the 2,048th of 16,384 scores a row, counted by a kernel).
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
 def test_sparse_attention(chip, grad):
     from tpuic.kernels.sparse_attention import sparse_attention
@@ -186,10 +186,14 @@ def test_sparse_attention(chip, grad):
     assert _compile(fn, chip, *shapes) == (3 if grad else 1)
 
 
-def test_the_selection_of_a_query_chunk(chip):
+def test_the_selection_of_a_query_chunk(chip, monkeypatch):
+    """The 2,048th score and tie key of each row by the row-select kernel,
+    steered to its chip form (off the chip it would interpret)."""
+    import tpuic.kernels
     from tpuic.models.keye import select
-    assert _compile(lambda s: select(s, 2048), chip,
-                    ((1, 512, 16384), F32)) == 0
+    monkeypatch.setattr(tpuic.kernels, "default_interpret", lambda: False)
+    assert _compile(lambda s, f: select(s, 2048, f), chip,
+                    ((1, 512, 16384), F32), ((), I32)) == 1
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])

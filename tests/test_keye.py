@@ -226,6 +226,34 @@ def test_the_selection_mask_is_the_scores_top_k_chunk_by_chunk():
     np.testing.assert_array_equal(live, _tiles_held(want, 64, 64))
 
 
+@pytest.mark.parametrize("planted", [False, True], ids=["distinct",
+                                                        "planted"])
+def test_the_tie_rows_are_the_rows_cut_inside_a_run_by_hand(planted):
+    """``selection`` counts, an image, the rows whose ``top_k``-th score
+    has an equal one left out. Positive queries, keys and weights keep
+    every ReLU open, so the scores are distinct (0); with keys planted in
+    pairs every score has a twin, and the rows that cut between twins are
+    counted by hand from the scores sorted in float64."""
+    rng = np.random.default_rng(4)
+    b, n, j, e, k = 2, 256, 4, 32, 48
+    qi = np.abs(rng.standard_normal((b, n, j, e))).astype(np.float32)
+    ki = np.abs(rng.standard_normal((b, n, e))).astype(np.float32)
+    w = np.abs(rng.standard_normal((b, n, j))).astype(np.float32)
+    if planted:
+        ki[:, 1::2] = ki[:, 0::2]
+    *_, ties = keye.selection(*(jnp.asarray(t) for t in (qi, ki, w)), k,
+                              64, 64)
+    scores = np.einsum("bqj,bqje,bke->bqk", w.astype(np.float64),
+                       qi.astype(np.float64), ki.astype(np.float64))
+    want = np.zeros(b, int)
+    for i in range(b):
+        for t in range(k - 1, n):       # rows that hold k keys or more
+            row = np.sort(scores[i, t, :t + 1])[::-1]
+            want[i] += (row >= row[k - 1]).sum() > k
+    np.testing.assert_array_equal(ties, want)
+    assert (want > 0).all() if planted else (want == 0).all()
+
+
 # -- the kernel -------------------------------------------------------------
 
 def _dense(q, k, v, sel):
@@ -414,6 +442,12 @@ def test_the_step_differentiates_the_kl_reports_the_loss_and_the_counters(
     assert float(metrics["attention_key_blocks_visited"]) <= 2 * 10
     assert float(metrics["attention_pairs_selected"]) == \
         2 * (48 * 49 // 2 + (256 - 48) * 48)
+    # the rows cut inside a run of equal scores, as the forward counts them
+    _, sown = model.apply(v, jnp.asarray(images), train=False,
+                          mutable=["counters"])
+    ties = float(sown["counters"]["backbone"]["selection_tie_rows"][0])
+    assert float(metrics["selection_tie_rows"]) == ties
+    assert ties == int(ties) and 0 <= ties <= 2 * 256
     want = jax.tree_util.tree_map(lambda a, b: a + b, float32_pass["by_ce"],
                                   float32_pass["by_kl"])
     moved = jax.tree_util.tree_map(lambda a, b: a - b, v["params"],

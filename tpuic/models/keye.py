@@ -33,7 +33,10 @@ one closing RMSNorm after the last block. ``Attn`` (:class:`SparseAttention`):
 - **selection** (:func:`select`): ``S_t`` is the ``min(t + 1, top_k)``
   causal keys of largest score, ties to the lower key (``lax.top_k``'s
   rule), exactly; computed in chunks of ``block_q`` queries, so that no
-  ``[N, N]`` float is held, into a byte mask;
+  ``[N, N]`` float is held, into a byte mask. No sort: each row's
+  ``top_k``-th score and the key that cuts a run of scores equal to it
+  are counted out of the scores' float bits by bisection
+  (``kernels/row_select.py``), and bound the set;
 - **core**: ``softmax over S_t`` of ``q . k / sqrt(head_dim)`` times ``v``,
   through ``kernels/sparse_attention.py`` (tiles with no selected pair are
   neither computed nor fetched); then ``W_o``;
@@ -53,7 +56,9 @@ The stack sows, once a step: ``attention_key_blocks_visited`` (the
 computes, for a head of one image, summed over the layers),
 ``attention_key_blocks_square`` (every tile of those squares),
 ``attention_pairs_selected`` (``sum_t |S_t|`` of one image, summed over the
-layers) and ``indexer_kl`` (``L^I`` summed over the layers) into
+layers), ``selection_tie_rows`` (the rows of one image whose cut fell inside
+a run of equal scores, so that the tie key decided, summed over the layers)
+and ``indexer_kl`` (``L^I`` summed over the layers) into
 ``counters``, and the same ``L^I`` sum, with its gradient, into
 ``intermediates`` under ``auxiliary_objective``: the training step
 differentiates it with the classifier's loss and reports the loss without
@@ -121,21 +126,31 @@ def index_scores(qi, ki, w, first: int):
     return jnp.where(s <= t, scores, -jnp.inf)
 
 
-def select(scores, top_k: int):
+def select(scores, top_k: int, first=None):
     """The selection bool [B, C, N] of ``scores`` [B, C, N] (``-inf`` past
     each query): its ``min(t + 1, top_k)`` causal keys of largest score,
-    ties to the lower key, as ``lax.top_k`` picks them. The ``top_k``-th
-    value and its key bound the set: every key above that value, and those
-    equal to it up to that key. ``-0.0`` is read as ``0.0`` first: a score
-    whose ReLUs are all 0 is a zero of either sign, and ``top_k`` orders
+    ties to the lower key, as ``lax.top_k`` picks them. ``first``, where
+    given, is the first row's query: no key past the last row's is read."""
+    return _select(scores, top_k, first)[0]
+
+
+def _select(scores, top_k: int, first):
+    """``(selection, tie int32 [B, C, 1])``. The ``top_k``-th value and a
+    key bound the set: every key above that value, and those equal to it
+    up to that key, both counted out of the scores' float bits by
+    ``kernels/row_select.py`` (no sort); ``tie`` is 1 where that key cut a
+    run of equal scores. ``-0.0`` is read as ``0.0`` first: a score whose
+    ReLUs are all 0 is a zero of either sign, and ``top_k`` orders
     ``-0.0`` below ``0.0`` where ``==`` calls them a tie."""
+    # Pallas is imported when a model is traced, not with the registry
+    from tpuic.kernels.row_select import kth_largest
     scores = jnp.where(scores == 0, 0.0, scores)
-    k = min(top_k, scores.shape[-1])
-    values, keys = jax.lax.top_k(scores, k)
-    bound, last = values[..., -1:], keys[..., -1:]
+    n = scores.shape[-1]
+    bound, last, tie = kth_largest(scores, min(top_k, n),
+                                   n if first is None else first)
     s = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 2)
     return (scores > -jnp.inf) & ((scores > bound)
-                                  | ((scores == bound) & (s <= last)))
+                                  | ((scores == bound) & (s <= last))), tie
 
 
 def _chunks(x, size: int):
@@ -144,10 +159,11 @@ def _chunks(x, size: int):
     return jnp.moveaxis(x.reshape((b, n // size, size) + x.shape[2:]), 1, 0)
 
 
-def selection_mask(qi, ki, w, top_k: int, block_q: int, block_k: int):
-    """``(mask int8 [B, N, N], live int32 [B, N/bq, N/bk], pairs [B])``:
-    the selection, query chunk by query chunk, the tiles that hold a
-    selected pair and the count of selected pairs."""
+def selection(qi, ki, w, top_k: int, block_q: int, block_k: int):
+    """``(mask int8 [B, N, N], live int32 [B, N/bq, N/bk], pairs [B],
+    tie_rows [B])``: the selection, query chunk by query chunk, the tiles
+    that hold a selected pair, the count of selected pairs and the count
+    of rows whose cut fell inside a run of equal scores."""
     b, n = ki.shape[:2]
 
     def chunk(args):
@@ -155,16 +171,23 @@ def selection_mask(qi, ki, w, top_k: int, block_q: int, block_k: int):
         with jax.named_scope("index_scores"):
             scores = index_scores(qi_c, ki, w_c, first)
         with jax.named_scope("select"):
-            chosen = select(scores, top_k)
+            chosen, tie = _select(scores, top_k, first)
             live = jnp.any(chosen.reshape(b, block_q, n // block_k, block_k),
                            axis=(1, 3))
             return (chosen.astype(jnp.int8), live.astype(jnp.int32),
-                    jnp.sum(chosen, axis=(1, 2), dtype=jnp.int32))
+                    jnp.sum(chosen, axis=(1, 2), dtype=jnp.int32),
+                    jnp.sum(tie, axis=(1, 2)))
     firsts = jnp.arange(0, n, block_q, dtype=jnp.int32)
-    mask, live, pairs = jax.lax.map(
+    mask, live, pairs, ties = jax.lax.map(
         chunk, (_chunks(qi, block_q), _chunks(w, block_q), firsts))
     return (jnp.moveaxis(mask, 0, 1).reshape(b, n, n),
-            jnp.moveaxis(live, 0, 1), jnp.sum(pairs, axis=0))
+            jnp.moveaxis(live, 0, 1), jnp.sum(pairs, axis=0),
+            jnp.sum(ties, axis=0))
+
+
+def selection_mask(qi, ki, w, top_k: int, block_q: int, block_k: int):
+    """``(mask, live, pairs)`` of :func:`selection`."""
+    return selection(qi, ki, w, top_k, block_q, block_k)[:3]
 
 
 def indexer_loss(qi, ki, w, mask, q, k, lse, block_q: int):
@@ -249,7 +272,8 @@ class Indexer(nn.Module):
 
 class SparseAttention(nn.Module):
     """Returns ``(y [B, N, d], L^I summed over the batch's queries, tiles
-    visited a head of one image, selected pairs of one image)``."""
+    visited a head of one image, selected pairs of one image, rows of one
+    image whose cut fell inside a run of equal scores)``."""
 
     num_heads: int
     kv_heads: int
@@ -290,7 +314,7 @@ class SparseAttention(nn.Module):
                                 self.rope_theta, self.eps, self.dtype,
                                 self.param_dtype, name="indexer")(
                 jax.lax.stop_gradient(u))
-            mask, live, pairs = selection_mask(
+            mask, live, pairs, ties = selection(
                 *(jax.lax.stop_gradient(t) for t in (qi, ki, w)),
                 self.top_k, block_q, block_k)
         # Pallas is imported when a model is traced, not with the registry
@@ -309,11 +333,13 @@ class SparseAttention(nn.Module):
         y = proj(d, "o", self.dtype, self.param_dtype,
                  ("model", "embed"))(out.reshape(b, n, -1))
         visited = jnp.sum(live).astype(jnp.float32) / b
-        return y, loss, visited, jnp.sum(pairs).astype(jnp.float32) / b
+        return (y, loss, visited, jnp.sum(pairs).astype(jnp.float32) / b,
+                jnp.sum(ties).astype(jnp.float32) / b)
 
 
 class SparseMoeBlock(nn.Module):
-    """One published layer: ``(x, L^I, tiles visited, pairs selected)``."""
+    """One published layer: ``(x, L^I, tiles visited, pairs selected, tie
+    rows)``."""
 
     num_heads: int
     kv_heads: int
@@ -339,7 +365,7 @@ class SparseMoeBlock(nn.Module):
         def norm(name):
             return RMSNorm(self.eps, self.dtype, self.param_dtype, name=name)
         with jax.named_scope("sparse_attention"):
-            y, loss, visited, pairs = SparseAttention(
+            y, loss, visited, pairs, ties = SparseAttention(
                 self.num_heads, self.kv_heads, self.head_dim,
                 self.rope_theta, self.indexer_heads, self.indexer_dim,
                 self.top_k, tuple(self.blocks), self.with_loss, self.eps,
@@ -350,7 +376,7 @@ class SparseMoeBlock(nn.Module):
             self.num_experts, tuple(self.held), self.expert_width,
             self.experts_per_token, self.norm_topk, self.dtype,
             self.param_dtype, name="moe")(norm("mlp_norm")(x))
-        return x, loss, visited, pairs
+        return x, loss, visited, pairs, ties
 
 
 class SparseMoeStack(nn.Module):
@@ -390,9 +416,9 @@ class SparseMoeStack(nn.Module):
                          self.param_dtype)
         block_cls = nn.remat(SparseMoeBlock) if self.remat_blocks \
             else SparseMoeBlock
-        total = {"loss": 0.0, "visited": 0.0, "pairs": 0.0}
+        total = {"loss": 0.0, "visited": 0.0, "pairs": 0.0, "ties": 0.0}
         for i in range(self.depth):
-            h, loss, visited, pairs = block_cls(
+            h, loss, visited, pairs, ties = block_cls(
                 self.num_heads, self.kv_heads, self.head_dim,
                 self.rope_theta, self.indexer_heads, self.indexer_dim,
                 self.top_k, self.num_experts, tuple(self.held),
@@ -400,7 +426,7 @@ class SparseMoeStack(nn.Module):
                 tuple(self.blocks), train, self.eps, self.dtype,
                 self.param_dtype, self.mesh, name=f"layer{i}")(h)
             for key, value in (("loss", loss), ("visited", visited),
-                               ("pairs", pairs)):
+                               ("pairs", pairs), ("ties", ties)):
                 total[key] = total[key] + value
         if not self.is_initializing():
             self._sow(h.shape[0], h.shape[1], total, train)
@@ -418,6 +444,7 @@ class SparseMoeStack(nn.Module):
                 ("attention_key_blocks_square",
                  self.depth * (tokens // block_q) * (tokens // block_k)),
                 ("attention_pairs_selected", total["pairs"]),
+                ("selection_tie_rows", total["ties"]),
                 ("indexer_kl", kl)):
             self.sow("counters", name,
                      jax.lax.stop_gradient(jnp.float32(value)))

@@ -645,6 +645,9 @@ def train_exposition(report: dict, steptime: Optional[dict] = None,
             ("attention_pairs_selected", "sparse attention: (query, key) "
              "pairs the learned indexer selects for one image, summed "
              "over the layers"),
+            ("selection_tie_rows", "sparse attention: rows of one image "
+             "whose selection cut a run of equal scores, so that the tie "
+             "key decided, summed over the layers"),
             ("indexer_kl", "sparse attention: the indexer's KL objective "
              "summed over the layers, differentiated beside the loss")):
         rows.append((name, counters.get(name), "gauge", doc, None))
