@@ -177,14 +177,22 @@ def reference_check(ctx, model, variables, first):
     got = train_check.program_readings(first, optimizer)
     t0 = time.perf_counter()
     events = len(ctx.compiles.events)
-    want = steps.follow(ref, first.before, first.batches, ctx.config,
-                        optimizer, put=batch_placer(ctx))
+    try:
+        want = steps.follow(ref, first.before, first.batches, ctx.config,
+                            optimizer, put=batch_placer(ctx))
+    except steps.StepDoesNotFit as e:
+        problems.append(str(e))
+        return problems, compared
     values, where = train_check.numbers(got, want)
+    memory = want["memory"]
     ctx.say(f"reference: {first.steps} steps followed in "
             f"{time.perf_counter() - t0:.2f} s ("
             f"{len(ctx.compiles.events) - events} compiles or cache loads), "
-            f"{held / 2**20:.0f} MiB still held by the program; losses "
-            f"program {got['losses']} reference {want['losses']}; "
+            f"{held / 2**20:.0f} MiB still held by the program; step "
+            f"{memory['step']} bytes compiled ("
+            f"{memory['step'] / memory['parameters']:.2f} B a parameter "
+            f"over {memory['parameters']}; temporaries {memory['temp']}); "
+            f"losses program {got['losses']} reference {want['losses']}; "
             f"numbers {values}; worst leaves {where}")
     limits = train_check.limits(ctx.config)
     if "loss_gap" not in limits or len(limits) < 3:
